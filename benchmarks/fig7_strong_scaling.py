@@ -51,6 +51,7 @@ def run(
     env["PYTHONPATH"] = str(ROOT / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
+    env["JAX_PLATFORMS"] = "cpu"  # a CPU simulation: never takes the chip
     cmd = [
         sys.executable, "-m", "repro.launch.dryrun", "--su3-fig7",
         "--L", str(L),

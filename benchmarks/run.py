@@ -56,13 +56,17 @@ def main(argv: list[str] | None = None) -> None:
         if i + 1 < len(argv):
             seed = int(argv[i + 1])
 
+    from repro.compile_cache import enable_compile_cache
+
     from benchmarks import (
         cg_solve, fig7_strong_scaling, fig9_gemm_vs_dot, fig10_arch_compare,
         lm_step, serve_chaos, serve_tenancy, serve_traffic, stencil,
         table1_roofline, table2_variants, table3_placement,
     )
 
+    enable_compile_cache()
     collected: dict[str, list[dict]] = {}
+    failed: list[str] = []
     tables = [
         ("table1_roofline", lambda: table1_roofline.run()),
         ("table2_variants", lambda: table2_variants.run(
@@ -88,6 +92,7 @@ def main(argv: list[str] | None = None) -> None:
             rows = fn()
         except Exception as e:  # noqa: BLE001
             rows = [{"name": f"{table}_error", "error": f"{type(e).__name__}: {e}"[:300]}]
+            failed.append(table)
         _emit(rows, collected, table)
 
     if json_path:
@@ -102,6 +107,8 @@ def main(argv: list[str] | None = None) -> None:
         with open(json_path, "w") as f:
             json.dump(payload, f, indent=2, default=str)
         print(f"# wrote {json_path}", file=sys.stderr)
+    if failed:
+        raise SystemExit(f"tables with errors: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -200,7 +200,11 @@ def _roofline_rows(L: int, dtype: str) -> list[dict]:
 
 
 def _subprocess_json(code: str, timeout: int = 600) -> tuple[dict | list | None, str]:
+    """Run ``code`` (a forced-device CPU simulation) in a child process and
+    return its last stdout line as JSON.  The child runs on the CPU: the
+    parent may hold the accelerator, which one process owns at a time."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""

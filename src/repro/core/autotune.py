@@ -1123,7 +1123,7 @@ def _cg_measure_problem(L: int, seed: int = 7) -> tuple[Any, Any]:
     b = (rng.normal(size=(n, 3)) + 1j * rng.normal(size=(n, 3))).astype(
         np.complex64
     )
-    return jnp.asarray(u), jnp.asarray(b)
+    return layouts.on_host(lambda: (jnp.asarray(u), jnp.asarray(b)))
 
 
 def measure_cg_candidate(

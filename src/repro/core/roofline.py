@@ -59,7 +59,10 @@ class HardwareSpec:
         return self.peak_flops / self.hbm_bw
 
 
-# Assignment-given constants: 197 TFLOP/s bf16; 819 GB/s HBM; ~50 GB/s/link ICI.
+# Published peaks of one chip (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect (200 GB/s, taken here as 4 links x 50 GB/s).  The f32 VPU peak
+# and the issue rate are NOT published figures: they are models.
 TPU_V5E = HardwareSpec(
     name="tpu_v5e",
     peak_flops=197e12,
@@ -103,6 +106,27 @@ PIUMA_CORE = HardwareSpec(
 )
 
 HARDWARE = {h.name: h for h in (TPU_V5E, XEON_8280_SOCKET, PIUMA_CORE)}
+
+# The peaks table, keyed by the ``device_kind`` jax reports for the chip.
+PEAKS_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def hardware_for_device(device: Any) -> HardwareSpec:
+    """The peaks of ``device`` (a ``jax.Device``).
+
+    A TPU whose kind is not in :data:`PEAKS_BY_DEVICE_KIND` raises: a share
+    of a peak nobody recorded is not a number.  Any other platform (CPU
+    rehearsals) ranks against the v5e model, the chip this stack targets.
+    """
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return PEAKS_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks recorded for TPU kind {device.device_kind!r}; add it "
+            f"to roofline.PEAKS_BY_DEVICE_KIND with its source"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -24,12 +24,16 @@ On TPU the analogous axes are VPU lanes (128-wide) and VMEM tiles:
 
 Canonical (logical) form everywhere else in the library is complex:
   A : (n_sites, 4, 3, 3) complex   B : (4, 3, 3) complex.
+Canonical arrays live on the host (:func:`on_host`); only physical forms go
+onto an accelerator.
 """
 from __future__ import annotations
 
 import dataclasses
 import enum
-from typing import Any
+from typing import Any, Callable
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +85,25 @@ class LatticeShape:
 # ---------------------------------------------------------------------------
 # Canonical <-> physical layout converters.
 # ---------------------------------------------------------------------------
+
+
+def on_host(fn: Callable[..., Any], *args: Any) -> Any:
+    """``fn(*args)`` computed on the host CPU, its results committed there.
+
+    Canonical complex arrays have minor dimensions of 3 and 4, which a TPU
+    pads to 128 lanes: an L=32 gauge field of 288 MiB takes 9 GB of HBM.  So
+    canonical arrays never go onto an accelerator: array arguments are
+    fetched to the host first, and callers ``device_put`` the physical
+    results where the plan shards them.  On a CPU backend the host is the
+    device, and only arrays sharded over several CPU devices are gathered.
+    """
+    cpu = jax.devices("cpu")[0]
+
+    def put(x: Any) -> Any:
+        return jax.device_put(x, cpu) if isinstance(x, (jax.Array, np.ndarray)) else x
+
+    with jax.default_device(cpu):
+        return jax.tree.map(put, fn(*map(put, args)))
 
 
 def _real_dtype(complex_dtype: Any) -> Any:

@@ -262,6 +262,21 @@ def first_touch_init(
     return jax.make_array_from_callback(aval.shape, sharding, build_shard)
 
 
+def site_local(
+    fn: Callable[..., Any], mesh: jax.sharding.Mesh, in_specs: Any, out_specs: Any
+) -> Callable[..., Any]:
+    """``fn`` run once per shard of ``mesh`` (``jax.shard_map``).
+
+    Mosaic kernels cannot be partitioned automatically: a ``pallas_call`` on
+    a sharded operand must run inside a shard_map.  Every SU3 kernel is
+    site-local, so the per-shard call computes exactly the sites that shard
+    holds.  Gathers that cross shards stay outside, where XLA partitions them.
+    """
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
+
+
 def make_raw_step(
     codec: LayoutCodec,
     kernel: registry.KernelEntry,
@@ -270,6 +285,7 @@ def make_raw_step(
     k_iters: int = 1,
     interpret: bool | None = None,
     alias: bool = False,
+    mesh: jax.sharding.Mesh | None = None,
 ) -> Callable[[jax.Array, jax.Array], jax.Array]:
     """Unjitted physical step (a_phys, b_planar) -> c_phys for any kernel form.
 
@@ -278,6 +294,10 @@ def make_raw_step(
     ``accum_dtype`` (mixed-precision storage plans) flows to planar kernels
     that own their upcast; canonical kernels accumulate in float32 by
     construction (the codec unpacks to complex64).
+
+    With ``mesh``, a planar kernel runs per shard of the lattice's site
+    sharding (:func:`site_local`); without it the step is one unsharded call
+    (per-lattice bodies that a caller vmaps and shards itself).
     """
     if not kernel.supports_layout(codec.layout):
         raise ValueError(
@@ -330,6 +350,10 @@ def make_raw_step(
                 kw["interpret"] = interpret
             c_p = kernel.fn(a_p, b_p, **kw)
             return codec.from_planar_view(c_p, a_phys)
+
+        if mesh is not None:
+            spec = dist_sharding.lattice_site_spec(codec, mesh)
+            raw_step = site_local(raw_step, mesh, (spec, P()), spec)
 
     else:  # canonical complex kernel wrapped by the codec
 
@@ -417,6 +441,22 @@ def stencil_neighbor_tables(
     ]
     bidx = np.concatenate(ranges) if ranges else np.empty(0, np.int64)
     return glob.astype(np.int32), local.astype(np.int32), bidx.astype(np.int32)
+
+
+def gather_neighbors(v_p: jax.Array, idx: np.ndarray) -> jax.Array:
+    """Planar ``(2, 3, S)`` field -> ``(D, 2, 3, N)`` neighbor fields through
+    the ``(D, N)`` index table ``idx``.
+
+    One gather per direction: a single ``(D, N)``-indexed gather is the same
+    data movement, but at L=32 the TPU compiler takes ~35 s on it and emits
+    a 54 MB program, against ~3 s for the per-direction form.
+    """
+    nbrs = jnp.stack([v_p[:, :, idx[d]] for d in range(idx.shape[0])])
+    # materialized, as the kernel operand it is on the chip: under CPU
+    # interpret mode XLA would otherwise fuse the gather into the kernel body
+    # differently per program, and the fused-vs-composed CG bit-identity
+    # contract would hinge on that fusion choice
+    return jax.lax.optimization_barrier(nbrs)
 
 
 def init_stencil_canonical(n_sites: int) -> tuple[jax.Array, jax.Array]:
@@ -532,7 +572,8 @@ def cg_reference_solve(
     ``(x, relative residuals per iteration, converged)``.  Never raises on
     exhaustion (the oracle reports, the plan enforces).
     """
-    apply_j = jax.jit(lambda p: sigma * p + stencil_apply_reference(u, p, L))
+    # u is an argument, not a closed-over constant: at L=32 it is 302 MB
+    apply_j = jax.jit(lambda u, p: sigma * p + stencil_apply_reference(u, p, L))
     b_rs = float(jnp.sum(jnp.real(b) ** 2 + jnp.imag(b) ** 2))
     if b_rs == 0.0:
         return jnp.zeros_like(b), [], True
@@ -542,7 +583,7 @@ def cg_reference_solve(
     rs = jnp.sum(jnp.real(r) ** 2 + jnp.imag(r) ** 2)
     residuals: list[float] = []
     for _ in range(max_iters):
-        ap = apply_j(p)
+        ap = apply_j(u, p)
         pap = jnp.real(jnp.vdot(p, ap))
         alpha = rs / pap
         x = x + alpha * p
@@ -661,8 +702,10 @@ class ExecutionPlan:
             mesh, dist_sharding.lattice_site_spec(self.codec, mesh)
         )
         self.replicated = NamedSharding(mesh, P())
-        self.raw_step = make_raw_step(self.codec, self.kernel, tile=cfg.tile)
-        self.step = jax.jit(self.raw_step, out_shardings=self.sharding, donate_argnums=())
+        self.raw_step = make_raw_step(
+            self.codec, self.kernel, tile=cfg.tile, mesh=mesh
+        )
+        self.step = jax.jit(self.raw_step, out_shardings=self.sharding)
         self._fused_steps: dict[int, Callable[[jax.Array, jax.Array], jax.Array]] = {}
         self._batched_steps: dict[
             tuple[int, int], Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
@@ -729,21 +772,19 @@ class ExecutionPlan:
         """One dispatch performing K chained multiplies (C fed back as A).
 
         ``fused_step(k)(a, b)`` equals ``step`` applied k times sequentially.
-        On TPU the argument is donated and the Pallas C-tile aliases A's
-        buffer, so the chain is a true in-place VMEM-resident update.
+        The argument is donated (callers rebind ``a = fused(a, b)`` and never
+        reuse the input) and the Pallas C-tile aliases A's buffer, so the
+        chain is a true in-place VMEM-resident update.
         """
         if k < 1:
             raise ValueError(f"k must be >= 1, got {k}")
         if k not in self._fused_steps:
-            on_tpu = jax.default_backend() == "tpu"
             raw = make_raw_step(
                 self.codec, self.kernel, tile=self.cfg.tile, k_iters=k,
-                alias=self.kernel.form == registry.PLANAR and on_tpu,
+                alias=self.kernel.form == registry.PLANAR, mesh=self.mesh,
             )
             self._fused_steps[k] = jax.jit(
-                raw,
-                out_shardings=self.sharding,
-                donate_argnums=(0,) if on_tpu else (),
+                raw, out_shardings=self.sharding, donate_argnums=(0,)
             )
         return self._fused_steps[k]
 
@@ -760,8 +801,10 @@ class ExecutionPlan:
         are data (scalar-prefetched), clamped to the static ``max_k``; a slot
         with depth 0 passes through untouched.
 
-        On TPU the slot table is donated and the kernel's C block aliases A's
-        buffer, so in-flight slots update in place with zero copies.
+        The slot table is donated and the kernel's C block aliases A's
+        buffer, so in-flight slots update in place with zero copies.  When
+        ``slots`` divides the device count each device runs whole slots;
+        otherwise every device runs its site shard of every slot.
 
         Args:
             slots: slot-table size (the leading axis of ``a_batch``).
@@ -775,20 +818,22 @@ class ExecutionPlan:
         key = (slots, max_k)
         if key not in self._batched_steps:
             kernel = registry.get_kernel(MEGAKERNEL_VARIANT)
-            on_tpu = jax.default_backend() == "tpu"
             raw = make_raw_batched_step(
-                self.codec, kernel, tile=self.cfg.tile, max_k=max_k, alias=on_tpu
+                self.codec, kernel, tile=self.cfg.tile, max_k=max_k, alias=True
             )
-            # whole lattices per device (when the table divides the mesh) —
-            # the same sharding BatchedLatticeRunner gives request batches
-            out_sh = (
-                self.lattice_batch_sharding()
-                if slots % self.n_devices == 0 else None
-            )
+            if slots % self.n_devices == 0:
+                # whole lattices per device — the same sharding
+                # BatchedLatticeRunner gives request batches
+                a_spec = self.lattice_batch_sharding().spec
+                per_slot = P(a_spec[0])
+            else:
+                a_spec = P(None, *self.sharding.spec)
+                per_slot = P()
+            b_spec = P(*per_slot, None, None)
             self._batched_steps[key] = jax.jit(
-                raw,
-                out_shardings=out_sh,
-                donate_argnums=(0,) if on_tpu else (),
+                site_local(raw, self.mesh, (a_spec, b_spec, per_slot), a_spec),
+                out_shardings=NamedSharding(self.mesh, a_spec),
+                donate_argnums=(0,),
             )
         return self._batched_steps[key]
 
@@ -825,9 +870,17 @@ class ExecutionPlan:
             )
         return self._stencil_tables
 
-    def _stencil_kernel_kwargs(
-        self, variant: str = STENCIL_VARIANT
-    ) -> tuple[registry.KernelEntry, dict[str, Any]]:
+    def _stencil_kernel(
+        self, variant: str = STENCIL_VARIANT, sharded: bool = True
+    ) -> Callable[..., Any]:
+        """The stencil-family kernel with the plan's kwargs bound.
+
+        ``sharded`` runs it per shard of the plan's site sharding
+        (:func:`site_local`): planar operands carry the site axis last —
+        gauge ``(2, rows, S)``, gathered neighbors ``(8, 2, 3, S)``, vectors
+        ``(2, 3, S)`` — and the CG coefficients are replicated.  Unsharded is
+        the per-lattice body a caller vmaps and shards itself.
+        """
         kernel = registry.get_kernel(variant)
         if not kernel.supports_layout(self.codec.layout):
             raise ValueError(
@@ -849,24 +902,40 @@ class ExecutionPlan:
             kw["accum_dtype"] = self.codec.accum_dtype
         if self.codec.is_compressed:
             kw["compressed"] = True
-        return kernel, kw
+        fn = functools.partial(kernel.fn, **kw)
+        if not sharded:
+            return fn
+        ax = self.site_axes if len(self.site_axes) > 1 else self.site_axes[0]
+        vec, nbr = P(None, None, ax), P(None, None, None, ax)
+        if kernel.form == registry.STENCIL:
+            return site_local(fn, self.mesh, (vec, nbr), vec)
+        return site_local(
+            fn, self.mesh, (vec, nbr, nbr, vec, vec, P()), (vec, vec)
+        )
 
-    def raw_stencil_reference(self) -> Callable[[jax.Array, jax.Array], jax.Array]:
+    def _shard_pad(self, n: int) -> int:
+        """Padding that makes a gathered ``n``-site pass (boundary, ring)
+        split into whole tiles on every device."""
+        return (-n) % (self.cfg.tile * self.n_devices)
+
+    def raw_stencil_reference(
+        self, sharded: bool = True
+    ) -> Callable[[jax.Array, jax.Array], jax.Array]:
         """Unjitted reference stencil ``(u_phys, v_p) -> out_p``.
 
         Gathers all 8 neighbor fields through the exact periodic table and
         runs ONE kernel pass over every site — the bit-identity oracle the
-        overlapped schedule is pinned against, and the form the serving
-        layer vmaps over request batches.
+        overlapped schedule is pinned against.  ``sharded=False`` is the
+        per-lattice form the serving layer vmaps over request batches.
         """
-        kernel, kw = self._stencil_kernel_kwargs()
+        kernel = self._stencil_kernel(sharded=sharded)
         glob, _local, _bidx = self._stencil_geometry()
         codec = self.codec
 
         def reference(u_phys: jax.Array, v_p: jax.Array) -> jax.Array:
             u_p = codec.planar_view(u_phys)
-            v_nbr = jnp.moveaxis(v_p[:, :, glob], 2, 0)  # (8, 2, 3, S)
-            return kernel.fn(u_p, v_nbr, **kw)
+            v_nbr = gather_neighbors(v_p, glob)  # (8, 2, 3, S)
+            return kernel(u_p, v_nbr)
 
         return reference
 
@@ -943,15 +1012,15 @@ class ExecutionPlan:
         """
         if self._stencil_parts is not None:
             return self._stencil_parts
-        kernel, kw = self._stencil_kernel_kwargs()
+        kernel = self._stencil_kernel()
         glob, local, bidx = self._stencil_geometry()
-        codec, tile = self.codec, self.cfg.tile
+        codec = self.codec
         out_sh = self.vec_sharding
 
         def interior_fn(u_phys: jax.Array, v_p: jax.Array) -> jax.Array:
             # slab-local gathers only: independent of the in-flight exchange
-            v_nbr = jnp.moveaxis(v_p[:, :, local], 2, 0)  # (8, 2, 3, S)
-            return kernel.fn(codec.planar_view(u_phys), v_nbr, **kw)
+            v_nbr = gather_neighbors(v_p, local)  # (8, 2, 3, S)
+            return kernel(codec.planar_view(u_phys), v_nbr)
 
         parts: dict[str, Any] = {
             "interior_j": jax.jit(interior_fn, out_shardings=out_sh),
@@ -962,7 +1031,7 @@ class ExecutionPlan:
             # +-t ghosts: the true remote neighbors of the boundary sites
             ghost_fwd_idx, ghost_bwd_idx = glob[3][bidx], glob[7][bidx]
             xyz_idx = glob[(0, 1, 2, 4, 5, 6), :][:, bidx]  # shard-local dirs
-            pad = (-n_boundary) % tile
+            pad = self._shard_pad(n_boundary)
 
             def exchange_fn(v_p: jax.Array) -> tuple[jax.Array, jax.Array]:
                 return v_p[:, :, ghost_fwd_idx], v_p[:, :, ghost_bwd_idx]
@@ -975,14 +1044,14 @@ class ExecutionPlan:
                 out_interior: jax.Array,
             ) -> jax.Array:
                 u_b = codec.planar_view(u_phys)[:, :, bidx]  # (2, 36|24, B)
-                v6 = jnp.moveaxis(v_p[:, :, xyz_idx], 2, 0)  # (6, 2, 3, B)
+                v6 = gather_neighbors(v_p, xyz_idx)  # (6, 2, 3, B)
                 v_nbr = jnp.concatenate(
                     [v6[:3], ghost_fwd[None], v6[3:], ghost_bwd[None]], axis=0
                 )  # (8, 2, 3, B) in direction order
                 if pad:
                     u_b = jnp.pad(u_b, ((0, 0), (0, 0), (0, pad)))
                     v_nbr = jnp.pad(v_nbr, ((0, 0), (0, 0), (0, 0), (0, pad)))
-                out_b = kernel.fn(u_b, v_nbr, **kw)[:, :, :n_boundary]
+                out_b = kernel(u_b, v_nbr)[:, :, :n_boundary]
                 return out_interior.at[:, :, bidx].set(out_b)
 
             parts.update(
@@ -1105,16 +1174,16 @@ class ExecutionPlan:
         the recompute feeds the kernel the same per-site inputs, hence the
         bit-identity with two depth-1 steps.
         """
-        kernel, kw = self._stencil_kernel_kwargs()
+        kernel = self._stencil_kernel()
         glob, _local, _bidx = self._stencil_geometry()
-        codec, tile = self.codec, self.cfg.tile
+        codec = self.codec
         interior_j, boundary_j = parts["interior_j"], parts["boundary_j"]
         n_boundary = parts["n_boundary"]
 
         ridx = np.concatenate([parts["ghost_fwd_idx"], parts["ghost_bwd_idx"]])
         ring_nbr_idx = glob[:, ridx]  # (8, 2B): every v site the ring reads
         n_ring = int(ridx.size)
-        rpad = (-n_ring) % tile
+        rpad = self._shard_pad(n_ring)
 
         def exchange2_fn(
             v_p: jax.Array,
@@ -1125,7 +1194,7 @@ class ExecutionPlan:
             return (
                 v_p[:, :, parts["ghost_fwd_idx"]],
                 v_p[:, :, parts["ghost_bwd_idx"]],
-                jnp.moveaxis(v_p[:, :, ring_nbr_idx], 2, 0),  # (8, 2, 3, 2B)
+                gather_neighbors(v_p, ring_nbr_idx),  # (8, 2, 3, 2B)
             )
 
         exchange2_j = jax.jit(exchange2_fn)
@@ -1139,7 +1208,7 @@ class ExecutionPlan:
                 ring_vnbr = jnp.pad(
                     ring_vnbr, ((0, 0), (0, 0), (0, 0), (0, rpad))
                 )
-            w_r = kernel.fn(u_r, ring_vnbr, **kw)[:, :, :n_ring]
+            w_r = kernel(u_r, ring_vnbr)[:, :, :n_ring]
             # step 1's output at (+t, -t) neighbors of the boundary — the
             # ghosts step 2's boundary pass would otherwise exchange
             return w_r[:, :, :n_boundary], w_r[:, :, n_boundary:]
@@ -1194,33 +1263,42 @@ class ExecutionPlan:
         every output component of the 8-direction stencil is then exactly
         (1, 0) (see :func:`init_stencil_canonical`)."""
         a_phys, _b, _init_s, _scatter_s = self.init_data()
-        _, v = init_stencil_canonical(self.cfg.shape.n_sites)
-        v_p = self.codec.pack_vec(v, self.padded_sites)
-        return a_phys, jax.device_put(v_p, self.vec_sharding)
+        _, v = layouts.on_host(init_stencil_canonical, self.cfg.shape.n_sites)
+        return a_phys, self.pack_rhs(v)
 
-    def unpack_vec(self, out_p: jax.Array) -> jax.Array:
-        """Planar stencil output -> canonical complex (n_sites, 3)."""
-        return self.codec.unpack_vec(out_p, self.cfg.shape.n_sites)
+    def unpack_vec(self, out_p: jax.Array, n_sites: int | None = None) -> jax.Array:
+        """Planar stencil output -> canonical complex ``(n_sites, 3)`` on
+        the host (default: the plan's live sites)."""
+        n = n_sites or self.cfg.shape.n_sites
+        return layouts.on_host(lambda x: self.codec.unpack_vec(x, n), out_p)
 
     def pack_gauge(self, u: jax.Array) -> jax.Array:
         """Canonical complex ``(n_sites, 4, 3, 3)`` gauge field -> physical
-        packed layout, zero-padded to ``padded_sites``.  Padding sites
-        self-neighbor in the stencil tables and carry zero links, so they
-        contribute nothing to any stencil or CG output."""
-        n = u.shape[0]
-        if n < self.padded_sites:
-            u = jnp.concatenate(
-                [u, jnp.zeros((self.padded_sites - n,) + u.shape[1:], u.dtype)]
-            )
-        return self.codec.pack(u)
+        packed layout under the plan's sharding, zero-padded to
+        ``padded_sites`` (packed on the host).  Padding sites self-neighbor
+        in the stencil tables and carry zero links, so they contribute
+        nothing to any stencil or CG output."""
+
+        def pack(u: jax.Array) -> jax.Array:
+            pad = self.padded_sites - u.shape[0]
+            if pad > 0:
+                u = jnp.concatenate([u, jnp.zeros((pad,) + u.shape[1:], u.dtype)])
+            return self.codec.pack(u)
+
+        return jax.device_put(layouts.on_host(pack, u), self.sharding)
 
     def pack_rhs(self, b: jax.Array) -> jax.Array:
         """Canonical complex ``(n_sites, 3)`` vector field -> planar
         ``(2, 3, padded_sites)`` under the plan's vector sharding (zero
         padding keeps every CG reduction over the padded array exact)."""
-        return jax.device_put(
-            self.codec.pack_vec(b, self.padded_sites), self.vec_sharding
-        )
+        v_p = layouts.on_host(self.codec.pack_vec, b, self.padded_sites)
+        return jax.device_put(v_p, self.vec_sharding)
+
+    def pack_links(self, b: jax.Array) -> jax.Array:
+        """Canonical link set ``(4, 3, 3)`` (or a batch of them) -> planar
+        ``(..., 2, 36)``, replicated over the plan's mesh."""
+        pack = self.codec.pack_b if b.ndim == 3 else jax.vmap(self.codec.pack_b)
+        return jax.device_put(layouts.on_host(pack, b), self.replicated)
 
     def verify_stencil(self, out_p: jax.Array) -> bool:
         """Fixed-point check for :meth:`init_stencil_data` inputs: every
@@ -1344,19 +1422,17 @@ class ExecutionPlan:
             self._cg_applies[key] = composed
             return composed
 
-        kernel, kw = self._stencil_kernel_kwargs(CG_VARIANT)
+        kernel = self._stencil_kernel(CG_VARIANT)
         glob, local, bidx = self._stencil_geometry()
-        codec, tile = self.codec, self.cfg.tile
+        codec = self.codec
         vec_sh = self.vec_sharding
         n_boundary = int(bidx.size)
         gather_idx = local if (overlap and n_boundary) else glob
 
         def whole_fn(u_phys, r_p, p_p, coefs):
-            r_nbr = jnp.moveaxis(r_p[:, :, gather_idx], 2, 0)  # (8, 2, 3, S)
-            p_nbr = jnp.moveaxis(p_p[:, :, gather_idx], 2, 0)
-            return kernel.fn(
-                codec.planar_view(u_phys), r_nbr, p_nbr, r_p, p_p, coefs, **kw
-            )
+            r_nbr = gather_neighbors(r_p, gather_idx)  # (8, 2, 3, S)
+            p_nbr = gather_neighbors(p_p, gather_idx)
+            return kernel(codec.planar_view(u_phys), r_nbr, p_nbr, r_p, p_p, coefs)
 
         whole_j = jax.jit(whole_fn, out_shardings=(vec_sh, vec_sh))
 
@@ -1383,7 +1459,7 @@ class ExecutionPlan:
         # runs once on the merged array via the shared epilogue
         ghost_fwd_idx, ghost_bwd_idx = glob[3][bidx], glob[7][bidx]
         xyz_idx = glob[(0, 1, 2, 4, 5, 6), :][:, bidx]
-        pad = (-n_boundary) % tile
+        pad = self._shard_pad(n_boundary)
 
         def exchange_fn(r_p, p_p):
             return (
@@ -1393,8 +1469,8 @@ class ExecutionPlan:
 
         def boundary_fn(u_phys, r_p, p_p, r_gf, r_gb, p_gf, p_gb, coefs, s_i):
             u_b = codec.planar_view(u_phys)[:, :, bidx]  # (2, 36|24, B)
-            r6 = jnp.moveaxis(r_p[:, :, xyz_idx], 2, 0)  # (6, 2, 3, B)
-            p6 = jnp.moveaxis(p_p[:, :, xyz_idx], 2, 0)
+            r6 = gather_neighbors(r_p, xyz_idx)  # (6, 2, 3, B)
+            p6 = gather_neighbors(p_p, xyz_idx)
             r_nbr = jnp.concatenate(
                 [r6[:3], r_gf[None], r6[3:], r_gb[None]], axis=0
             )
@@ -1408,7 +1484,7 @@ class ExecutionPlan:
                 p_nbr = jnp.pad(p_nbr, ((0, 0), (0, 0), (0, 0), (0, pad)))
                 r_b = jnp.pad(r_b, ((0, 0), (0, 0), (0, pad)))
                 p_b = jnp.pad(p_b, ((0, 0), (0, 0), (0, pad)))
-            _p_new_b, s_b = kernel.fn(u_b, r_nbr, p_nbr, r_b, p_b, coefs, **kw)
+            _p_new_b, s_b = kernel(u_b, r_nbr, p_nbr, r_b, p_b, coefs)
             return s_i.at[:, :, bidx].set(s_b[:, :, :n_boundary])
 
         exchange_j = jax.jit(exchange_fn)
@@ -1681,9 +1757,11 @@ class ExecutionPlan:
 
     # -- views / checks --------------------------------------------------------
 
-    def unpack(self, c_phys: jax.Array) -> jax.Array:
-        """Physical C -> canonical complex, sliced to the live lattice sites."""
-        return self.codec.unpack(c_phys, self.cfg.shape.n_sites)
+    def unpack(self, c_phys: jax.Array, n_sites: int | None = None) -> jax.Array:
+        """Physical C -> canonical complex on the host, sliced to ``n_sites``
+        (default: the plan's live lattice sites)."""
+        n = n_sites or self.cfg.shape.n_sites
+        return layouts.on_host(lambda c: self.codec.unpack(c, n), c_phys)
 
     def verify(self, c_phys: jax.Array) -> bool:
         """su3_bench check: with A=(1,0), B=(1/3,0) every C element is (1,0).
@@ -1770,26 +1848,55 @@ class BatchedLatticeRunner:
             raw = make_raw_step(
                 self.plan.codec, self.plan.kernel, tile=self.cfg.tile, k_iters=k
             )
-            self._steps[k] = jax.jit(jax.vmap(raw), out_shardings=self._sharding)
+            a_spec = self._sharding.spec  # whole lattices per device
+            b_spec = P(a_spec[0], None, None)
+            self._steps[k] = jax.jit(
+                site_local(jax.vmap(raw), self.mesh, (a_spec, b_spec), a_spec),
+                out_shardings=self._sharding,
+            )
         return self._steps[k]
 
+    def batch_sharding(self, bsz: int) -> NamedSharding:
+        """Placement of a ``bsz``-lattice physical batch: whole lattices per
+        device when ``bsz`` divides the mesh, else each lattice site-sharded."""
+        if bsz % self.n_devices == 0:
+            return self._sharding
+        return NamedSharding(self.mesh, P(None, *self.plan.sharding.spec))
+
     def pack_batch(self, a: jax.Array) -> jax.Array:
-        """Canonical (B, n_sites, 4, 3, 3) complex -> batched physical form."""
+        """Canonical (B, n_sites, 4, 3, 3) complex -> batched physical form,
+        packed on the host and placed by :meth:`batch_sharding`."""
         if a.shape[1] > self.plan.padded_sites:
             raise ValueError(
                 f"batch carries {a.shape[1]} sites > plan capacity "
                 f"{self.plan.padded_sites} (L={self.cfg.L}, tile={self.cfg.tile})"
             )
-        pad = self.plan.padded_sites - a.shape[1]
-        if pad:
-            a = jnp.concatenate(
-                [a, jnp.zeros((a.shape[0], pad) + a.shape[2:], a.dtype)], axis=1
-            )
-        return jax.vmap(self.plan.codec.pack)(a)
+
+        def pack(a: jax.Array) -> jax.Array:
+            pad = self.plan.padded_sites - a.shape[1]
+            if pad:
+                a = jnp.concatenate(
+                    [a, jnp.zeros((a.shape[0], pad) + a.shape[2:], a.dtype)], axis=1
+                )
+            return jax.vmap(self.plan.codec.pack)(a)
+
+        return jax.device_put(
+            layouts.on_host(pack, a), self.batch_sharding(a.shape[0])
+        )
+
+    def pack_vec_batch(self, v: jax.Array) -> jax.Array:
+        """Canonical (B, n_sites, 3) vector fields -> planar (B, 2, 3, S),
+        packed on the host and placed by :meth:`batch_sharding`."""
+        codec, n = self.plan.codec, self.plan.padded_sites
+        v_p = layouts.on_host(jax.vmap(lambda x: codec.pack_vec(x, n)), v)
+        return jax.device_put(v_p, self.batch_sharding(v.shape[0]))
 
     def unpack_batch(self, c_phys: jax.Array, n_sites: int | None = None) -> jax.Array:
+        """Batched physical -> canonical complex on the host."""
         n = n_sites if n_sites is not None else self.cfg.shape.n_sites
-        return jax.vmap(lambda x: self.plan.codec.unpack(x, n))(c_phys)
+        return layouts.on_host(
+            jax.vmap(lambda x: self.plan.codec.unpack(x, n)), c_phys
+        )
 
     def run(self, a_batch: jax.Array, b_batch: jax.Array, k: int = 1) -> jax.Array:
         """Batched physical (B, ...) x planar B (B, 2, 36) -> physical C batch."""
@@ -1807,6 +1914,6 @@ class BatchedLatticeRunner:
         """Canonical batched entry: a (B, S, 4, 3, 3), b (B, 4, 3, 3) complex."""
         n_sites = a.shape[1]
         a_phys = self.pack_batch(a)
-        b_p = jax.vmap(self.plan.codec.pack_b)(b)
+        b_p = self.plan.pack_links(b)
         c_phys = self.run(a_phys, b_p, k=k)
         return self.unpack_batch(c_phys, n_sites)

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 
 def pipeline_forward(
@@ -76,7 +75,7 @@ def pipeline_forward(
         jax.tree.map(lambda _: P(axis), stage_params),
         P(),  # microbatches replicated into every rank (stage 0 reads them)
     )
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         per_rank, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
     return fn(stage_params, x_microbatches)

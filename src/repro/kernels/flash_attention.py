@@ -30,9 +30,7 @@ NEG_INF = -1e30
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, sq: int, g: int,
                   causal: bool, scale: float):
     """One (batch-head, q-block) grid step."""
-    # size-1 leading axis is read whole and squeezed: bare int ref indexers
-    # hit a discharge-rule bug in jax 0.4.x interpret mode
-    q = q_ref[...][0].astype(jnp.float32) * scale  # (block_qg, d)
+    q = q_ref[0].astype(jnp.float32) * scale  # (block_qg, d)
     block_qg, d = q.shape
     skv = k_ref.shape[1]
     nk = skv // block_k
@@ -43,8 +41,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, sq: int, g: int,
 
     def body(ik, carry):
         acc, m, l = carry
-        k_blk = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(ik * block_k, block_k), slice(None)))[0]
-        v_blk = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(ik * block_k, block_k), slice(None)))[0]
+        k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :]
+        v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :]
         s = q @ k_blk.astype(jnp.float32).T  # (block_qg, block_k) on the MXU
         if causal:
             k_pos = ik * block_k + jax.lax.iota(jnp.int32, block_k)

@@ -1,7 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = os.environ.get("REPRO_XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = os.environ.get("REPRO_XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST run before any jax import: jax locks the device count on first init.
-# REPRO_XLA_FLAGS lets tests use smaller placeholder device counts.
+# REPRO_XLA_FLAGS lets tests use smaller placeholder device counts.  Every
+# dry-run is a CPU simulation over placeholder devices, so it never claims an
+# accelerator another process holds.
 
 # Multi-pod dry-run: lower + compile every (architecture x input-shape x mesh)
 # cell with ShapeDtypeStruct stand-ins (no allocation), print memory/cost
@@ -38,7 +42,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import ALL_ARCHS, SHAPES, get_config, shape_applicable
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import roofline
@@ -198,7 +201,7 @@ def lower_cell(
     batch_sds = registry.input_specs(cfg, shape)
     b_sh = sharding.batch_shardings(batch_sds, mesh, rules)
 
-    with compat.set_mesh(mesh), act_sharding.use_rules(mesh, rules):
+    with jax.set_mesh(mesh), act_sharding.use_rules(mesh, rules):
         if shape.kind == "train":
             opt_cfg = adamw.AdamWConfig(moment_dtype=policy.moment_dtype)
             m_dt = jnp.dtype(policy.moment_dtype)
@@ -464,6 +467,7 @@ def su3_fig7_launch(
     tmpdir = tempfile.mkdtemp(prefix="su3_fig7_")
     env = dict(os.environ)
     env["REPRO_XLA_FLAGS"] = f"--xla_force_host_platform_device_count={max_dev}"
+    env["JAX_PLATFORMS"] = "cpu"  # simulated controllers never take the chip
     env.setdefault("PYTHONPATH", str(pathlib.Path(__file__).resolve().parents[2]))
     for rank in range(controllers):
         out = pathlib.Path(tmpdir) / f"controller_{rank}.json"

@@ -31,13 +31,8 @@ DEVICE_AXIS = "devices"
 
 
 def _mk(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType landed in jax 0.5.x (explicit-sharding work); Auto
-    # is the default there, so omitting axis_types on 0.4.x builds the
-    # identical mesh.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -99,29 +99,6 @@ def count_params(tree: Any) -> int:
 import functools as _functools
 
 
-@jax.custom_vjp
-def grad_safe_barrier(x: jax.Array) -> jax.Array:
-    """``optimization_barrier`` usable under autodiff on every jax we run.
-
-    jax 0.4.x has no differentiation rule for the primitive; this custom VJP
-    applies the barrier to the primal on the forward pass and to the
-    cotangent on the backward pass (which is also the semantically right
-    pin — both directions of the residual stream stay per-layer).
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-def _gsb_fwd(x: jax.Array):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _gsb_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-grad_safe_barrier.defvjp(_gsb_fwd, _gsb_bwd)
-
-
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
     """RMSNorm with f32 stats but NO materialized f32 copy of x.

@@ -182,7 +182,7 @@ def _scan_stack(
         # Barrier: stops XLA hoisting the f32 upcast of the residual slice
         # out of the backward scan as a full-stack fp32 copy (observed:
         # +22 GiB/device on the qwen3 train cell without it).
-        xc = common.grad_safe_barrier(xc)
+        xc = jax.lax.optimization_barrier(xc)
         y, new_cache, aux = layer_apply(
             lp, xc, cfg, positions=positions, moe_layer=moe_layer,
             cache=lcache, cur_len=cur_len, q_chunk=q_chunk, kv_chunk=kv_chunk,

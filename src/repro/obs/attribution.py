@@ -59,13 +59,14 @@ def _spans(records: Iterable[Any]) -> list[dict[str, Any]]:
 
 
 def _predictors():
-    """(predict_pipeline, predict_stencil, Candidates, hw) or None."""
+    """(autotune module, peaks of the local device) or (None, None)."""
     try:
-        from repro.core import autotune, roofline
+        import jax
 
-        return autotune, roofline.TPU_V5E
-    except Exception:  # pragma: no cover - jax-less trace readers
+        from repro.core import autotune, roofline
+    except ImportError:  # pragma: no cover - jax-less trace readers
         return None, None
+    return autotune, roofline.hardware_for_device(jax.devices()[0])
 
 
 # --------------------------------------------------------------------- joins

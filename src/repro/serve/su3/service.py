@@ -78,6 +78,8 @@ import random
 import time
 from typing import Any
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -90,6 +92,7 @@ from repro.core.su3.plan import (
     BatchedLatticeRunner,
     CGDivergedError,
     EngineConfig,
+    site_local,
 )
 from repro.kernels.su3_stencil import (
     CG_ITER_FLOPS_PER_SITE,
@@ -307,39 +310,51 @@ class ServiceConfig:
             )
 
 
+def _host_stack(xs: list[Any], pad: int) -> np.ndarray:
+    """Canonical request arrays stacked on the host, with ``pad`` zero
+    entries appended (canonical arrays never go onto an accelerator)."""
+    a = np.stack([np.asarray(x) for x in xs])
+    if pad:
+        a = np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+    return a
+
+
 class _ChainArrays:
     """Device-array half of one in-flight chain (scheduling half:
     :class:`~repro.serve.su3.batcher.InflightChain`).
 
     Holds the physical lattice batch ``a_phys (slots, ...)`` and planar B
     batch ``b_p (slots, 2, 36)``; free slots carry zero lattices (they step
-    harmlessly and are charged as padding by the metrics).
+    harmlessly and are charged as padding by the metrics).  Both are made
+    on the device in the physical form; canonical arrays stay on the host.
     """
 
     def __init__(self, runner: BatchedLatticeRunner, slots: int):
         self.runner = runner
-        zero_canon = jnp.zeros(
-            (slots, runner.plan.padded_sites, 4, 3, 3), jnp.complex64
+        plan = runner.plan
+        phys = jax.eval_shape(
+            jax.vmap(plan.codec.pack),
+            jax.ShapeDtypeStruct((slots, plan.padded_sites, 4, 3, 3), jnp.complex64),
         )
-        self.a_phys = jax.vmap(runner.plan.codec.pack)(zero_canon)
-        self.b_p = jnp.zeros(
-            (slots, 2, 36), runner.plan.codec.word_dtype
-        )
+        self.a_phys = jax.device_put(
+            jnp.zeros(phys.shape, phys.dtype), runner.batch_sharding(slots))
+        self.b_p = jax.device_put(
+            jnp.zeros((slots, 2, 36), plan.codec.word_dtype), plan.replicated)
 
     def seat(self, slot: int, a: jax.Array, b: jax.Array) -> None:
-        """Pack one request's canonical (A, B) into ``slot``."""
-        a_one = self.runner.pack_batch(a[None])[0]
-        b_one = self.runner.plan.codec.pack_b(b)
-        self.a_phys = self.a_phys.at[slot].set(a_one)
-        self.b_p = self.b_p.at[slot].set(b_one)
+        """Pack one request's canonical (A, B) into ``slot``, zero-padding
+        its sites up to the runner's capacity."""
+        self.a_phys = self.a_phys.at[slot].set(self.runner.pack_batch(a[None])[0])
+        self.b_p = self.b_p.at[slot].set(self.runner.plan.pack_links(b))
 
     def advance(self) -> None:
         """One vmapped physical multiply over every slot (k=1)."""
         self.a_phys = self.runner.run(self.a_phys, self.b_p, k=1)
 
     def result(self, slot: int, n_sites: int) -> jax.Array:
-        """Canonical complex C of ``slot``, sliced to the live sites."""
-        return self.runner.plan.codec.unpack(self.a_phys[slot], n_sites)
+        """Canonical complex C of ``slot`` on the host, sliced to the live
+        sites."""
+        return self.runner.plan.unpack(self.a_phys[slot], n_sites)
 
     def clear(self, slot: int) -> None:
         """Zero a freed slot (its stale lattice would otherwise keep
@@ -348,7 +363,7 @@ class _ChainArrays:
         self.b_p = self.b_p.at[slot].set(jnp.zeros_like(self.b_p[slot]))
 
 
-class _SlotTableArrays:
+class _SlotTableArrays(_ChainArrays):
     """Device-array half of one host's megakernel slot table (scheduling
     half: :class:`~repro.serve.su3.batcher.SlotTable`).
 
@@ -359,38 +374,17 @@ class _SlotTableArrays:
     """
 
     def __init__(self, runner: BatchedLatticeRunner, slots: int, max_k: int):
-        self.runner = runner
+        super().__init__(runner, slots)
         self.slots = slots
         self.max_k = max_k
         self.cap_L = runner.cfg.L
-        plan = runner.plan
-        zero_canon = jnp.zeros((slots, plan.padded_sites, 4, 3, 3), jnp.complex64)
-        self.a_phys = jax.vmap(plan.codec.pack)(zero_canon)
-        self.b_p = jnp.zeros((slots, 2, 36), plan.codec.word_dtype)
-        self._step = plan.fused_batched_step(slots, max_k=max_k)
-
-    def seat(self, slot: int, a: jax.Array, b: jax.Array) -> None:
-        """Pack one request's canonical (A, B) into ``slot``, zero-padding
-        its sites up to the table's capacity."""
-        a_one = self.runner.pack_batch(a[None])[0]
-        b_one = self.runner.plan.codec.pack_b(b)
-        self.a_phys = self.a_phys.at[slot].set(a_one)
-        self.b_p = self.b_p.at[slot].set(b_one)
+        self._step = runner.plan.fused_batched_step(slots, max_k=max_k)
 
     def advance(self, slot_k: list[int]) -> None:
         """ONE megakernel dispatch: slot ``i`` advances ``slot_k[i]``
         multiplies in-kernel (0 = pass-through)."""
         ks = jnp.asarray(slot_k, jnp.int32)
         self.a_phys = self._step(self.a_phys, self.b_p, ks)
-
-    def result(self, slot: int, n_sites: int) -> jax.Array:
-        """Canonical complex C of ``slot``, sliced to the live sites."""
-        return self.runner.plan.codec.unpack(self.a_phys[slot], n_sites)
-
-    def clear(self, slot: int) -> None:
-        """Zero a freed slot."""
-        self.a_phys = self.a_phys.at[slot].set(jnp.zeros_like(self.a_phys[slot]))
-        self.b_p = self.b_p.at[slot].set(jnp.zeros_like(self.b_p[slot]))
 
 
 class SU3Service:
@@ -600,23 +594,18 @@ class SU3Service:
             runner = self.runner_for(L)
             n_sites = L**4
             for bsz in batch_sizes:
-                a = jnp.zeros((bsz, n_sites, 4, 3, 3), jnp.complex64)
-                b = jnp.zeros((bsz, 4, 3, 3), jnp.complex64)
+                a = np.zeros((bsz, n_sites, 4, 3, 3), np.complex64)
+                b = np.zeros((bsz, 4, 3, 3), np.complex64)
                 for k in ks:
                     runner.multiply(a, b, k=k).block_until_ready()
                     self._seen_shapes.add(self._shape_key(runner, L, k, bsz))
                 if stencil:
-                    plan = runner.plan
                     host = self.router.host_for(L)
                     dispatched = bsz + (-bsz) % runner.n_devices
-                    u_w = jnp.zeros(
-                        (dispatched, n_sites, 4, 3, 3), jnp.complex64
-                    )
-                    v = jnp.zeros((dispatched, n_sites, 3), jnp.complex64)
+                    u_w = np.zeros((dispatched, n_sites, 4, 3, 3), np.complex64)
+                    v = np.zeros((dispatched, n_sites, 3), np.complex64)
                     u_phys = runner.pack_batch(u_w)
-                    v_p = jax.vmap(
-                        lambda x: plan.codec.pack_vec(x, plan.padded_sites)
-                    )(v)
+                    v_p = runner.pack_vec_batch(v)
                     step = self._stencil_step_for(runner, host, L)
                     step(u_phys, v_p).block_until_ready()
                     self._seen_shapes.add(("stencil", L, dispatched))
@@ -1411,15 +1400,8 @@ class SU3Service:
                 if quarantined:
                     self._quarantine(host)
                 return 0
-        a = jnp.stack([r.a for r in reqs])
-        b = jnp.stack([r.b for r in reqs])
-        if batch.pad:
-            a = jnp.concatenate(
-                [a, jnp.zeros((batch.pad,) + a.shape[1:], a.dtype)], axis=0
-            )
-            b = jnp.concatenate(
-                [b, jnp.zeros((batch.pad,) + b.shape[1:], b.dtype)], axis=0
-            )
+        a = _host_stack([r.a for r in reqs], batch.pad)
+        b = _host_stack([r.b for r in reqs], batch.pad)
         shape_key = self._shape_key(runner, batch.L, batch.k, batch.padded_size)
         cold = shape_key not in self._seen_shapes
         t0 = time.perf_counter()
@@ -1475,11 +1457,14 @@ class SU3Service:
         step = self._stencil_steps.get(key)
         if step is None:
             plan = runner.plan
-            axes = plan.site_axes
-            batch_axis = axes if len(axes) > 1 else axes[0]
-            out_sh = NamedSharding(plan.mesh, P(batch_axis, None, None, None))
+            u_spec = plan.lattice_batch_sharding().spec
+            v_spec = P(u_spec[0], None, None, None)
             step = jax.jit(
-                jax.vmap(plan.raw_stencil_reference()), out_shardings=out_sh
+                site_local(
+                    jax.vmap(plan.raw_stencil_reference(sharded=False)),
+                    plan.mesh, (u_spec, v_spec), v_spec,
+                ),
+                out_shardings=NamedSharding(plan.mesh, v_spec),
             )
             self._stencil_steps[key] = step
         return step
@@ -1508,17 +1493,8 @@ class SU3Service:
         # (whole lattices per device, as the multiply path's run() pads)
         dispatched = batch.padded_size + (-batch.padded_size) % runner.n_devices
         pad = dispatched - len(reqs)
-        u = jnp.stack([r.a for r in reqs])
-        v = jnp.stack([r.b for r in reqs])
-        if pad:
-            u = jnp.concatenate(
-                [u, jnp.zeros((pad,) + u.shape[1:], u.dtype)], axis=0
-            )
-            v = jnp.concatenate(
-                [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)], axis=0
-            )
-        u_phys = runner.pack_batch(u)
-        v_p = jax.vmap(lambda x: plan.codec.pack_vec(x, plan.padded_sites))(v)
+        u_phys = runner.pack_batch(_host_stack([r.a for r in reqs], pad))
+        v_p = runner.pack_vec_batch(_host_stack([r.b for r in reqs], pad))
         step = self._stencil_step_for(runner, host, batch.L)
         shape_key = ("stencil", batch.L, dispatched)
         cold = shape_key not in self._seen_shapes
@@ -1552,7 +1528,7 @@ class SU3Service:
                 cold=cold)
         done_s = time.perf_counter()
         for i, r in enumerate(reqs):
-            self._results[r.req_id] = plan.codec.unpack_vec(out_p[i], n_sites)
+            self._results[r.req_id] = plan.unpack_vec(out_p[i], n_sites)
             self.metrics.record_completion(
                 done_s - r.arrival_s, tenant=r.tenant, slo=r.slo)
             if self.tracer.enabled:
@@ -1585,8 +1561,8 @@ class SU3Service:
                     runner = cand
                     break
         plan = runner.plan
-        u_phys = plan.pack_gauge(jnp.asarray(req.a))
-        b_p = plan.pack_rhs(jnp.asarray(req.b))
+        u_phys = plan.pack_gauge(req.a)
+        b_p = plan.pack_rhs(req.b)
         state = plan.cg_state_init(b_p)
         b_rs = float(jax.device_get(state["rs"]))  # r_0 = b, so rs_0 = ||b||^2
         active = {
@@ -1937,15 +1913,17 @@ class SU3Service:
             shape_key = ("mega", arrays.cap_L, table.slots, self.cfg.chain_horizon)
             cold = shape_key not in self._seen_shapes
             live = table.live
+            guard = self.faults.enabled or self.cfg.numerics_guard
+            # the megakernel donates the slot table: roll back to a copy
+            prev_a = jnp.copy(arrays.a_phys) if guard and not degraded else None
             t0 = time.perf_counter()
-            prev_a = arrays.a_phys
             if degraded:
                 for slot, req, _rem in occupants:
                     if not ks[slot]:
                         continue
                     a_mid = arrays.result(slot, req.n_sites)
                     c = self.runner_for(req.L, host).multiply(
-                        a_mid[None], jnp.asarray(req.b)[None], k=ks[slot])[0]
+                        a_mid[None], req.b[None], k=ks[slot])[0]
                     arrays.seat(slot, c, req.b)
             else:
                 arrays.advance(ks)
@@ -1954,8 +1932,7 @@ class SU3Service:
                         arrays.a_phys, host, "multiply")
             arrays.a_phys.block_until_ready()
             step_s = time.perf_counter() - t0
-            if not degraded and (self.faults.enabled or self.cfg.numerics_guard) \
-                    and not self._finite(arrays.a_phys):
+            if prev_a is not None and not self._finite(arrays.a_phys):
                 arrays.a_phys = prev_a  # retried advance is bitwise clean
                 quarantined = self.health.record_failure(
                     host, "non-finite output")
@@ -1968,7 +1945,7 @@ class SU3Service:
                 else:
                     self.metrics.record_queue_depth(self.queued())
                 return 0
-            if not degraded and (self.faults.enabled or self.cfg.numerics_guard):
+            if prev_a is not None:
                 self.health.record_success(host)
             self._seen_shapes.add(shape_key)
             dispatch_flops = sum(

@@ -15,7 +15,6 @@ _PHASE1 = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.models import registry, common
@@ -32,7 +31,7 @@ mesh = make_mesh((2, 2), ("data", "model"))
 rules = sharding.default_rules(mesh)
 api = registry.get(cfg)
 p_sh = sharding.param_shardings(api.spec(cfg), mesh, rules)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     params = api.init(jax.random.PRNGKey(0), cfg)
     params = jax.tree.map(jax.device_put, params, p_sh)
     opt_cfg = adamw.AdamWConfig(peak_lr=1e-3, warmup_steps=2, total_steps=20)
@@ -53,7 +52,6 @@ import os, sys
 # two of four hosts died -> planner gives a 2-device mesh
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax, jax.numpy as jnp
-from repro import compat
 from repro.configs import get_config
 from repro.models import registry
 from repro.distributed import sharding
@@ -80,7 +78,7 @@ template = (template_p, adamw.init(template_p, opt_cfg))
 mgr = CheckpointManager(CheckpointConfig(ckpt_dir))
 (params, opt), extra, start = mgr.restore(template)
 # reshard onto the SURVIVOR mesh: host arrays -> new shardings
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     params = jax.tree.map(lambda x, s: jax.device_put(jnp.asarray(x), s), params, p_sh)
     opt = {"m": jax.tree.map(lambda x, s: jax.device_put(jnp.asarray(x), s), opt["m"], p_sh),
            "v": jax.tree.map(lambda x, s: jax.device_put(jnp.asarray(x), s), opt["v"], p_sh),

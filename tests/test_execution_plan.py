@@ -139,6 +139,25 @@ def test_batched_lattice_runner_fused_chain():
     np.testing.assert_allclose(np.asarray(fused), np.asarray(seq), rtol=1e-4, atol=1e-4)
 
 
+def test_canonical_arrays_stay_on_the_host():
+    # a TPU pads the canonical (S, 4, 3, 3) form's minor dims to 128 lanes:
+    # packing and unpacking run on the host CPU, only physical forms are
+    # placed where the plan shards them
+    p = plan.build_plan(EngineConfig(L=2, tile=16))
+    cpu = jax.devices("cpu")[0]
+    a = np.asarray(_random_lattice(jax.random.PRNGKey(3), 16))
+    a_phys = p.pack_gauge(a)
+    assert a_phys.sharding == p.sharding
+    b_p = p.pack_links(np.asarray(_random_b(jax.random.PRNGKey(4))))
+    assert b_p.sharding == p.replicated and b_p.shape == (2, 36)
+    c = p.unpack(p.step(a_phys, b_p))
+    assert c.committed and c.devices() == {cpu}
+    assert bool(jnp.array_equal(p.unpack(a_phys), a))
+    seen = []
+    out = layouts.on_host(lambda x, n: seen.append(n) or x + 1, jnp.ones(3), 7)
+    assert seen == [7] and out.devices() == {cpu}
+
+
 # -- mixed-precision (bf16-storage / f32-accumulate) plans ---------------------
 # (persistent autotune cache coverage lives in tests/test_autotune_cache.py)
 

@@ -75,7 +75,8 @@ def test_iteration_granularity_bit_identical_to_single_step(layout, dtype, accum
     plan = _plan(layout, dtype, accum)
     a_phys, b_p = _rand_batch(plan, SLOTS)
     ks = jnp.array([0, 1, 1, 0], jnp.int32)
-    c = plan.fused_batched_step(SLOTS, max_k=4)(a_phys, b_p, ks)
+    # the step donates its slot table; the reference below reads a_phys
+    c = plan.fused_batched_step(SLOTS, max_k=4)(jnp.copy(a_phys), b_p, ks)
     ref = jnp.stack([
         plan.step(a_phys[s], b_p[s]) if int(ks[s]) else a_phys[s]
         for s in range(SLOTS)
@@ -92,7 +93,8 @@ def test_deep_chains_pure_dtype_bit_identical_to_sequential_steps(layout, dtype)
     plan = _plan(layout, dtype)
     a_phys, b_p = _rand_batch(plan, SLOTS)
     ks = jnp.array([1, 2, 3, 4], jnp.int32)
-    c = plan.fused_batched_step(SLOTS, max_k=4)(a_phys, b_p, ks)
+    # the step donates its slot table; the reference below reads a_phys
+    c = plan.fused_batched_step(SLOTS, max_k=4)(jnp.copy(a_phys), b_p, ks)
     ref = []
     for s in range(SLOTS):
         x = a_phys[s]
@@ -107,7 +109,8 @@ def test_deep_chains_mixed_precision_bit_identical_to_fused_step(layout):
     plan = _plan(layout, "bfloat16", "float32")
     a_phys, b_p = _rand_batch(plan, SLOTS)
     ks = jnp.array([1, 2, 3, 4], jnp.int32)
-    c = plan.fused_batched_step(SLOTS, max_k=4)(a_phys, b_p, ks)
+    # the step donates its slot table; the reference below reads a_phys
+    c = plan.fused_batched_step(SLOTS, max_k=4)(jnp.copy(a_phys), b_p, ks)
     ref = jnp.stack([
         plan.fused_step(int(ks[s]))(a_phys[s], b_p[s]) for s in range(SLOTS)
     ])
@@ -118,7 +121,7 @@ def test_slot_k_clamped_to_static_max():
     plan = _plan(Layout.SOA)
     a_phys, b_p = _rand_batch(plan, 2)
     c = plan.fused_batched_step(2, max_k=2)(
-        a_phys, b_p, jnp.array([5, 2], jnp.int32))
+        jnp.copy(a_phys), b_p, jnp.array([5, 2], jnp.int32))
     ref = plan.fused_batched_step(2, max_k=2)(
         a_phys, b_p, jnp.array([2, 2], jnp.int32))
     assert bool(jnp.all(c == ref))

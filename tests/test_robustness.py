@@ -546,6 +546,38 @@ def test_megakernel_dispatch_failure_degrades_to_chained_path():
 
 
 @pytest.mark.chaos
+def test_megakernel_nonfinite_output_rolls_back_past_donation():
+    # the megakernel donates its slot table, so the pre-dispatch state it
+    # rolls back to on a poisoned output must be a copy, not the donated
+    # buffer; the retried advance then finishes bitwise equal to a clean run
+    mega = dict(continuous=True, megakernel=True, chain_slots=2,
+                chain_horizon=1,
+                batcher=BatcherConfig(max_batch=2, warm_batch_sizes=(2,),
+                                      max_queue_depth=8))
+    reqs = [_rand_ab(300 + i) for i in range(2)]
+    clean = _svc(**mega)
+    clean_ids = [clean.submit(a, b, k=3) for a, b in reqs]
+    clean.run_until_drained()
+
+    plan = FaultPlan(6, {"kernel": FaultSpec(probability=1.0,
+                                             actions=("nan",), max_fires=1)})
+    svc = _storm_svc(plan, **mega)
+    ids = [svc.submit(a, b, k=3) for a, b in reqs]
+    svc.step()  # admit + the poisoned dispatch, rolled back
+    assert plan.fired == 1
+    (_table, arrays), = svc._tables.values()
+    assert not arrays.a_phys.is_deleted()
+    donated = arrays.a_phys
+    svc.run_until_drained()
+    assert donated.is_deleted()  # the retried advance donated the rollback
+    assert svc.metrics.snapshot()["retries"] >= 1
+    for rid, rid0 in zip(ids, clean_ids):
+        out = svc.pop_result(rid)
+        assert not isinstance(out, Exception)
+        assert bool(jnp.array_equal(out, clean.pop_result(rid0)))
+
+
+@pytest.mark.chaos
 def test_solve_kernel_poison_retries_to_the_clean_answer():
     # one poisoned CG residual -> the numerics guard unseats the solve, the
     # retry re-runs it from scratch, and the answer matches the clean run

@@ -168,3 +168,28 @@ def test_xeon_piuma_models_match_paper():
     assert roofline.PIUMA_CORE.ridge_flops_per_byte < 3.0
     # PIUMA compute-bound 8 GF/s FMA; bandwidth-bound 4.32 GF/s at AI=0.675
     assert roofline.PIUMA_CORE.hbm_bw * 0.675 == pytest.approx(4.32e9, rel=0.01)
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from types import SimpleNamespace as Dev
+
+    v5e = Dev(platform="tpu", device_kind="TPU v5 lite")
+    assert roofline.hardware_for_device(v5e) is roofline.TPU_V5E
+    assert roofline.TPU_V5E.hbm_bw == 819e9 and roofline.TPU_V5E.peak_flops == 197e12
+    # CPU rehearsals rank against the v5e model; an unrecorded chip raises
+    assert roofline.hardware_for_device(jax.devices()[0]) is roofline.TPU_V5E
+    with pytest.raises(ValueError, match="no peaks recorded"):
+        roofline.hardware_for_device(Dev(platform="tpu", device_kind="TPU v9"))
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    import pathlib
+
+    from repro import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.compile_cache_dir() is None  # JAX reads the env
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = pathlib.Path(compile_cache.compile_cache_dir())
+    assert path.name == ".jax_cache"
+    assert (path.parent / "src" / "repro" / "compile_cache.py").is_file()
