@@ -394,8 +394,9 @@ def traced_serving(
     trace_dir = os.path.dirname(trace_prefix)
     if trace_dir:
         os.makedirs(trace_dir, exist_ok=True)  # gitignored artifacts/ home
-    n_records = tracer.to_jsonl(jsonl_path)
-    tracer.to_chrome_trace(chrome_path, metadata=provenance_block())
+    prov = provenance_block()
+    n_records = tracer.to_jsonl(jsonl_path, metadata=prov) - 1  # less meta
+    tracer.to_chrome_trace(chrome_path, metadata=prov)
     # tracing cost shows up in the replay wall of the identical Poisson
     # schedule (busy_s can NOT see it: spans are recorded outside the timed
     # dispatch region by design); at quick scale the delta is noise-level —
@@ -416,7 +417,8 @@ def traced_serving(
         "lifecycle_covered": lifecycle <= names,
         "phases_covered": phases <= names,
         "span_names": sorted(names),
-        "attribution_rows": len(attribution_report(tracer.spans())),
+        "attribution_rows": len(attribution_report(
+            tracer.spans(), prov["device_kind"])),
         "trace_jsonl": jsonl_path,
         "trace_chrome": chrome_path,
     }

@@ -95,6 +95,7 @@ print(json.dumps({
     "t_serial_s": t_serial, "t_overlap_s": t_overlap,
     "halo": plan.stencil_halo().as_dict(),
     "spans": [s.as_dict() for s in plan.tracer.spans()],
+    "device_kind": jax.devices()[0].device_kind,
 }))
 """
 
@@ -256,18 +257,21 @@ def _overlap_identity_row(L: int, tile: int, reps: int) -> dict:
             dominant_phase=(max(acct["phase_s"], key=acct["phase_s"].get)
                             if acct["phase_s"] else None),
         )
-    row["_spans"] = payload.get("spans", [])  # popped by run(); not a column
+    # popped by run(); not columns
+    row["_spans"] = payload.get("spans", [])
+    row["_device_kind"] = payload.get("device_kind")
     return row
 
 
-def _phase_attribution_rows(payload_spans: list[dict]) -> list[dict]:
+def _phase_attribution_rows(payload_spans: list[dict],
+                            device_kind: str | None) -> list[dict]:
     """Model-vs-measured rows for the traced schedule configs: the paper's
     attribution method (which roofline term binds, and by how much the
     model misses) applied to the stencil overlap schedule."""
     from repro.obs.attribution import attribution_report
 
     rows = []
-    for arow in attribution_report(payload_spans):
+    for arow in attribution_report(payload_spans, device_kind):
         if arow["workload"] != "stencil_schedule":
             continue
         sched = f"h{arow['hosts']}_d{arow['depth']}"
@@ -333,8 +337,9 @@ def run(quick: bool = True) -> list[dict]:
     rows.extend(_roofline_rows(L, "float32"))
     overlap_row = _overlap_identity_row(L, tile=min(64, L**3), reps=reps)
     spans = overlap_row.pop("_spans", [])
+    device_kind = overlap_row.pop("_device_kind", None)
     rows.append(overlap_row)
-    rows.extend(_phase_attribution_rows(spans))
+    rows.extend(_phase_attribution_rows(spans, device_kind))
     rows.extend(_depth2_identity_rows(
         2 if quick else 4, tile=min(16, L**3), reps=reps))
     return rows

@@ -35,6 +35,11 @@ ROOT = pathlib.Path(__file__).resolve().parent
 AUTOTUNE_DIR = ROOT / ".su3_autotune"
 SEED = 0
 
+sys.path.insert(0, str(ROOT / "src"))
+# both fail outside a checkout, before any phase
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+from repro.core.su3.layouts import on_host  # noqa: E402
+
 
 def _version(dist: str) -> str:
     try:
@@ -103,20 +108,14 @@ def _random_su3(key, n: int):
 # lanes, so an L=32 canonical gauge field would not fit the chip.
 
 
-def _on_host(fn, *args):
-    from repro.core.su3.layouts import on_host
-
-    return on_host(fn, *args)
-
-
 def _lattice(key, L: int):
     """A random SU(3) gauge lattice ``(L**4, 4, 3, 3)`` complex64."""
-    return _on_host(lambda k: _random_su3(k, L**4 * 4).reshape(L**4, 4, 3, 3), key)
+    return on_host(lambda k: _random_su3(k, L**4 * 4).reshape(L**4, 4, 3, 3), key)
 
 
 def _links(key):
     """A random SU(3) link set ``(4, 3, 3)`` — the multiply's B."""
-    return _on_host(lambda k: _random_su3(k, 4), key)
+    return on_host(lambda k: _random_su3(k, 4), key)
 
 
 def _vector(key, L: int):
@@ -129,7 +128,7 @@ def _vector(key, L: int):
         return 0.1 * jax.lax.complex(jax.random.normal(k0, shape),
                                      jax.random.normal(k1, shape))
 
-    return _on_host(make, key)
+    return on_host(make, key)
 
 
 def _chain_ref(a, b, k: int):
@@ -141,7 +140,7 @@ def _chain_ref(a, b, k: int):
             a = su3_mult_ref(a, b)
         return a
 
-    return _on_host(chain, a, b)
+    return on_host(chain, a, b)
 
 
 def _host(x):
@@ -256,11 +255,11 @@ def phase_served(L: int) -> dict:
     out = svc.pop_result(sid)
     u_phys, v_p = plan.pack_gauge(u), plan.pack_rhs(v)
     ref_step = plan.stencil_reference_step()(u_phys, v_p)
-    ref = _on_host(lambda u, v: su3_plan.stencil_apply_reference(u, v, L), u, v)
+    ref = on_host(lambda u, v: su3_plan.stencil_apply_reference(u, v, L), u, v)
     errors["stencil"] = (_err(out, ref), tol)
     errors["stencil_vs_plan_reference"] = (_err(out, plan.unpack_vec(ref_step)), tol)
     x = svc.pop_result(cid)
-    x_ref, res_ref, conv_ref = _on_host(
+    x_ref, res_ref, conv_ref = on_host(
         lambda u, b: su3_plan.cg_reference_solve(u, b, L, tol=1e-6), u_cg, b_cg)
     if isinstance(x, Exception) or not conv_ref:
         raise AssertionError(f"solve failed: {x!r}, reference converged={conv_ref}")
@@ -376,8 +375,8 @@ def phase_sharded_plan(L: int, tile: int) -> dict:
     v = _vector(jax.random.fold_in(key, 3), L)
     u4, v4 = p4.pack_gauge(u), p4.pack_rhs(v)
     u1, v1 = p1.pack_gauge(u), p1.pack_rhs(v)
-    ref1 = _on_host(lambda u, v: su3_plan.stencil_apply_reference(u, v, L), u, v)
-    ref2 = _on_host(lambda u, v: su3_plan.stencil_apply_reference(u, v, L), u, ref1)
+    ref1 = on_host(lambda u, v: su3_plan.stencil_apply_reference(u, v, L), u, v)
+    ref2 = on_host(lambda u, v: su3_plan.stencil_apply_reference(u, v, L), u, ref1)
     one = p1.stencil_step(overlap=False)
     for depth, ref, single in ((1, ref1, one(u1, v1)), (2, ref2, one(u1, one(u1, v1)))):
         out = p4.stencil_step(overlap=True, depth=depth)(u4, v4)
@@ -388,7 +387,7 @@ def phase_sharded_plan(L: int, tile: int) -> dict:
     u_cg, b_cg = autotune._cg_measure_problem(L)
     r4 = p4.cg_solve(p4.pack_gauge(u_cg), p4.pack_rhs(b_cg))
     r1 = p1.cg_solve(p1.pack_gauge(u_cg), p1.pack_rhs(b_cg))
-    x_ref, _res, conv = _on_host(
+    x_ref, _res, conv = on_host(
         lambda u, b: su3_plan.cg_reference_solve(u, b, L, tol=1e-6), u_cg, b_cg)
     if not (r4.converged and r1.converged and conv):
         raise AssertionError("a CG solve did not converge")
@@ -436,9 +435,6 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the sharded path on a four-chip host")
     args = ap.parse_args(argv)
-    sys.path.insert(0, str(ROOT / "src"))
-    from repro.compile_cache import enable_compile_cache  # fails outside a checkout
-
     devs = _device_check(args.chips)
     print(json.dumps({"compile_cache": enable_compile_cache()}), flush=True)
     AUTOTUNE_DIR.mkdir(exist_ok=True)

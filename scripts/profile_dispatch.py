@@ -100,13 +100,18 @@ def megakernel_amortization_row(L: int, slots: int = SLOTS, reps: int = 5) -> di
         jnp.asarray(b[..., 0] + 1j * b[..., 1], jnp.complex64))
     ones = jnp.ones((slots,), jnp.int32)
     mega = plan.fused_batched_step(slots, max_k=1)
+    # the megakernel donates its slot table: it advances its own copy, the
+    # way the serving loop rebinds its table every iteration
+    table = jnp.copy(a_phys)
 
     def per_chain():
         outs = [plan.step(a_phys[s], b_p[s]) for s in range(slots)]
         outs[-1].block_until_ready()
 
     def megakernel():
-        mega(a_phys, b_p, ones).block_until_ready()
+        nonlocal table
+        table = mega(table, b_p, ones)
+        table.block_until_ready()
 
     per_chain()  # warm both compiled shapes before timing
     megakernel()
@@ -171,11 +176,14 @@ def main(argv: list[str] | None = None) -> int:
         merge_into_artifact(rows, args.json)
         print(f"# merged dispatch table into {args.json}", file=sys.stderr)
     if args.trace:
+        from repro.obs import provenance_block
+
+        meta = provenance_block()
         if args.trace.endswith(".jsonl"):
-            n = TRACER.to_jsonl(args.trace)
+            n = TRACER.to_jsonl(args.trace, metadata=meta)
         else:
-            n = TRACER.to_chrome_trace(args.trace)
-        print(f"# wrote {n} spans to {args.trace}", file=sys.stderr)
+            n = TRACER.to_chrome_trace(args.trace, metadata=meta)
+        print(f"# wrote {n} records to {args.trace}", file=sys.stderr)
     bad = [r for r in rows if "verified" in r and not r["verified"]]
     return 1 if bad else 0
 

@@ -42,16 +42,28 @@ except ImportError:  # direct invocation without PYTHONPATH=src
     from repro.obs.tracer import load_jsonl
 
 
+def _split_meta(records: list[dict]) -> tuple[list[dict], dict]:
+    """Flat JSONL keeps its metadata in a leading ``{"type": "meta"}`` record."""
+    meta: dict = {}
+    rest = []
+    for rec in records:
+        if rec.get("type") == "meta":
+            meta = {k: v for k, v in rec.items() if k != "type"}
+        else:
+            rest.append(rec)
+    return rest, meta
+
+
 def load_records(path: str) -> tuple[list[dict], dict]:
     """(records, metadata) from a JSONL or Chrome trace-event file."""
     try:
         with open(path) as fh:
             payload = json.load(fh)
     except json.JSONDecodeError:  # multiple lines -> flat JSONL
-        return load_jsonl(path), {}
+        return _split_meta(load_jsonl(path))
     if not isinstance(payload, dict) or "traceEvents" not in payload:
         # a single-record JSONL file parses as one object
-        return ([payload] if isinstance(payload, dict) else []), {}
+        return _split_meta([payload] if isinstance(payload, dict) else [])
     records = []
     for ev in payload.get("traceEvents", []):
         args = dict(ev.get("args") or {})
@@ -142,8 +154,10 @@ def report(path: str) -> str:
         out.append("  (efficiency = sum_phases / UNTRACED wall; traced walls "
                    "serialize at phase boundaries and cannot witness hiding)")
     out.append("")
-    out.append("attribution (measured vs roofline):")
-    out.append(render_attribution(attribution_report(records)))
+    kind = meta.get("device_kind")
+    out.append(f"attribution (measured vs roofline of "
+               f"{kind or 'no recorded device: model columns empty'}):")
+    out.append(render_attribution(attribution_report(records, kind)))
     return "\n".join(out)
 
 
@@ -155,6 +169,9 @@ def main(argv: list[str] | None = None) -> int:
                          "(default: %(default)s — where the traced serve "
                          "benchmark row exports)")
     args = ap.parse_args(argv)
+    # the roofline model lowers kernels to count their work; on the host CPU,
+    # so reading a trace never claims an accelerator another process may hold
+    os.environ["JAX_PLATFORMS"] = "cpu"
     if not os.path.exists(args.trace):
         print(f"trace_report: no trace at {args.trace!r}", file=sys.stderr)
         return 1

@@ -21,22 +21,13 @@ ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def compile_cache_dir() -> str | None:
-    """The directory :func:`enable_compile_cache` sets, or None where the
-    environment already names one for JAX."""
-    if os.environ.get(ENV):
-        return None
-    return str(DEFAULT_DIR)
-
-
 def enable_compile_cache() -> str:
     """Turn the persistent cache on; returns the directory in use."""
     import jax
 
     # the Pallas kernels compile in well under JAX's default 1 s floor
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    path = compile_cache_dir()
-    if path is None:
+    if os.environ.get(ENV):
         return os.environ[ENV]
-    jax.config.update("jax_compilation_cache_dir", path)
-    return path
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
+    return str(DEFAULT_DIR)

@@ -111,22 +111,23 @@ HARDWARE = {h.name: h for h in (TPU_V5E, XEON_8280_SOCKET, PIUMA_CORE)}
 PEAKS_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
 
 
-def hardware_for_device(device: Any) -> HardwareSpec:
-    """The peaks of ``device`` (a ``jax.Device``).
+def hardware_for_device_kind(device_kind: str) -> HardwareSpec:
+    """The peaks of the device whose ``jax.Device.device_kind`` is given:
+    the kind recorded where the measurement was made, not the reader's.
 
-    A TPU whose kind is not in :data:`PEAKS_BY_DEVICE_KIND` raises: a share
-    of a peak nobody recorded is not a number.  Any other platform (CPU
-    rehearsals) ranks against the v5e model, the chip this stack targets.
+    CPU rehearsals (kind ``"cpu"``) rank against the v5e model, the chip this
+    stack targets.  Any other kind raises: a share of a peak nobody recorded
+    is not a number.
     """
-    if device.platform != "tpu":
+    if device_kind == "cpu":
         return TPU_V5E
     try:
-        return PEAKS_BY_DEVICE_KIND[device.device_kind]
+        return PEAKS_BY_DEVICE_KIND[device_kind]
     except KeyError:
         raise ValueError(
-            f"no peaks recorded for TPU kind {device.device_kind!r}; add it "
-            f"to roofline.PEAKS_BY_DEVICE_KIND with its source"
-        ) from None
+            f"no peaks recorded for device kind {device_kind!r}; add it "
+            f"to roofline.PEAKS_BY_DEVICE_KIND with its source") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -448,8 +448,10 @@ def gather_neighbors(v_p: jax.Array, idx: np.ndarray) -> jax.Array:
     the ``(D, N)`` index table ``idx``.
 
     One gather per direction: a single ``(D, N)``-indexed gather is the same
-    data movement, but at L=32 the TPU compiler takes ~35 s on it and emits
-    a 54 MB program, against ~3 s for the per-direction form.
+    data movement, but compiled alone at L=32 (D=8, N=32**4) for a described
+    v5e with jax 0.9.0 / libtpu 0.0.34, it took 29.2 s, a 54.2 MB program and
+    201 MB of temp, against 1.5 s, 35.1 MB and no temp for the per-direction
+    form (``jit(f).lower(v).compile()`` and its ``memory_analysis()``).
     """
     nbrs = jnp.stack([v_p[:, :, idx[d]] for d in range(idx.shape[0])])
     # materialized, as the kernel operand it is on the chip: under CPU

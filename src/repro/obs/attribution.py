@@ -22,7 +22,9 @@ Span contract (what the serve/plan instrumentation emits):
 
 Rows accept live ``Span`` objects or JSONL record dicts interchangeably,
 so ``scripts/trace_report.py`` can re-run the join offline from a trace
-file.  Model calls import jax lazily; on a machine without the stack the
+file.  The model side is priced against the peaks of the device kind the
+trace recorded (never the reader's device, so reading a trace starts no JAX
+backend); with no recorded kind, or on a machine without the stack, the
 report degrades to measured-only rows (``predicted_gflops=None``).
 """
 from __future__ import annotations
@@ -58,15 +60,26 @@ def _spans(records: Iterable[Any]) -> list[dict[str, Any]]:
     return out
 
 
-def _predictors():
-    """(autotune module, peaks of the local device) or (None, None)."""
-    try:
-        import jax
+def _predictors(device_kind: str | None):
+    """(autotune module, peaks of the recording device) or (None, None).
 
+    The peaks are those of the ``device_kind`` the trace was recorded on,
+    never the reader's; with no recorded kind the model side stays empty."""
+    if device_kind is None:
+        return None, None
+    try:
         from repro.core import autotune, roofline
     except ImportError:  # pragma: no cover - jax-less trace readers
         return None, None
-    return autotune, roofline.hardware_for_device(jax.devices()[0])
+    return autotune, roofline.hardware_for_device_kind(device_kind)
+
+
+def recorded_device_kind(records: Iterable[Any]) -> str | None:
+    """The ``device_kind`` of a trace's ``{"type": "meta"}`` record, if any."""
+    for rec in records:
+        if isinstance(rec, dict) and rec.get("type") == "meta":
+            return rec.get("device_kind")
+    return None
 
 
 # --------------------------------------------------------------------- joins
@@ -228,16 +241,24 @@ def _model_fields(pred: dict | None, measured_unit_s: float) -> dict:
     }
 
 
-def attribution_report(records: Iterable[Any]) -> list[dict]:
+def attribution_report(records: Iterable[Any],
+                       device_kind: str | None = None) -> list[dict]:
     """Measured-vs-modeled rows for every config that shows up in spans.
 
     Three workload families: ``multiply`` (serving dispatch, joined against
     predict_pipeline), ``stencil`` (serving dispatch, predict_stencil at
     hosts=1/serial), ``stencil_schedule`` (the overlap schedule's step +
     phase spans, predict_stencil at the traced (overlap, depth, hosts)).
+
+    ``device_kind`` is the ``jax.Device.device_kind`` the spans were
+    recorded on; when omitted it is read from the records' meta record.
+    With neither, the rows carry the measured side only.
     """
+    records = list(records)
+    if device_kind is None:
+        device_kind = recorded_device_kind(records)
     spans = _spans(records)
-    autotune_mod, hw = _predictors()
+    autotune_mod, hw = _predictors(device_kind)
     rows = []
     rows.extend(_multiply_rows(spans, autotune_mod, hw))
     rows.extend(_stencil_dispatch_rows(spans, autotune_mod, hw))

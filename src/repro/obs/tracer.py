@@ -23,6 +23,7 @@ parent id is given.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import time
 from collections import deque
@@ -240,11 +241,16 @@ class Tracer:
         for name, value in sorted(self.counters.items()):
             yield {"type": "counter", "name": name, "value": value}
 
-    def to_jsonl(self, path: str) -> int:
-        """Flat JSONL: one record per line. Returns the record count."""
+    def to_jsonl(self, path: str,
+                 metadata: dict[str, Any] | None = None) -> int:
+        """Flat JSONL: one record per line, ``metadata`` (if any) first as a
+        ``{"type": "meta", ...}`` record. Returns the record count."""
+        records = self.iter_records()
+        if metadata:
+            records = itertools.chain([dict(metadata, type="meta")], records)
         n = 0
         with open(path, "w") as fh:
-            for rec in self.iter_records():
+            for rec in records:
                 fh.write(json.dumps(rec) + "\n")
                 n += 1
         return n

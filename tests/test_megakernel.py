@@ -267,3 +267,20 @@ def test_megakernel_warm_compiles_the_table_shape():
     svc.submit(a, b, k=1)
     svc.run_until_drained()
     assert svc.metrics.compiles == 0, "warmed table shape must not recompile"
+
+
+def test_dispatch_profiler_megakernel_row_survives_donation():
+    """scripts/profile_dispatch.py times the donating megakernel many times
+    over one slot table; each call must advance a live buffer."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "profile_dispatch",
+        os.path.join(os.path.dirname(__file__), "..", "scripts",
+                     "profile_dispatch.py"))
+    profile_dispatch = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(profile_dispatch)
+    row = profile_dispatch.megakernel_amortization_row(2, reps=2)
+    assert row["dispatches_per_iter_megakernel"] == 1
+    assert row["megakernel_s"] > 0 and row["chains_s"] > 0

@@ -258,7 +258,7 @@ def _mk_records():
 
 
 def test_attribution_joins_measured_against_roofline():
-    rows = attribution_report(_mk_records())
+    rows = attribution_report(_mk_records(), "cpu")
     by_wl = {r["workload"]: r for r in rows}
     mult = by_wl["multiply"]
     assert mult["n_spans"] == 3 and mult["fused_k"] == 2
@@ -279,12 +279,23 @@ def test_attribution_accepts_jsonl_records_and_renders(tmp_path):
     for s in _mk_records():
         tr._record(s)
     p = tmp_path / "t.jsonl"
-    tr.to_jsonl(str(p))
+    tr.to_jsonl(str(p), metadata={"device_kind": "cpu"})
     rows = attribution_report(load_jsonl(str(p)))
     assert {r["workload"] for r in rows} == {"multiply", "stencil_schedule"}
+    # the kind comes from the trace's meta record, not the reader's device
+    assert all(r["predicted_s"] is not None for r in rows)
     text = render_attribution(rows)
     assert "multiply" in text and "L4/t64" in text and "ovl" in text
     assert render_attribution([]).startswith("(no attributable")
+
+
+def test_attribution_prices_only_a_recorded_device_kind():
+    # no recorded kind: measured columns only, never the reader's peaks
+    rows = attribution_report(_mk_records())
+    assert rows and all(r["predicted_s"] is None for r in rows)
+    assert all(r["measured_unit_s"] > 0 for r in rows)
+    with pytest.raises(ValueError, match="no peaks recorded"):
+        attribution_report(_mk_records(), "TPU v9")
 
 
 def test_overlap_efficiency_accounting():
@@ -327,3 +338,28 @@ def test_service_emits_request_lifecycle_spans():
     assert req.attrs["queue_wait_s"] >= 0.0
     # request spans cover admission -> completion, so they outlast dispatch
     assert req.dur_s >= disp.dur_s
+
+
+def test_trace_report_reads_the_recorded_device_kind(tmp_path):
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_report",
+        os.path.join(os.path.dirname(__file__), "..", "scripts",
+                     "trace_report.py"))
+    trace_report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_report)
+    tr = Tracer()
+    for s in _mk_records():
+        tr._record(s)
+    for suffix in (".jsonl", ".chrome.json"):
+        p = str(tmp_path / f"t{suffix}")
+        if suffix == ".jsonl":
+            tr.to_jsonl(p, metadata={"device_kind": "cpu"})
+        else:
+            tr.to_chrome_trace(p, metadata={"device_kind": "cpu"})
+        records, meta = trace_report.load_records(p)
+        assert meta["device_kind"] == "cpu"
+        assert all(r.get("type") != "meta" for r in records)
+        assert "roofline of cpu" in trace_report.report(p)

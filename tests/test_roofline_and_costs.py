@@ -171,15 +171,14 @@ def test_xeon_piuma_models_match_paper():
 
 
 def test_peaks_are_keyed_by_device_kind():
-    from types import SimpleNamespace as Dev
-
-    v5e = Dev(platform="tpu", device_kind="TPU v5 lite")
-    assert roofline.hardware_for_device(v5e) is roofline.TPU_V5E
+    assert roofline.hardware_for_device_kind("TPU v5 lite") is roofline.TPU_V5E
     assert roofline.TPU_V5E.hbm_bw == 819e9 and roofline.TPU_V5E.peak_flops == 197e12
-    # CPU rehearsals rank against the v5e model; an unrecorded chip raises
-    assert roofline.hardware_for_device(jax.devices()[0]) is roofline.TPU_V5E
-    with pytest.raises(ValueError, match="no peaks recorded"):
-        roofline.hardware_for_device(Dev(platform="tpu", device_kind="TPU v9"))
+    # CPU rehearsals rank against the v5e model; an unrecorded kind raises
+    cpu_kind = jax.devices()[0].device_kind
+    assert roofline.hardware_for_device_kind(cpu_kind) is roofline.TPU_V5E
+    for unrecorded in ("TPU v9", "unknown"):
+        with pytest.raises(ValueError, match="no peaks recorded"):
+            roofline.hardware_for_device_kind(unrecorded)
 
 
 def test_compile_cache_dir_rule(monkeypatch):
@@ -187,9 +186,18 @@ def test_compile_cache_dir_rule(monkeypatch):
 
     from repro import compile_cache
 
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
-    assert compile_cache.compile_cache_dir() is None  # JAX reads the env
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-    path = pathlib.Path(compile_cache.compile_cache_dir())
-    assert path.name == ".jax_cache"
-    assert (path.parent / "src" / "repro" / "compile_cache.py").is_file()
+    saved = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs")}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == saved[
+            "jax_compilation_cache_dir"]  # JAX reads the env itself
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = pathlib.Path(compile_cache.enable_compile_cache())
+        assert path.name == ".jax_cache"
+        assert (path.parent / "src" / "repro" / "compile_cache.py").is_file()
+        assert jax.config.jax_compilation_cache_dir == str(path)
+    finally:  # nothing this test process compiles lands in the checkout
+        for name, value in saved.items():
+            jax.config.update(name, value)

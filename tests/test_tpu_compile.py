@@ -8,14 +8,18 @@ VMEM limits, the dynamic ``fori_loop`` chain, scalar prefetch) and, on the
 described 2x2 mesh, that every sharded kernel sits inside a shard_map.
 
 The topology is described inside a fixture, never at import: only one
-process at a time may load the TPU library.  The persistent compilation
-cache is off around these compiles (an entry written without a chip cannot
-be read back).
+process at a time may load the TPU library.  Under pytest-xdist the file is
+one unit of work, so one worker describes the chip: ``--dist loadfile``
+sends a whole file to one worker, and ``--dist loadgroup`` honours the
+``xdist_group`` mark below.  The persistent compilation cache is off around
+these compiles (an entry written without a chip cannot be read back).
 """
 import os
 
 import numpy as np
 import pytest
+
+pytestmark = pytest.mark.xdist_group("tpu_compile")
 
 import jax
 import jax.numpy as jnp
