@@ -1299,8 +1299,13 @@ class ExecutionPlan:
     def pack_links(self, b: jax.Array) -> jax.Array:
         """Canonical link set ``(4, 3, 3)`` (or a batch of them) -> planar
         ``(..., 2, 36)``, replicated over the plan's mesh."""
+        return jax.device_put(self.pack_links_on_host(b), self.replicated)
+
+    def pack_links_on_host(self, b: jax.Array) -> jax.Array:
+        """Canonical link set ``(4, 3, 3)`` (or a batch of them) -> planar
+        ``(..., 2, 36)``, on the host."""
         pack = self.codec.pack_b if b.ndim == 3 else jax.vmap(self.codec.pack_b)
-        return jax.device_put(layouts.on_host(pack, b), self.replicated)
+        return layouts.on_host(pack, b)
 
     def verify_stencil(self, out_p: jax.Array) -> bool:
         """Fixed-point check for :meth:`init_stencil_data` inputs: every
@@ -1844,6 +1849,8 @@ class BatchedLatticeRunner:
         self.n_devices = self.plan.n_devices
         self._sharding = self.plan.lattice_batch_sharding()
         self._steps: dict[int, Callable[[jax.Array, jax.Array], jax.Array]] = {}
+        # recorder of multiply's steps, read at call time (like plan.tracer)
+        self.tracer = NULL_TRACER
 
     def _batched_step(self, k: int) -> Callable[[jax.Array, jax.Array], jax.Array]:
         if k not in self._steps:
@@ -1868,6 +1875,11 @@ class BatchedLatticeRunner:
     def pack_batch(self, a: jax.Array) -> jax.Array:
         """Canonical (B, n_sites, 4, 3, 3) complex -> batched physical form,
         packed on the host and placed by :meth:`batch_sharding`."""
+        return jax.device_put(self._pack_on_host(a), self.batch_sharding(a.shape[0]))
+
+    def _pack_on_host(self, a: jax.Array) -> jax.Array:
+        """Canonical (B, n_sites, 4, 3, 3) complex -> batched physical form,
+        zero-padded to the plan's sites, on the host."""
         if a.shape[1] > self.plan.padded_sites:
             raise ValueError(
                 f"batch carries {a.shape[1]} sites > plan capacity "
@@ -1882,9 +1894,7 @@ class BatchedLatticeRunner:
                 )
             return jax.vmap(self.plan.codec.pack)(a)
 
-        return jax.device_put(
-            layouts.on_host(pack, a), self.batch_sharding(a.shape[0])
-        )
+        return layouts.on_host(pack, a)
 
     def pack_vec_batch(self, v: jax.Array) -> jax.Array:
         """Canonical (B, n_sites, 3) vector fields -> planar (B, 2, 3, S),
@@ -1913,9 +1923,29 @@ class BatchedLatticeRunner:
         return c[:bsz] if pad else c
 
     def multiply(self, a: jax.Array, b: jax.Array, k: int = 1) -> jax.Array:
-        """Canonical batched entry: a (B, S, 4, 3, 3), b (B, 4, 3, 3) complex."""
+        """Canonical batched entry: a (B, S, 4, 3, 3), b (B, 4, 3, 3) complex.
+
+        Five steps, each a span on :attr:`tracer`: ``codec.pack`` (canonical
+        to physical on the host), ``transfer.h2d`` (``bytes`` placed on the
+        devices), ``device.step`` (the batched chain of ``k``),
+        ``transfer.d2h`` (``bytes`` fetched back) and ``codec.unpack``.  A
+        recording tracer waits for each step's work before closing its span;
+        an idle one waits for nothing, and the caller waits on the result.
+        """
+        tr = self.tracer
+        done = jax.block_until_ready if tr.enabled else (lambda x: x)
         n_sites = a.shape[1]
-        a_phys = self.pack_batch(a)
-        b_p = self.plan.pack_links(b)
-        c_phys = self.run(a_phys, b_p, k=k)
-        return self.unpack_batch(c_phys, n_sites)
+        with tr.span("codec.pack"):
+            a_phys = done(self._pack_on_host(a))
+            b_p = done(self.plan.pack_links_on_host(b))
+        with tr.span("transfer.h2d") as span:
+            a_phys, b_p = done(jax.device_put(
+                (a_phys, b_p), (self.batch_sharding(a.shape[0]), self.plan.replicated)))
+            span.set(bytes=a_phys.nbytes + b_p.nbytes)
+        with tr.span("device.step", k=k):
+            c_phys = done(self.run(a_phys, b_p, k=k))
+        with tr.span("transfer.d2h") as span:
+            c_phys = done(jax.device_put(c_phys, jax.devices("cpu")[0]))
+            span.set(bytes=c_phys.nbytes)
+        with tr.span("codec.unpack"):
+            return done(self.unpack_batch(c_phys, n_sites))

@@ -1,9 +1,15 @@
 """Flight-recorder span tracer: nested spans, bounded ring, two exports.
 
-Design constraints (ISSUE 7):
+Design constraints:
 
   * monotonic clock — ``time.perf_counter`` everywhere; wall-clock never
     enters a duration.
+  * the profiler's clock too — while a ``jax.profiler`` session records,
+    every live span of an enabled tracer also opens a
+    ``jax.profiler.TraceAnnotation`` named ``su3.<name>``, so the span lands
+    in the profile's host plane beside the runtime's events and the device's
+    ops.  Retroactive spans (``add_span``, ``event``) stay on the recorder
+    alone.
   * bounded memory — completed spans land in a ``deque(maxlen=capacity)``
     flight recorder; the oldest spans fall off and ``dropped`` counts them.
   * near-zero cost disabled — ``NULL_TRACER.span(...)`` returns one shared
@@ -30,6 +36,23 @@ from collections import deque
 from typing import Any, Iterator
 
 _CLOCK = time.perf_counter
+ANNOTATION_PREFIX = "su3."
+_annotation_cls: Any = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _open_annotation(name: str) -> Any:
+    """An entered ``TraceAnnotation`` for span ``name`` while a profiler
+    session records, else None (jax is imported here, not with the module)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    if not _annotation_cls.is_enabled():
+        return None
+    annotation = _annotation_cls(ANNOTATION_PREFIX + name)
+    annotation.__enter__()
+    return annotation
 
 
 class Span:
@@ -74,21 +97,26 @@ class Span:
 
 
 class _SpanContext:
-    """Context manager pairing one Span with the tracer's nesting stack."""
+    """Context manager pairing one Span with the tracer's nesting stack and,
+    while a profiler session records, with a profiler annotation."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
+        self._annotation = None
 
     def __enter__(self) -> Span:
+        self._annotation = _open_annotation(self._span.name)
         self._tracer._stack.append(self._span)
         return self._span
 
     def __exit__(self, *exc) -> None:
         span = self._span
         span.t1_s = _CLOCK()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         stack = self._tracer._stack
         if stack and stack[-1] is span:
             stack.pop()

@@ -634,19 +634,27 @@ class SU3Service:
 
     # -- tracing -------------------------------------------------------------
 
-    def _trace_dispatch(self, runner: BatchedLatticeRunner, host: int,
-                        kind: str, L: int, k: int, mode: str, t0: float,
-                        step_s: float, live: int, padded: int, flops: float,
-                        cold: bool) -> None:
-        """One retroactive dispatch span (the timed block already ran —
-        zero extra clock reads on the hot path).  Callers guard with
-        ``if self.tracer.enabled``."""
+    def _dispatch_span(self, runner: BatchedLatticeRunner, host: int,
+                       kind: str, L: int, k: int, mode: str, live: int,
+                       padded: int, flops: float, cold: bool):
+        """A live ``dispatch`` span around one timed dispatch block (the
+        shared no-op span, with no attrs built, while tracing is off)."""
+        if not self.tracer.enabled:
+            return NULL_TRACER.span("dispatch")
         ecfg = runner.cfg
-        self.tracer.add_span(
-            "dispatch", t0, t0 + step_s, lane=host,
+        return self.tracer.span(
+            "dispatch", lane=host,
             kind=kind, mode=mode, host=host, L=L, k=k,
             tile=ecfg.tile, dtype=ecfg.dtype, compression=ecfg.compression,
             live=live, padded=padded, flops=flops, cold=cold)
+
+    @staticmethod
+    def _seat_popped(reqs: list[ServeRequest]) -> None:
+        """Batch mode seats a request when its batch is popped: the queue
+        wait ends there, before the batch is stacked on the host."""
+        now = time.perf_counter()
+        for r in reqs:
+            r.seated_s = now
 
     def _trace_request(self, req: ServeRequest, done_s: float, host: int,
                        mode: str) -> None:
@@ -968,11 +976,14 @@ class SU3Service:
     # -- dispatch ------------------------------------------------------------
 
     def _work_pending(self) -> bool:
+        return bool(self._retry_q) or self._work_ready()
+
+    def _work_ready(self) -> bool:
+        """True while a turn has something to dispatch: a queued request, an
+        active solve or a live chain or slot table (retry backoffs aside)."""
         if any(len(b) for b in self._batchers):
             return True
         if self._solves:
-            return True
-        if self._retry_q:
             return True
         if any(chain.live for chain, _ in self._chains.values()):
             return True
@@ -1309,6 +1320,12 @@ class SU3Service:
         slot-swaps then fires one batched K-chain dispatch.  Each step also
         feeds one pressure sample to the brownout ladder and the warm-pool
         autoscaler (when configured).
+
+        A traced turn with work to dispatch is a live ``serve.step`` span:
+        the host work between two dispatches (delivering results, stacking,
+        packing) then lies under one span, which a profile can name the
+        device's idle time by.  Empty turns (retry backoffs, idle polling)
+        record none, so they cannot crowd the flight recorder.
         """
         now = time.perf_counter()
         if self._retry_q:
@@ -1317,6 +1334,14 @@ class SU3Service:
             self._evict_expired(now)
         if self._brownout is not None or self._autoscaler is not None:
             self._observe_pressure()
+        if self.tracer.enabled and self._work_ready():
+            with self.tracer.span("serve.step"):
+                return self._turn()
+        return self._turn()
+
+    def _turn(self) -> int:
+        """The dispatch half of :meth:`step`: pick the turn's owner, then
+        dispatch its work."""
         order = ("multiply", "stencil", "solve")
         for _ in range(self.cfg.hosts):
             host = self._rr_host
@@ -1387,6 +1412,9 @@ class SU3Service:
         if batch is None:
             return 0
         reqs = batch.requests
+        tr = self.tracer
+        if tr.enabled:
+            self._seat_popped(reqs)
         runner = self.runner_for(batch.L, host)
         n_sites = batch.L**4
         if self.faults.enabled:
@@ -1400,16 +1428,25 @@ class SU3Service:
                 if quarantined:
                     self._quarantine(host)
                 return 0
-        a = _host_stack([r.a for r in reqs], batch.pad)
-        b = _host_stack([r.b for r in reqs], batch.pad)
+        with tr.span("serve.stack", lane=host) as span:
+            if tr.enabled:
+                span.set(live=len(reqs), padded=batch.padded_size)
+            a = _host_stack([r.a for r in reqs], batch.pad)
+            b = _host_stack([r.b for r in reqs], batch.pad)
         shape_key = self._shape_key(runner, batch.L, batch.k, batch.padded_size)
         cold = shape_key not in self._seen_shapes
-        t0 = time.perf_counter()
-        c = runner.multiply(a, b, k=batch.k)
-        if self.faults.enabled:
-            c = self._poison_output(c, host, "multiply")
-        c.block_until_ready()
-        step_s = time.perf_counter() - t0
+        flops = request_flops(n_sites, batch.k) * len(reqs)
+        with self._dispatch_span(runner, host, "multiply", batch.L, batch.k,
+                                 "batch", live=len(reqs),
+                                 padded=batch.padded_size, flops=flops,
+                                 cold=cold):
+            t0 = time.perf_counter()
+            runner.tracer = tr  # the runner records its steps on it
+            c = runner.multiply(a, b, k=batch.k)
+            if self.faults.enabled:
+                c = self._poison_output(c, host, "multiply")
+            c.block_until_ready()
+            step_s = time.perf_counter() - t0
         if (self.faults.enabled or self.cfg.numerics_guard) \
                 and not self._finite(c):
             # poisoned (or genuinely non-finite) output: never delivered —
@@ -1425,21 +1462,14 @@ class SU3Service:
         self._seen_shapes.add(shape_key)
         self.metrics.record_dispatch(
             live=len(reqs), padded=batch.padded_size, step_s=step_s,
-            flops=request_flops(n_sites, batch.k) * len(reqs), cold=cold,
-            host=host,
+            flops=flops, cold=cold, host=host,
         )
-        if self.tracer.enabled:
-            self._trace_dispatch(
-                runner, host, "multiply", batch.L, batch.k, "batch", t0,
-                step_s, live=len(reqs), padded=batch.padded_size,
-                flops=request_flops(n_sites, batch.k) * len(reqs), cold=cold)
         done_s = time.perf_counter()
         for i, r in enumerate(reqs):
             self._results[r.req_id] = c[i]
             self.metrics.record_completion(
                 done_s - r.arrival_s, tenant=r.tenant, slo=r.slo)
-            if self.tracer.enabled:
-                r.seated_s = t0  # batch mode: seating IS the dispatch start
+            if tr.enabled:
                 self._trace_request(r, done_s, host, "batch")
         self.metrics.record_queue_depth(self.queued())
         return len(reqs)
@@ -1477,6 +1507,9 @@ class SU3Service:
         if batch is None:
             return 0
         reqs = batch.requests
+        tr = self.tracer
+        if tr.enabled:
+            self._seat_popped(reqs)
         runner = self.runner_for(batch.L, host)
         plan = runner.plan
         n_sites = batch.L**4
@@ -1493,17 +1526,26 @@ class SU3Service:
         # (whole lattices per device, as the multiply path's run() pads)
         dispatched = batch.padded_size + (-batch.padded_size) % runner.n_devices
         pad = dispatched - len(reqs)
-        u_phys = runner.pack_batch(_host_stack([r.a for r in reqs], pad))
-        v_p = runner.pack_vec_batch(_host_stack([r.b for r in reqs], pad))
+        with tr.span("serve.stack", lane=host) as span:
+            if tr.enabled:
+                span.set(live=len(reqs), padded=dispatched)
+            u = _host_stack([r.a for r in reqs], pad)
+            v = _host_stack([r.b for r in reqs], pad)
+        u_phys = runner.pack_batch(u)
+        v_p = runner.pack_vec_batch(v)
         step = self._stencil_step_for(runner, host, batch.L)
         shape_key = ("stencil", batch.L, dispatched)
         cold = shape_key not in self._seen_shapes
-        t0 = time.perf_counter()
-        out_p = step(u_phys, v_p)
-        if self.faults.enabled:
-            out_p = self._poison_output(out_p, host, "stencil")
-        out_p.block_until_ready()
-        step_s = time.perf_counter() - t0
+        flops = float(STENCIL_FLOPS_PER_SITE) * n_sites * len(reqs)
+        with self._dispatch_span(runner, host, "stencil", batch.L, 1, "batch",
+                                 live=len(reqs), padded=dispatched,
+                                 flops=flops, cold=cold):
+            t0 = time.perf_counter()
+            out_p = step(u_phys, v_p)
+            if self.faults.enabled:
+                out_p = self._poison_output(out_p, host, "stencil")
+            out_p.block_until_ready()
+            step_s = time.perf_counter() - t0
         if (self.faults.enabled or self.cfg.numerics_guard) \
                 and not self._finite(out_p):
             quarantined = self.health.record_failure(host, "non-finite output")
@@ -1516,23 +1558,15 @@ class SU3Service:
             self.health.record_success(host)
         self._seen_shapes.add(shape_key)
         self.metrics.record_dispatch(
-            live=len(reqs), padded=dispatched, step_s=step_s,
-            flops=float(STENCIL_FLOPS_PER_SITE) * n_sites * len(reqs),
+            live=len(reqs), padded=dispatched, step_s=step_s, flops=flops,
             cold=cold, host=host,
         )
-        if self.tracer.enabled:
-            self._trace_dispatch(
-                runner, host, "stencil", batch.L, 1, "batch", t0, step_s,
-                live=len(reqs), padded=dispatched,
-                flops=float(STENCIL_FLOPS_PER_SITE) * n_sites * len(reqs),
-                cold=cold)
         done_s = time.perf_counter()
         for i, r in enumerate(reqs):
             self._results[r.req_id] = plan.unpack_vec(out_p[i], n_sites)
             self.metrics.record_completion(
                 done_s - r.arrival_s, tenant=r.tenant, slo=r.slo)
-            if self.tracer.enabled:
-                r.seated_s = t0
+            if tr.enabled:
                 self._trace_request(r, done_s, host, "batch")
         self.metrics.record_queue_depth(self.queued())
         return len(reqs)
@@ -1617,33 +1651,37 @@ class SU3Service:
         shape_key = ("solve", req.L)
         cold = shape_key not in self._seen_shapes
         tr = self.tracer
-        t0 = time.perf_counter()
-        for _ in range(n):
-            if tr.enabled:
-                with tr.span("cg.iter", lane=host, req_id=req.req_id,
-                             it=state["iterations"] + 1):
-                    state = plan.cg_iterate(active["u_phys"], state)
-                    jax.block_until_ready(state["rs"])
-            else:
-                state = plan.cg_iterate(active["u_phys"], state)
-        if self.faults.enabled:
-            # "kernel" seam for solves: poison the chunk's residual scalar —
-            # the corrupted-iterate case the residual guard below must catch
-            fk = self.faults.ask("kernel", host=host, kind="solve")
-            if fk is not None:
-                self.metrics.record_fault()
+        flops = float(CG_ITER_FLOPS_PER_SITE) * req.n_sites * n
+        with self._dispatch_span(runner, host, "solve", req.L, n, "solve",
+                                 live=1, padded=1, flops=flops, cold=cold):
+            t0 = time.perf_counter()
+            for _ in range(n):
                 if tr.enabled:
-                    tr.event("chaos.fault", lane=host, site="kernel",
-                             action=fk.action, seq=fk.seq, host=host,
-                             kind="solve")
-                state["rs"] = jnp.full_like(state["rs"], float("nan"))
-        if tr.enabled:
-            with tr.span("cg.reduce", lane=host, req_id=req.req_id,
-                         it=state["iterations"]):
-                rs_host = float(jax.device_get(state["rs"]))
-        else:
-            rs_host = float(jax.device_get(state["rs"]))  # syncs the chunk
-        step_s = time.perf_counter() - t0
+                    with tr.span("cg.iter", lane=host, req_id=req.req_id,
+                                 it=state["iterations"] + 1):
+                        state = plan.cg_iterate(active["u_phys"], state)
+                        jax.block_until_ready(state["rs"])
+                else:
+                    state = plan.cg_iterate(active["u_phys"], state)
+            if self.faults.enabled:
+                # "kernel" seam for solves: poison the chunk's residual
+                # scalar — the corrupted-iterate case the residual guard
+                # below must catch
+                fk = self.faults.ask("kernel", host=host, kind="solve")
+                if fk is not None:
+                    self.metrics.record_fault()
+                    if tr.enabled:
+                        tr.event("chaos.fault", lane=host, site="kernel",
+                                 action=fk.action, seq=fk.seq, host=host,
+                                 kind="solve")
+                    state["rs"] = jnp.full_like(state["rs"], float("nan"))
+            if tr.enabled:
+                with tr.span("cg.reduce", lane=host, req_id=req.req_id,
+                             it=state["iterations"]):
+                    rs_host = float(jax.device_get(state["rs"]))
+            else:
+                rs_host = float(jax.device_get(state["rs"]))  # syncs the chunk
+            step_s = time.perf_counter() - t0
         active["state"] = state
         if self.faults.enabled or self.cfg.numerics_guard:
             # CG residual guard: NaN/Inf or blow-up is numerical breakdown —
@@ -1673,15 +1711,10 @@ class SU3Service:
             if best is None or rs_host < best[0]:
                 active["best"] = (rs_host, state["x"])
         self._seen_shapes.add(shape_key)
-        flops = float(CG_ITER_FLOPS_PER_SITE) * req.n_sites * n
         self.metrics.record_dispatch(
             live=1, padded=1, step_s=step_s, flops=flops, cold=cold, host=host,
         )
         self.metrics.record_iteration(host, kind="solve", n=n)
-        if tr.enabled:
-            self._trace_dispatch(
-                runner, host, "solve", req.L, n, "solve", t0, step_s,
-                live=1, padded=1, flops=flops, cold=cold)
         if rs_host <= active["stop2"] or state["iterations"] >= req.max_iters:
             return self._retire_solve(host, active, state)
         self.metrics.record_queue_depth(self.queued())
@@ -1774,14 +1807,18 @@ class SU3Service:
             shape_key = self._shape_key(runner, L, 1, slots)
             cold = shape_key not in self._seen_shapes
             live = chain.live
-            t0 = time.perf_counter()
+            flops = request_flops(n_sites, 1) * live
             prev_a = arrays.a_phys
-            arrays.advance()
-            if self.faults.enabled:
-                arrays.a_phys = self._poison_output(
-                    arrays.a_phys, host, "multiply")
-            arrays.a_phys.block_until_ready()
-            step_s = time.perf_counter() - t0
+            with self._dispatch_span(runner, host, "multiply", L, 1,
+                                     "continuous", live=live, padded=slots,
+                                     flops=flops, cold=cold):
+                t0 = time.perf_counter()
+                arrays.advance()
+                if self.faults.enabled:
+                    arrays.a_phys = self._poison_output(
+                        arrays.a_phys, host, "multiply")
+                arrays.a_phys.block_until_ready()
+                step_s = time.perf_counter() - t0
             if (self.faults.enabled or self.cfg.numerics_guard) \
                     and not self._finite(arrays.a_phys):
                 # roll the chain state back: the retried advance re-runs
@@ -1801,14 +1838,9 @@ class SU3Service:
                 self.health.record_success(host)
             self._seen_shapes.add(shape_key)
             self.metrics.record_dispatch(
-                live=live, padded=slots, step_s=step_s,
-                flops=request_flops(n_sites, 1) * live, cold=cold, host=host,
+                live=live, padded=slots, step_s=step_s, flops=flops,
+                cold=cold, host=host,
             )
-            if self.tracer.enabled:
-                self._trace_dispatch(
-                    runner, host, "multiply", L, 1, "continuous", t0, step_s,
-                    live=live, padded=slots,
-                    flops=request_flops(n_sites, 1) * live, cold=cold)
             done_s = time.perf_counter()
             for slot, req in chain.advance():
                 self._results[req.req_id] = arrays.result(slot, n_sites)
@@ -1916,22 +1948,31 @@ class SU3Service:
             guard = self.faults.enabled or self.cfg.numerics_guard
             # the megakernel donates the slot table: roll back to a copy
             prev_a = jnp.copy(arrays.a_phys) if guard and not degraded else None
-            t0 = time.perf_counter()
-            if degraded:
-                for slot, req, _rem in occupants:
-                    if not ks[slot]:
-                        continue
-                    a_mid = arrays.result(slot, req.n_sites)
-                    c = self.runner_for(req.L, host).multiply(
-                        a_mid[None], req.b[None], k=ks[slot])[0]
-                    arrays.seat(slot, c, req.b)
-            else:
-                arrays.advance(ks)
-                if self.faults.enabled:
-                    arrays.a_phys = self._poison_output(
-                        arrays.a_phys, host, "multiply")
-            arrays.a_phys.block_until_ready()
-            step_s = time.perf_counter() - t0
+            dispatch_flops = sum(
+                request_flops(req.n_sites, ks[slot])
+                for slot, req, _rem in occupants
+            )
+            with self._dispatch_span(arrays.runner, host, "multiply",
+                                     arrays.cap_L, self.cfg.chain_horizon,
+                                     "megakernel", live=live,
+                                     padded=table.slots, flops=dispatch_flops,
+                                     cold=cold):
+                t0 = time.perf_counter()
+                if degraded:
+                    for slot, req, _rem in occupants:
+                        if not ks[slot]:
+                            continue
+                        a_mid = arrays.result(slot, req.n_sites)
+                        c = self.runner_for(req.L, host).multiply(
+                            a_mid[None], req.b[None], k=ks[slot])[0]
+                        arrays.seat(slot, c, req.b)
+                else:
+                    arrays.advance(ks)
+                    if self.faults.enabled:
+                        arrays.a_phys = self._poison_output(
+                            arrays.a_phys, host, "multiply")
+                arrays.a_phys.block_until_ready()
+                step_s = time.perf_counter() - t0
             if prev_a is not None and not self._finite(arrays.a_phys):
                 arrays.a_phys = prev_a  # retried advance is bitwise clean
                 quarantined = self.health.record_failure(
@@ -1948,21 +1989,11 @@ class SU3Service:
             if prev_a is not None:
                 self.health.record_success(host)
             self._seen_shapes.add(shape_key)
-            dispatch_flops = sum(
-                request_flops(req.n_sites, ks[slot])
-                for slot, req, _rem in occupants
-            )
             self.metrics.record_dispatch(
                 live=live, padded=table.slots, step_s=step_s,
                 flops=dispatch_flops,
                 cold=cold, host=host,
             )
-            if self.tracer.enabled:
-                self._trace_dispatch(
-                    arrays.runner, host, "multiply", arrays.cap_L,
-                    self.cfg.chain_horizon, "megakernel", t0, step_s,
-                    live=live, padded=table.slots, flops=dispatch_flops,
-                    cold=cold)
             done_s = time.perf_counter()
             for slot, req in table.advance(ks):
                 self._results[req.req_id] = arrays.result(slot, req.n_sites)

@@ -363,3 +363,156 @@ def test_trace_report_reads_the_recorded_device_kind(tmp_path):
         assert meta["device_kind"] == "cpu"
         assert all(r.get("type") != "meta" for r in records)
         assert "roofline of cpu" in trace_report.report(p)
+
+
+# -- the profiler's clock -----------------------------------------------------
+
+
+def _profiled_su3_events(log_dir):
+    """``(plane, name)`` of every ``su3.*`` event in the one profile under
+    ``log_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return [(plane.name, e.name)
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name.startswith("su3.")]
+
+
+def test_live_span_lands_on_the_profilers_host_plane(tmp_path):
+    import jax
+
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("outer"):
+            with tr.span("inner"):
+                pass
+        tr.add_span("retro", 0.0, 1.0)  # retroactive: the recorder's alone
+    events = _profiled_su3_events(str(tmp_path))
+    assert ("/host:CPU", "su3.outer") in events
+    assert ("/host:CPU", "su3.inner") in events
+    assert not any(name == "su3.retro" for _plane, name in events)
+    assert [s.name for s in tr.spans()] == ["inner", "outer", "retro"]
+
+
+def test_no_annotation_with_the_recorder_off_or_no_session(tmp_path):
+    import jax
+
+    tr = Tracer()
+    ctx = tr.span("unprofiled")
+    with ctx:
+        assert ctx._annotation is None  # no profiler session records
+    with jax.profiler.trace(str(tmp_path)):
+        with Tracer(enabled=False).span("off"):
+            pass
+        with NULL_TRACER.span("null"):
+            pass
+    assert _profiled_su3_events(str(tmp_path)) == []
+    assert [s.name for s in tr.spans()] == ["unprofiled"]
+
+
+# -- a served batch, span by span (tiny lattice, no autotune) ----------------
+
+_STEPS = ("codec.pack", "transfer.h2d", "device.step", "transfer.d2h",
+          "codec.unpack")
+
+
+def _served_batch(svc, n=2, k=2):
+    """Submit ``n`` L=2 multiplies of chain depth ``k`` and drain them."""
+    import numpy as np
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(1)
+    a = rng.standard_normal((n, 16, 4, 3, 3, 2)).astype(np.float32)
+    b = rng.standard_normal((n, 4, 3, 3, 2)).astype(np.float32)
+    ids = [svc.submit(jnp.asarray(a[i, ..., 0] + 1j * a[i, ..., 1], jnp.complex64),
+                      jnp.asarray(b[i, ..., 0] + 1j * b[i, ..., 1], jnp.complex64),
+                      k=k) for i in range(n)]
+    svc.run_until_drained()
+    return [svc.pop_result(rid) for rid in ids]
+
+
+def _small_service(tracer=None):
+    from repro.serve.su3 import BatcherConfig, ServiceConfig, SU3Service
+
+    return SU3Service(ServiceConfig(
+        autotune=False, tile=16,
+        batcher=BatcherConfig(max_batch=2, warm_batch_sizes=(2,)),
+    ), tracer=tracer)
+
+
+def test_batch_dispatch_records_each_step_under_its_dispatch_span():
+    tracer = Tracer()
+    svc = _small_service(tracer)
+    _served_batch(svc, k=2)
+    spans = tracer.spans()
+    (disp,) = [s for s in spans if s.name == "dispatch"]
+    (stack,) = [s for s in spans if s.name == "serve.stack"]
+    steps = [s for s in spans if s.name in _STEPS]
+    assert [s.name for s in steps] == list(_STEPS)  # one each, in order
+    assert all(s.parent_id == disp.span_id for s in steps)
+    (turn,) = [s for s in spans if s.name == "serve.step" and s.span_id == disp.parent_id]
+    assert stack.attrs == {"live": 2, "padded": 2} and stack.parent_id == turn.span_id
+    assert stack.t1_s <= disp.t0_s
+    assert disp.attrs["mode"] == "batch" and disp.attrs["k"] == 2
+    assert next(s for s in steps if s.name == "device.step").attrs == {"k": 2}
+    for s, nxt in zip(steps, steps[1:]):
+        assert disp.t0_s <= s.t0_s <= s.t1_s <= nxt.t0_s <= disp.t1_s
+
+
+def test_transfer_spans_count_the_bytes_moved():
+    import numpy as np
+
+    tracer = Tracer()
+    svc = _small_service(tracer)
+    _served_batch(svc, k=1)
+    runner = svc.runner_for(2)
+    a_phys = runner.pack_batch(np.zeros((2, 16, 4, 3, 3), np.complex64))
+    b_p = runner.plan.pack_links(np.zeros((2, 4, 3, 3), np.complex64))
+    h2d = next(s for s in tracer.spans() if s.name == "transfer.h2d")
+    d2h = next(s for s in tracer.spans() if s.name == "transfer.d2h")
+    assert h2d.attrs["bytes"] == a_phys.nbytes + b_p.nbytes
+    assert d2h.attrs["bytes"] == a_phys.nbytes  # C has A's physical form
+    assert a_phys.nbytes == 2 * 16 * 72 * 4 and b_p.nbytes == 2 * 72 * 4
+
+
+def test_queue_wait_ends_where_the_batch_is_popped():
+    tracer = Tracer()
+    svc = _small_service(tracer)
+    _served_batch(svc)
+    stack = next(s for s in tracer.spans() if s.name == "serve.stack")
+    reqs = [s for s in tracer.spans() if s.name == "request"]
+    assert len(reqs) == 2
+    for req in reqs:
+        wait = req.attrs["queue_wait_s"]
+        assert 0.0 <= wait <= stack.t0_s - req.t0_s  # the stacking is not in it
+
+
+@pytest.mark.parametrize("warm_first", [False, True])
+def test_a_tracer_assigned_after_construction_records_the_steps(warm_first):
+    svc = _small_service()
+    if warm_first:  # the runner exists before the tracer is replaced
+        _served_batch(svc)
+    svc.tracer = tracer = Tracer()
+    _served_batch(svc)
+    names = [s.name for s in tracer.spans()]
+    for name in _STEPS + ("dispatch", "serve.stack"):
+        assert names.count(name) == 1, name
+    svc.tracer = NULL_TRACER
+    _served_batch(svc)
+    assert [s.name for s in tracer.spans()] == names  # the old one hears no more
+
+
+def test_turns_with_nothing_to_dispatch_record_no_span():
+    tracer = Tracer()
+    svc = _small_service(tracer)
+    for _ in range(3):
+        assert svc.step() == 0
+    assert tracer.spans() == []
+    _served_batch(svc)
+    assert [s.name for s in tracer.spans()].count("serve.step") == 1
