@@ -24,8 +24,9 @@ On TPU the analogous axes are VPU lanes (128-wide) and VMEM tiles:
 
 Canonical (logical) form everywhere else in the library is complex:
   A : (n_sites, 4, 3, 3) complex   B : (4, 3, 3) complex.
-Canonical arrays live on the host (:func:`on_host`); only physical forms go
-onto an accelerator.
+Canonical arrays live on the host (:func:`on_host`); only physical forms, and
+the canonical float32 words (:func:`words_view`), which a TPU holds without
+padding, go onto an accelerator.
 """
 from __future__ import annotations
 
@@ -126,9 +127,15 @@ def pack_aos(a: jax.Array, site_meta: jax.Array | None = None) -> jax.Array:
     exactly MILC's ``site.link[4]``; words [72:80] are the metadata/pad block.
     """
     n_sites = a.shape[0]
-    dt = _real_dtype(a.dtype)
     gauge = jnp.stack([jnp.real(a), jnp.imag(a)], axis=-1)  # (s, 4, 3, 3, 2)
-    gauge = gauge.reshape(n_sites, GAUGE_WORDS).astype(dt)
+    return aos_from_words(
+        gauge.reshape(n_sites, GAUGE_WORDS).astype(_real_dtype(a.dtype)), site_meta)
+
+
+def aos_from_words(gauge: jax.Array, site_meta: jax.Array | None = None) -> jax.Array:
+    """Canonical gauge words (n_sites, 72) real -> AoS (n_sites, 80) at the
+    words' dtype, the metadata/pad block appended (see :func:`pack_aos`)."""
+    n_sites, dt = gauge.shape[0], gauge.dtype
     if site_meta is None:
         # x, y, z, t, index, parity, pad, pad — populated like the benchmark's
         # make_lattice(): index = linear site id; coords from L is unknown here
@@ -219,20 +226,46 @@ def reconstruct_third_row(r0: jax.Array, r1: jax.Array) -> jax.Array:
     precision; callers wanting f32 reconstruction from narrower storage
     upcast first.
     """
-    a_r, a_i = jnp.real(r0), jnp.imag(r0)
-    b_r, b_i = jnp.real(r1), jnp.imag(r1)
+    re, im = conj_cross(jnp.real(r0), jnp.imag(r0), jnp.real(r1), jnp.imag(r1), -1)
+    return jax.lax.complex(re, im)
 
-    def _comp(i: int, j: int) -> jax.Array:
-        # conj(r0[i]*r1[j] - r0[j]*r1[i]), grouped as in _expand_tile
-        xr = (a_r[..., i] * b_r[..., j] - a_i[..., i] * b_i[..., j]) - (
-            a_r[..., j] * b_r[..., i] - a_i[..., j] * b_i[..., i]
-        )
-        xi = (a_r[..., i] * b_i[..., j] + a_i[..., i] * b_r[..., j]) - (
-            a_r[..., j] * b_i[..., i] + a_i[..., j] * b_r[..., i]
-        )
-        return jax.lax.complex(xr, -xi)
 
-    return jnp.stack([_comp(1, 2), _comp(2, 0), _comp(0, 1)], axis=-1)
+def conj_cross(
+    a_r: jax.Array, a_i: jax.Array, b_r: jax.Array, b_i: jax.Array, axis: int
+) -> tuple[jax.Array, jax.Array]:
+    """(re, im) of conj(a x b) for color 3-vectors given by their real and
+    imaginary parts, the color index on ``axis`` — the arithmetic of
+    :func:`reconstruct_third_row` on real arrays."""
+
+    def c(x: jax.Array, i: int) -> jax.Array:
+        return jax.lax.index_in_dim(x, i, axis, keepdims=False)
+
+    def _comp(i: int, j: int) -> tuple[jax.Array, jax.Array]:
+        # conj(a[i]*b[j] - a[j]*b[i]), grouped as in _expand_tile
+        xr = (c(a_r, i) * c(b_r, j) - c(a_i, i) * c(b_i, j)) - (
+            c(a_r, j) * c(b_r, i) - c(a_i, j) * c(b_i, i)
+        )
+        xi = (c(a_r, i) * c(b_i, j) + c(a_i, i) * c(b_r, j)) - (
+            c(a_r, j) * c(b_i, i) + c(a_i, j) * c(b_r, i)
+        )
+        return xr, -xi
+
+    comps = [_comp(1, 2), _comp(2, 0), _comp(0, 1)]
+    return (jnp.stack([re for re, _ in comps], axis=axis),
+            jnp.stack([im for _, im in comps], axis=axis))
+
+
+def words_view(a: np.ndarray) -> np.ndarray:
+    """Canonical complex64 ``(B, S, 4, 3, 3)`` on the host as its float32
+    words, without a copy: ``(B, S·72/128, 128)`` when ``S·72`` is a
+    multiple of 128 (every even L), else ``(B, S·72)``.
+
+    The 3-D view is lane-dense: a TPU tiles it with no padding and its tiled
+    bytes are its row-major bytes, so it goes onto an accelerator as one
+    flat copy (unlike the canonical array, see :func:`on_host`).
+    """
+    w = np.ascontiguousarray(a, np.complex64).view(np.float32).reshape(a.shape[0], -1)
+    return w.reshape(a.shape[0], -1, LANE) if w.shape[1] % LANE == 0 else w
 
 
 @dataclasses.dataclass(frozen=True)
@@ -297,23 +330,38 @@ class LayoutCodec:
         form is (2, 24, S) / (tiles, 2, 24, lane); row 2 never exists
         physically.
         """
-        wdt = self.word_dtype
         if self.layout == Layout.AOS:
-            return pack_aos(a).astype(wdt)  # (S, 80)
+            return pack_aos(a).astype(self.word_dtype)  # (S, 80)
+        return self.pack_planar(
+            to_planar(jnp.moveaxis(a, 0, -1)).reshape(2, PLANAR_ROWS, -1))
+
+    def pack_planar(self, p: jax.Array) -> jax.Array:
+        """Planar (2, 36, n_sites) real, every row -> the physical form of a
+        planar-view layout, in the word dtype: TWO_ROW keeps rows 0 and 1 of
+        each link, AoSoA pads the sites to the tile and goes tile-major."""
         if self.is_compressed:
-            a = a[:, :, :2, :]  # (S, 4, 2, 3): keep rows 0, 1
-        rows = self.planar_rows
+            p = p.reshape(2, LINKS, SU3 * SU3, -1)[:, :, : 2 * SU3]
+            p = p.reshape(2, PLANAR_COMP_ROWS, -1)
         if self.layout == Layout.SOA:
-            return to_planar(jnp.moveaxis(a, 0, -1)).reshape(2, rows, -1).astype(wdt)
-        # AoSoA: pad sites to the lane, tile-major site order
-        n_sites = a.shape[0]
-        pad = (-n_sites) % self.tile
+            return p.astype(self.word_dtype)
+        if self.layout != Layout.AOSOA:
+            raise ValueError(f"{self.layout} has no planar form")
+        pad = (-p.shape[-1]) % self.tile
         if pad:
-            a = jnp.concatenate([a, jnp.zeros((pad,) + a.shape[1:], a.dtype)], axis=0)
-        n_tiles = a.shape[0] // self.tile
-        t = jnp.moveaxis(a.reshape((n_tiles, self.tile) + a.shape[1:]), 1, -1)
-        p = jnp.stack([jnp.real(t), jnp.imag(t)], axis=1)
-        return p.reshape(n_tiles, 2, rows, self.tile).astype(wdt)
+            p = jnp.pad(p, ((0, 0), (0, 0), (0, pad)))
+        t = p.reshape(2, self.planar_rows, -1, self.tile)
+        return jnp.moveaxis(t, 2, 0).astype(self.word_dtype)
+
+    def unpack_planar(self, phys: jax.Array) -> jax.Array:
+        """Physical form of a planar-view layout -> planar (2, 36, S) float32,
+        every row: TWO_ROW's third row is rebuilt with the arithmetic of
+        :meth:`unpack`."""
+        p = self.planar_view(phys).astype(jnp.float32)
+        if not self.is_compressed:
+            return p
+        t = p.reshape(2, LINKS, 2 * SU3, -1)  # rows 0 and 1 of each link
+        r2 = conj_cross(t[0, :, :SU3], t[1, :, :SU3], t[0, :, SU3:], t[1, :, SU3:], 1)
+        return jnp.concatenate([t, jnp.stack(r2)], axis=2).reshape(2, PLANAR_ROWS, -1)
 
     def unpack(self, phys: jax.Array, n_sites: int | None = None) -> jax.Array:
         """Physical -> canonical complex; slice to ``n_sites`` when given.

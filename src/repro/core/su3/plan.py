@@ -80,7 +80,7 @@ from repro.core.su3 import layouts, registry
 from repro.core.su3 import variants as _variants  # noqa: F401  (registers XLA kernels)
 from repro.core.su3.layouts import Layout, LatticeShape, LayoutCodec
 from repro.distributed import sharding as dist_sharding
-from repro.kernels import ops as _kops  # noqa: F401  (registers the Pallas kernel)
+from repro.kernels import ops as _kops  # registers the Pallas kernels
 from repro.launch.mesh import MeshSpec
 from repro.chaos.faults import NULL_FAULT_PLAN, corrupt_ghosts
 from repro.obs.tracer import NULL_TRACER
@@ -1824,6 +1824,20 @@ def build_plan(
     return ExecutionPlan.build(cfg, mesh)
 
 
+# Words of zeros ahead of the fetched words.  JAX's CPU client wraps a
+# numpy buffer without a copy only at a 64-byte boundary, and the host buffer
+# a TPU fetch lands in starts 16 bytes into a page; 12 words (48 bytes) put
+# the words themselves on the boundary.  Where they land off it (the CPU
+# backend's own buffers among them) the put copies them, once.
+FETCH_HEAD = 12
+
+
+def _relayout_sites(padded_sites: int) -> int:
+    """Sites the relayout kernels run over: ``padded_sites`` rounded up to a
+    whole number of 128-site blocks."""
+    return -(-padded_sites // layouts.LANE) * layouts.LANE
+
+
 class BatchedLatticeRunner:
     """Serve B independent lattices through one vmapped, sharded plan step.
 
@@ -1849,6 +1863,7 @@ class BatchedLatticeRunner:
         self.n_devices = self.plan.n_devices
         self._sharding = self.plan.lattice_batch_sharding()
         self._steps: dict[int, Callable[[jax.Array, jax.Array], jax.Array]] = {}
+        self._codecs: dict[tuple[Any, ...], Callable[..., Any]] = {}
         # recorder of multiply's steps, read at call time (like plan.tracer)
         self.tracer = NULL_TRACER
 
@@ -1922,30 +1937,125 @@ class BatchedLatticeRunner:
         c = self._batched_step(k)(a_batch, b_batch)
         return c[:bsz] if pad else c
 
+    def _whole_lattices(self, bsz: int, ndim: int) -> NamedSharding:
+        """Placement of a rank-``ndim`` batch of ``bsz`` lattices' words:
+        whole lattices per device when ``bsz`` divides the mesh, else every
+        device holds the whole batch."""
+        if bsz % self.n_devices:
+            return NamedSharding(self.mesh, P())
+        return NamedSharding(
+            self.mesh, P(self._sharding.spec[0], *(None,) * (ndim - 1)))
+
+    def _relayout(self, fn: Callable[[jax.Array], jax.Array], x: jax.Array,
+                  out_ndim: int) -> jax.Array:
+        """A relayout kernel ``fn`` over a batch, run where
+        :meth:`_whole_lattices` places it (a Pallas call on sharded operands
+        runs inside a shard_map)."""
+        bsz = x.shape[0]
+        return site_local(fn, self.mesh, self._whole_lattices(bsz, x.ndim).spec,
+                          self._whole_lattices(bsz, out_ndim).spec)(x)
+
+    def _device_pack(self, words: tuple[int, ...], links: tuple[int, ...]):
+        """Jitted ``(A words, B words) -> (A physical, B planar)`` on the
+        devices, for the words of A's ``padded_sites`` sites, shaped
+        ``words`` (see :func:`~repro.core.su3.layouts.words_view`), and B's
+        ``(B, 72)``.
+
+        The same data movement and cast as :meth:`_pack_on_host` and
+        ``pack_links_on_host``, bit for bit.  No array with the canonical
+        minor dimensions of 3 or 4 is made: the planar layouts go through
+        the relayout kernel, AoS through its own (S, 72) words."""
+        key = ("pack", words, links)
+        if key not in self._codecs:
+            codec, s_pad = self.plan.codec, self.plan.padded_sites
+            bsz, s_k = words[0], _relayout_sites(s_pad)
+
+            def pack(x: jax.Array, b: jax.Array) -> tuple[jax.Array, jax.Array]:
+                if codec.layout == Layout.AOS:
+                    w = x.reshape(bsz, s_pad, layouts.GAUGE_WORDS)
+                    a = jax.vmap(layouts.aos_from_words)(w).astype(codec.word_dtype)
+                else:
+                    x = x.reshape(bsz, -1)  # a no-op on lane-dense words
+                    x = jnp.pad(x, ((0, 0), (0, s_k * layouts.GAUGE_WORDS - x.shape[1])))
+                    p = self._relayout(_kops.planar_from_flat,
+                                       x.reshape(bsz, -1, layouts.LANE), 4)
+                    a = jax.vmap(codec.pack_planar)(p[..., :s_pad])
+                b = jnp.swapaxes(b.reshape(-1, layouts.PLANAR_ROWS, 2), 1, 2)
+                return a, b.astype(codec.word_dtype)
+
+            self._codecs[key] = jax.jit(
+                pack, out_shardings=(self.batch_sharding(bsz), self.plan.replicated))
+        return self._codecs[key]
+
+    def _device_unpack(self, phys: tuple[int, ...]):
+        """Jitted physical batch of shape ``phys`` -> the canonical float32
+        words of its ``padded_sites`` sites, flat and after a
+        ``FETCH_HEAD``-word head: the inverse of :meth:`_device_pack`, with
+        the values of :meth:`unpack_batch` (TWO_ROW's rebuilt third row
+        within rounding)."""
+        key = ("unpack", phys)
+        if key not in self._codecs:
+            codec, s_pad = self.plan.codec, self.plan.padded_sites
+            bsz, s_k = phys[0], _relayout_sites(s_pad)
+            n_words = s_pad * layouts.GAUGE_WORDS
+
+            def unpack(c: jax.Array) -> jax.Array:
+                if codec.layout == Layout.AOS:
+                    w = c[..., : layouts.GAUGE_WORDS].astype(jnp.float32)
+                else:
+                    p = jax.vmap(codec.unpack_planar)(c)
+                    p = jnp.pad(p, ((0, 0), (0, 0), (0, 0), (0, s_k - s_pad)))
+                    w = self._relayout(_kops.flat_from_planar, p, 3)
+                w = w.reshape(bsz, -1)[:, :n_words].reshape(-1)
+                return jnp.concatenate([jnp.zeros((FETCH_HEAD,), w.dtype), w])
+
+            self._codecs[key] = jax.jit(unpack, out_shardings=self.plan.replicated)
+        return self._codecs[key]
+
     def multiply(self, a: jax.Array, b: jax.Array, k: int = 1) -> jax.Array:
         """Canonical batched entry: a (B, S, 4, 3, 3), b (B, 4, 3, 3) complex.
 
-        Five steps, each a span on :attr:`tracer`: ``codec.pack`` (canonical
-        to physical on the host), ``transfer.h2d`` (``bytes`` placed on the
-        devices), ``device.step`` (the batched chain of ``k``),
-        ``transfer.d2h`` (``bytes`` fetched back) and ``codec.unpack``.  A
-        recording tracer waits for each step's work before closing its span;
-        an idle one waits for nothing, and the caller waits on the result.
+        Five steps, each a span on :attr:`tracer`: ``transfer.h2d``
+        (``bytes`` placed on the devices: A's and B's canonical words,
+        viewed as float32 without a copy), ``codec.pack`` (``on="device"``:
+        the words relaid into the plan's physical form), ``device.step``
+        (the batched chain of ``k``), ``codec.unpack`` (``on="device"``:
+        back to canonical words) and ``transfer.d2h`` (``bytes`` fetched;
+        the host reads them as complex, with no copy where the CPU array
+        can alias the fetched buffer, see :data:`FETCH_HEAD`).  A lattice
+        smaller than the plan travels with its sites zero-padded to the
+        plan's, as the physical form did.  A recording tracer waits for each step's
+        work before closing its span; an idle one waits for nothing, and the
+        caller waits on the result.
         """
         tr = self.tracer
         done = jax.block_until_ready if tr.enabled else (lambda x: x)
-        n_sites = a.shape[1]
-        with tr.span("codec.pack"):
-            a_phys = done(self._pack_on_host(a))
-            b_p = done(self.plan.pack_links_on_host(b))
+        bsz, n_sites = a.shape[:2]
+        s_pad = self.plan.padded_sites
+        if n_sites > s_pad:
+            raise ValueError(
+                f"batch carries {n_sites} sites > plan capacity "
+                f"{s_pad} (L={self.cfg.L}, tile={self.cfg.tile})"
+            )
+        a = np.asarray(a)
+        if n_sites < s_pad:
+            a = np.concatenate(
+                [a, np.zeros((bsz, s_pad - n_sites) + a.shape[2:], a.dtype)], axis=1)
+        words, links = layouts.words_view(a), layouts.words_view(np.asarray(b))
+        # each step rebinds ``x``, so no step's input outlives it on the device
         with tr.span("transfer.h2d") as span:
-            a_phys, b_p = done(jax.device_put(
-                (a_phys, b_p), (self.batch_sharding(a.shape[0]), self.plan.replicated)))
-            span.set(bytes=a_phys.nbytes + b_p.nbytes)
+            x, b_p = done(jax.device_put(
+                (words, links),
+                (self._whole_lattices(bsz, words.ndim), self.plan.replicated)))
+            span.set(bytes=x.nbytes + b_p.nbytes)
+        with tr.span("codec.pack", on="device"):
+            x, b_p = done(self._device_pack(words.shape, links.shape)(x, b_p))
         with tr.span("device.step", k=k):
-            c_phys = done(self.run(a_phys, b_p, k=k))
+            x = done(self.run(x, b_p, k=k))
+        with tr.span("codec.unpack", on="device"):
+            x = done(self._device_unpack(x.shape)(x))
         with tr.span("transfer.d2h") as span:
-            c_phys = done(jax.device_put(c_phys, jax.devices("cpu")[0]))
-            span.set(bytes=c_phys.nbytes)
-        with tr.span("codec.unpack"):
-            return done(self.unpack_batch(c_phys, n_sites))
+            c = np.asarray(x)[FETCH_HEAD:].view(np.complex64).reshape(a.shape)
+            span.set(bytes=c.nbytes)
+            return jax.device_put(c[:, :n_sites], jax.devices("cpu")[0])
+

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.core.su3 import layouts, registry
 from repro.core.su3.layouts import Layout
 from repro.kernels import ref as kref
-from repro.kernels import su3_matmul, su3_stencil
+from repro.kernels import su3_matmul, su3_relayout, su3_stencil
 
 DEFAULT_TILE = 512
 
@@ -154,6 +154,22 @@ def su3_cg_fused_planar(
         u_p, r_nbr, p_nbr, r_p, p_p, coefs, tile=tile, interpret=interpret,
         accum_dtype=accum_dtype, compressed=compressed,
     )
+
+
+def planar_from_flat(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
+    """Canonical float32 words (B, S·9/16, 128) -> planar (B, 2, 36, S), on
+    the device (:mod:`repro.kernels.su3_relayout`)."""
+    if interpret is None:
+        interpret = _use_interpret()
+    return su3_relayout.planar_from_flat(x, interpret=interpret)
+
+
+def flat_from_planar(p: jax.Array, *, interpret: bool | None = None) -> jax.Array:
+    """Planar (B, 2, 36, S) float32 -> canonical words (B, S·9/16, 128), on
+    the device (:mod:`repro.kernels.su3_relayout`)."""
+    if interpret is None:
+        interpret = _use_interpret()
+    return su3_relayout.flat_from_planar(p, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))

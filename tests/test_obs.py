@@ -418,8 +418,8 @@ def test_no_annotation_with_the_recorder_off_or_no_session(tmp_path):
 
 # -- a served batch, span by span (tiny lattice, no autotune) ----------------
 
-_STEPS = ("codec.pack", "transfer.h2d", "device.step", "transfer.d2h",
-          "codec.unpack")
+_STEPS = ("transfer.h2d", "codec.pack", "device.step", "codec.unpack",
+          "transfer.d2h")
 
 
 def _served_batch(svc, n=2, k=2):

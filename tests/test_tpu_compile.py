@@ -156,3 +156,51 @@ def test_sharded_plan_compiles_on_four_chips(four_chips):
     cg = jax.jit(plan._cg_apply(fused=True, overlap=True)).lower(
         u, v, v, coefs).compile()
     assert _kernels(cg) == 2
+
+
+def _device_codec(runner, bsz):
+    """The served multiply's device pack and unpack at ``bsz`` fields,
+    compiled for the runner's mesh."""
+    words = (bsz, runner.plan.padded_sites * 72 // 128, 128)
+    rep = runner.plan.replicated
+    pack = runner._device_pack(words, (bsz, 72)).lower(
+        _shape(words, jnp.float32, runner._whole_lattices(bsz, 3)),
+        _shape((bsz, 72), jnp.float32, rep)).compile()
+    phys = jax.eval_shape(runner._device_pack(words, (bsz, 72)),
+                          jax.ShapeDtypeStruct(words, jnp.float32),
+                          jax.ShapeDtypeStruct((bsz, 72), jnp.float32))[0]
+    unpack = runner._device_unpack(phys.shape).lower(
+        _shape(phys.shape, phys.dtype, runner.batch_sharding(bsz))).compile()
+    return pack, unpack
+
+
+def _canonical_shapes(compiled) -> list[str]:
+    """Arrays whose minor dimension is the canonical 3 or 4, which a TPU
+    pads to 128 lanes."""
+    import re
+
+    return re.findall(r"\b(?:f32|bf16|c64)\[[0-9,]*,[34]\]", compiled.as_text())
+
+
+@pytest.mark.parametrize("dtype,accum,compression", [
+    ("float32", "", "none"),
+    ("bfloat16", "float32", "none"),
+    ("float32", "", "two_row"),
+])
+def test_device_codec_compiles_lane_dense(one_chip, dtype, accum, compression):
+    """At the served cell's L=32 and two fields: one relayout kernel each
+    way and no canonical-shaped array on the chip."""
+    runner = BatchedLatticeRunner(EngineConfig(
+        L=L, tile=TILE, dtype=dtype, accum_dtype=accum,
+        compression=compression), one_chip)
+    for compiled in _device_codec(runner, 2):
+        assert _kernels(compiled) == 1
+        assert _canonical_shapes(compiled) == []
+
+
+@pytest.mark.parametrize("bsz", [4, 2])  # whole lattices per chip / replicated
+def test_device_codec_compiles_on_four_chips(four_chips, bsz):
+    runner = BatchedLatticeRunner(EngineConfig(L=L, tile=TILE), four_chips)
+    for compiled in _device_codec(runner, bsz):
+        assert _kernels(compiled) == 1
+        assert _canonical_shapes(compiled) == []
