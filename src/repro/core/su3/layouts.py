@@ -83,6 +83,22 @@ class LatticeShape:
         return ((self.n_sites + lane - 1) // lane) * lane
 
 
+def parity_sites(L: int) -> tuple[np.ndarray, np.ndarray]:
+    """Site ids of the even and of the odd sites, each in increasing order.
+
+    Sites are t-major, ``((t*L + z)*L + y)*L + x``, and a site's parity is
+    ``(x + y + z + t) % 2``.  A half-lattice (even/odd) vector is the
+    canonical ``(L**4 // 2, 3)`` field of one parity in this order; its
+    planar form is the codec's ``pack_vec`` of it.  L must be even, or the
+    periodic lattice is not bipartite.
+    """
+    if L % 2:
+        raise ValueError(f"an even/odd split needs an even L, got L={L}")
+    s = np.arange(L**4, dtype=np.int64)
+    parity = (s % L + s // L % L + s // L**2 % L + s // L**3) % 2
+    return np.flatnonzero(parity == 0), np.flatnonzero(parity == 1)
+
+
 # ---------------------------------------------------------------------------
 # Canonical <-> physical layout converters.
 # ---------------------------------------------------------------------------

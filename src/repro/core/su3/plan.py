@@ -531,7 +531,11 @@ class CGResult:
 
     ``residuals[i]`` is the relative residual ``||r|| / ||b||`` after
     iteration ``i + 1`` — the iterate-by-iterate series the convergence
-    tier pins against :func:`cg_reference_solve`.
+    tier pins against :func:`cg_reference_solve`.  ``dslash_applies``
+    counts the operator applications dispatched (one stencil pass per
+    site-local iteration, two half-lattice Dslash passes per even/odd
+    staggered one; the lagged check's extra iteration and a restart's
+    ``A x0`` included).
     """
 
     x_p: jax.Array
@@ -539,6 +543,7 @@ class CGResult:
     residuals: list[float]
     converged: bool
     wall_s: float
+    dslash_applies: int = 0
 
 
 def stencil_apply_reference(u: jax.Array, v: jax.Array, L: int) -> jax.Array:
@@ -576,6 +581,179 @@ def cg_reference_solve(
     """
     # u is an argument, not a closed-over constant: at L=32 it is 302 MB
     apply_j = jax.jit(lambda u, p: sigma * p + stencil_apply_reference(u, p, L))
+    return _textbook_cg(apply_j, u, b, tol, max_iters)
+
+
+# -- the even/odd staggered (Kogut-Susskind) operator ------------------------
+#
+# MILC's ks_congrad solves M^dag M x = b with M = 2m + D and the one-link
+# staggered Dslash, in MILC's normalisation (no 1/2):
+#
+#     D v(x) = sum_mu eta_mu(x) [ U_mu(x) v(x + mu) - U_mu(x - mu)^dag v(x - mu) ]
+#
+# eta_x = 1, eta_y = (-1)^x, eta_z = (-1)^(x+y), eta_t = (-1)^(x+y+z), and the
+# fermion is antiperiodic in t: as MILC's rephasing does, that sign is folded
+# into the t links of the last time slice.  D only connects sites of opposite
+# parity, so on even sites (M^dag M)_ee = 4 m^2 - D_eo D_oe, Hermitian and
+# positive: the CG operator, applied as two half-lattice Dslash passes.
+
+KS_OPERATOR = "ks_eo"
+SITE_LOCAL_OPERATOR = "site_local"
+
+
+def staggered_phases(L: int) -> np.ndarray:
+    """``(4, L**4)`` float32: the sign each stored link U_mu(x) carries,
+    eta_mu(x) times -1 on the t links of the last time slice."""
+    s = np.arange(L**4, dtype=np.int64)
+    x, y, z, t = s % L, s // L % L, s // L**2 % L, s // L**3
+    eta = np.stack([np.ones_like(x), (-1) ** x, (-1) ** (x + y), (-1) ** (x + y + z)])
+    eta[3] = np.where(t == L - 1, -eta[3], eta[3])
+    return eta.astype(np.float32)
+
+
+def staggered_neighbor_tables(L: int, half: int) -> np.ndarray:
+    """``(2, 8, half)`` int32: for each site of parity 0 (even) and 1 (odd),
+    in :func:`layouts.parity_sites` order and padded to ``half`` sites, the
+    stencil's 8 neighbours (direction order of
+    :func:`stencil_neighbor_tables`) as positions in the OTHER parity's
+    half vector.  Padding sites point at themselves (their links are 0)."""
+    S = L**4
+    even, odd = layouts.parity_sites(L)
+    if half < S // 2:
+        raise ValueError(f"half lattice of {half} sites < {S // 2}")
+    glob, _local, _b = stencil_neighbor_tables(L, S, 1)
+    pos = np.empty(S, np.int64)
+    pos[even] = np.arange(S // 2)
+    pos[odd] = np.arange(S // 2)
+    nbr = np.tile(np.arange(half, dtype=np.int64), (2, 2 * layouts.LINKS, 1))
+    for par, sites in enumerate((even, odd)):
+        nbr[par, :, : S // 2] = pos[glob[:, sites]]
+    return nbr.astype(np.int32)
+
+
+def split_parity(a: jax.Array, L: int) -> tuple[jax.Array, jax.Array]:
+    """``(..., L**4)`` site-last array -> its even and odd sites
+    ``(..., L**4 // 2)`` each, in :func:`layouts.parity_sites` order.
+
+    In a row of fixed (t, z, y) the sites of one parity are every other x,
+    starting at x = (t + z + y + parity) % 2: a shift by one site where the
+    row starts odd, then every other site.  Site-last and gather-free, so
+    the site axis stays the lane axis of every intermediate on a TPU."""
+    row = np.arange(L**4) // L
+    odd_row = (row // L**2 + row // L % L + row % L) % 2 == 1
+    shifted = jnp.roll(a, -1, axis=-1)
+    even = jnp.where(odd_row, shifted, a)[..., ::2]
+    odd = jnp.where(odd_row, a, shifted)[..., ::2]
+    return even, odd
+
+
+def roll_back(a: jax.Array, L: int, mu: int) -> jax.Array:
+    """``(..., L**4)`` site-last array -> its value at x - mu at each site x
+    (periodic): a roll of the flat site axis, corrected on the face where
+    the coordinate wraps."""
+    stride = L**mu
+    return jnp.where(np.arange(L**4) // stride % L == 0,
+                     jnp.roll(a, -(L - 1) * stride, axis=-1),
+                     jnp.roll(a, stride, axis=-1))
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["fwd_e", "bwd_e", "fwd_o", "bwd_o", "nbr_e", "nbr_o"],
+    meta_fields=["mass"])
+@dataclasses.dataclass(frozen=True)
+class StaggeredField:
+    """A gauge field prepared for the even/odd staggered operator at one
+    valence mass (:meth:`ExecutionPlan.prepare_staggered`).
+
+    Per parity, planar ``(2, 36, half)`` links in the plan's word dtype:
+    ``fwd`` = eta_mu(x) U_mu(x), ``bwd`` = -eta_mu(x) U_mu(x - mu), both with
+    the antiperiodic t sign; and ``nbr (8, half)``, where each site's
+    neighbours sit in the other parity's vector
+    (:func:`staggered_neighbor_tables`).
+    Passed to ``cg_solve`` / ``cg_iterate`` in place of a physical gauge
+    lattice, it selects the operator ``4 m^2 - D_eo D_oe`` on even-site
+    vectors (``pack_half``).
+    """
+
+    fwd_e: jax.Array
+    bwd_e: jax.Array
+    fwd_o: jax.Array
+    bwd_o: jax.Array
+    nbr_e: jax.Array
+    nbr_o: jax.Array
+    mass: float
+
+    @property
+    def sigma(self) -> float:
+        """The shift of the normal operator: 4 m^2."""
+        return 4.0 * self.mass * self.mass
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in jax.tree.leaves(self))
+
+
+def staggered_apply_reference(u: jax.Array, v: jax.Array, L: int) -> jax.Array:
+    """Plain-jnp staggered Dslash ``D v`` on canonical complex arrays.
+
+    ``u (S, 4, 3, 3)``, ``v (S, 3)``: the textbook form, phases and the
+    antiperiodic t boundary applied to the hops (not folded into links),
+    the backward hop reading the neighbour's link — independent of the
+    plan's preparation, as the oracle the kernel is tested against.
+    """
+    S = L**4
+    glob, _local, _b = stencil_neighbor_tables(L, S, 1)
+    s = np.arange(S)
+    x, y, z, t = s % L, s // L % L, s // L**2 % L, s // L**3
+    eta = [np.ones(S), (-1.0) ** x, (-1.0) ** (x + y), (-1.0) ** (x + y + z)]
+    fwd_bc = [np.ones(S)] * 3 + [np.where(t == L - 1, -1.0, 1.0)]
+    bwd_bc = [np.ones(S)] * 3 + [np.where(t == 0, -1.0, 1.0)]
+    out = jnp.zeros_like(v)
+    with jax.default_matmul_precision("highest"):
+        for mu in range(layouts.LINKS):
+            fwd = jnp.einsum("skl,sl->sk", u[:, mu], v[glob[mu]])
+            back = jnp.einsum("slk,sl->sk", jnp.conj(u[glob[4 + mu], mu]),
+                              v[glob[4 + mu]])
+            f = jnp.asarray(eta[mu] * fwd_bc[mu], jnp.float32)[:, None]
+            b = jnp.asarray(eta[mu] * bwd_bc[mu], jnp.float32)[:, None]
+            out = out + f * fwd - b * back
+    return out
+
+
+def staggered_eo_reference_solve(
+    u: jax.Array,
+    b_e: jax.Array,
+    L: int,
+    mass: float,
+    *,
+    tol: float = 1e-6,
+    max_iters: int = 500,
+) -> tuple[jax.Array, list[float], bool]:
+    """Plain-jnp CG on ``(4 m^2 - D_eo D_oe) x_e = b_e`` — the oracle of the
+    staggered ``cg_solve``.
+
+    ``b_e (S/2, 3)`` is an even-site vector (:func:`layouts.parity_sites`
+    order).  The solve runs on full-lattice vectors that vanish on odd
+    sites, where ``4 m^2 v - D(D v)`` is exactly the even-even block.
+    Returns ``(x_e, relative residuals per iteration, converged)``.
+    """
+    even, _odd = layouts.parity_sites(L)
+    sigma = 4.0 * mass * mass
+
+    def op(u, p):
+        with jax.default_matmul_precision("highest"):
+            return sigma * p - staggered_apply_reference(
+                u, staggered_apply_reference(u, p, L), L)
+
+    full = jnp.zeros((L**4, layouts.SU3), jnp.complex64).at[even].set(b_e)
+    x, residuals, converged = _textbook_cg(jax.jit(op), u, full, tol, max_iters)
+    return x[even], residuals, converged
+
+
+def _textbook_cg(apply_j, u, b, tol, max_iters):
+    """Complex CG from x = 0 on canonical arrays; never raises on
+    exhaustion (the oracle reports, the plan enforces)."""
     b_rs = float(jnp.sum(jnp.real(b) ** 2 + jnp.imag(b) ** 2))
     if b_rs == 0.0:
         return jnp.zeros_like(b), [], True
@@ -719,6 +897,7 @@ class ExecutionPlan:
         self._stencil_parts: dict[str, Any] | None = None
         self._cg_help: dict[str, Any] | None = None
         self._cg_applies: dict[tuple[bool, bool], Callable[..., Any]] = {}
+        self._ks: dict[str, Any] | None = None
         # Phase tracer for the stencil schedule (repro.obs).  Disabled by
         # default: the untraced closures are byte-identical to pre-obs code.
         # When enabled, each schedule phase (exchange / interior / boundary)
@@ -873,7 +1052,8 @@ class ExecutionPlan:
         return self._stencil_tables
 
     def _stencil_kernel(
-        self, variant: str = STENCIL_VARIANT, sharded: bool = True
+        self, variant: str = STENCIL_VARIANT, sharded: bool = True,
+        backward_links: bool = False,
     ) -> Callable[..., Any]:
         """The stencil-family kernel with the plan's kwargs bound.
 
@@ -882,6 +1062,8 @@ class ExecutionPlan:
         gauge ``(2, rows, S)``, gathered neighbors ``(8, 2, 3, S)``, vectors
         ``(2, 3, S)`` — and the CG coefficients are replicated.  Unsharded is
         the per-lattice body a caller vmaps and shards itself.
+        ``backward_links`` takes the stencil's third operand, the backward
+        hop's own ``(2, 36, S)`` links (the staggered operator).
         """
         kernel = registry.get_kernel(variant)
         if not kernel.supports_layout(self.codec.layout):
@@ -910,7 +1092,8 @@ class ExecutionPlan:
         ax = self.site_axes if len(self.site_axes) > 1 else self.site_axes[0]
         vec, nbr = P(None, None, ax), P(None, None, None, ax)
         if kernel.form == registry.STENCIL:
-            return site_local(fn, self.mesh, (vec, nbr), vec)
+            specs = (vec, nbr, vec) if backward_links else (vec, nbr)
+            return site_local(fn, self.mesh, specs, vec)
         return site_local(
             fn, self.mesh, (vec, nbr, nbr, vec, vec, P()), (vec, vec)
         )
@@ -1334,6 +1517,130 @@ class ExecutionPlan:
             and jnp.max(jnp.abs(jnp.imag(c))) < tol
         )
 
+    # -- the even/odd staggered operator ----------------------------------------
+
+    @property
+    def half_sites(self) -> int:
+        """Sites per parity, padded so every device shard is whole tiles."""
+        chunk = self.n_devices * self.cfg.tile
+        return -(-(self.cfg.shape.n_sites // 2) // chunk) * chunk
+
+    def _ks_parts(self) -> dict[str, Any]:
+        """The staggered operator's host tables
+        (:func:`staggered_neighbor_tables`, :func:`staggered_phases`) and
+        jitted pieces, built once per plan.
+
+        ``prepare`` folds the phases into the links, rolls each direction's
+        links one site on for the backward hop (:func:`roll_back`) and
+        splits both by parity (:func:`split_parity`); ``dslash`` is one
+        half-lattice pass ``D_{p,1-p} v``: the gather of the other parity's
+        vector through ``nbr`` and one stencil kernel with backward links;
+        ``shift`` the normal operator's epilogue ``sigma p - s``.
+        """
+        if self._ks is not None:
+            return self._ks
+        if self.codec.is_compressed:
+            raise ValueError(
+                "the staggered operator needs full link rows: its phase-folded "
+                "links are not SU(3), so two-row storage cannot rebuild row 2")
+        L, H, f32 = self.cfg.L, self.half_sites, jnp.float32
+        S, rows = L**4, layouts.SU3 * layouts.SU3
+        kernel = self._stencil_kernel(backward_links=True)
+        vec_sh = self.vec_sharding
+
+        def prepare(u_p, phases):
+            signed = u_p[:, :, :S] * jnp.repeat(phases, rows, axis=0).astype(u_p.dtype)
+            back = -jnp.concatenate(
+                [roll_back(signed[:, rows * mu: rows * (mu + 1)], L, mu)
+                 for mu in range(layouts.LINKS)], axis=1)
+            fwd_e, fwd_o = split_parity(signed, L)
+            bwd_e, bwd_o = split_parity(back, L)
+            pad = ((0, 0), (0, 0), (0, H - S // 2))  # zero links: zero outputs
+            return tuple(jnp.pad(a, pad) for a in (fwd_e, bwd_e, fwd_o, bwd_o))
+
+        def dslash(fwd, bwd, v, nbr):
+            return kernel(fwd, gather_neighbors(v, nbr), bwd)
+
+        def shift(p, sigma, s):
+            return (sigma.astype(f32) * p.astype(f32) - s.astype(f32)).astype(p.dtype)
+
+        self._ks = dict(
+            nbr=staggered_neighbor_tables(L, H), phases=staggered_phases(L),
+            prepare=jax.jit(prepare, out_shardings=(vec_sh,) * 4),
+            dslash=jax.jit(dslash, out_shardings=vec_sh),
+            shift=jax.jit(shift, out_shardings=vec_sh),
+        )
+        return self._ks
+
+    def prepare_staggered(self, u: jax.Array, mass: float) -> StaggeredField:
+        """Canonical gauge field ``(L**4, 4, 3, 3)`` -> the resident
+        :class:`StaggeredField` of the even/odd staggered operator at valence
+        mass ``mass``: packed (on the host, as :meth:`pack_gauge`), then, on
+        the device, each link times its phase, each direction's links rolled
+        one site on for the backward hop, both split by parity.  Run once per
+        field; the field's solves then gather only vectors."""
+        if not mass > 0:
+            raise ValueError(f"the staggered mass must be > 0, got {mass}")
+        parts = self._ks_parts()
+        tr = self.tracer
+        with tr.span("ks.prepare", L=self.cfg.L, mass=float(mass)) as span:
+            u_p = self.codec.planar_view(self.pack_gauge(u))
+            links = parts["prepare"](u_p, parts["phases"])
+            nbr = (jax.device_put(t, self.replicated) for t in parts["nbr"])
+            field = StaggeredField(*links, *nbr, mass=float(mass))
+            if tr.enabled:
+                jax.block_until_ready(field)
+                span.set(bytes=field.nbytes)
+        return field
+
+    def dslash_half(self, field: StaggeredField, v_p: jax.Array, parity: int) -> jax.Array:
+        """One half-lattice Dslash: ``D_eo v`` (``parity=0``, v odd) or
+        ``D_oe v`` (``parity=1``, v even), planar ``(2, 3, half_sites)``."""
+        if parity == 0:
+            return self._ks_parts()["dslash"](field.fwd_e, field.bwd_e, v_p, field.nbr_e)
+        return self._ks_parts()["dslash"](field.fwd_o, field.bwd_o, v_p, field.nbr_o)
+
+    def _ks_apply(self) -> Callable[..., Any]:
+        """The staggered CG apply ``(field, r_p, p_p, coefs) -> (p', ap)``:
+        ``p' = r + beta p`` (the composed path's shared axpy), then
+        ``ap = 4 m^2 p' - D_eo (D_oe p')`` as two half-lattice passes."""
+        parts, h = self._ks_parts(), self._cg_helpers()
+
+        def apply(field, r_p, p_p, coefs):
+            p_new = h["axpy"](r_p, coefs[0, 0], p_p)
+            w = self.dslash_half(field, p_new, 1)  # D_oe p': odd sites
+            s = self.dslash_half(field, w, 0)  # D_eo D_oe p': even sites
+            return p_new, parts["shift"](p_new, coefs[0, 1], s)
+
+        return apply
+
+    def pack_half(self, b: jax.Array) -> jax.Array:
+        """Canonical half-lattice vector ``(L**4 // 2, 3)`` (one parity, in
+        :func:`layouts.parity_sites` order) -> planar ``(2, 3, half_sites)``
+        under the plan's vector sharding, zero-padded."""
+        v_p = layouts.on_host(self.codec.pack_vec, b, self.half_sites)
+        return jax.device_put(v_p, self.vec_sharding)
+
+    def unpack_half(self, x_p: jax.Array) -> jax.Array:
+        """Planar half-lattice vector -> canonical ``(L**4 // 2, 3)`` on the
+        host."""
+        return self.unpack_vec(x_p, self.cfg.shape.n_sites // 2)
+
+    def _cg_operator(self, u_phys: Any, sigma: float | None, fused: bool,
+                     overlap: bool | None) -> tuple[Callable[..., Any], float]:
+        """The CG apply and its shift for what ``u_phys`` is: a
+        :class:`StaggeredField` selects ``4 m^2 - D_eo D_oe`` (shift from
+        its mass; ``fused``/``overlap`` do not apply), a physical gauge
+        lattice the site-local ``sigma I + S`` (default :data:`CG_SHIFT`)."""
+        if isinstance(u_phys, StaggeredField):
+            if sigma is not None:
+                raise ValueError("the staggered operator's shift is 4 m^2 from "
+                                 "its field's mass; pass no sigma")
+            return self._ks_apply(), u_phys.sigma
+        if overlap is None:
+            overlap = self.is_multi_host
+        return self._cg_apply(fused, bool(overlap)), CG_SHIFT if sigma is None else sigma
+
     # -- conjugate-gradient solver (fused stencil+axpy iteration) --------------
 
     def _cg_helpers(self) -> dict[str, Any]:
@@ -1521,8 +1828,8 @@ class ExecutionPlan:
         b_p: jax.Array,
         x0_p: jax.Array | None = None,
         *,
-        u_phys: jax.Array | None = None,
-        sigma: float = CG_SHIFT,
+        u_phys: jax.Array | StaggeredField | None = None,
+        sigma: float | None = None,
         fused: bool = True,
         overlap: bool | None = None,
     ) -> dict[str, Any]:
@@ -1546,9 +1853,7 @@ class ExecutionPlan:
         if u_phys is None:
             raise ValueError("resuming cg_state_init from x0_p needs u_phys "
                              "to form r0 = b - A x0")
-        if overlap is None:
-            overlap = self.is_multi_host
-        apply_fn = self._cg_apply(fused, bool(overlap))
+        apply_fn, sigma = self._cg_operator(u_phys, sigma, fused, overlap)
         zeros, _r, _p = h["init"](b_p)
         # beta = 0 makes the apply's p' = x0 exactly, so ap = A x0 comes out
         # of the same compiled pass the iterations use
@@ -1562,10 +1867,10 @@ class ExecutionPlan:
 
     def cg_iterate(
         self,
-        u_phys: jax.Array,
+        u_phys: jax.Array | StaggeredField,
         state: dict[str, Any],
         *,
-        sigma: float = CG_SHIFT,
+        sigma: float | None = None,
         fused: bool = True,
         overlap: bool | None = None,
     ) -> dict[str, Any]:
@@ -1574,11 +1879,10 @@ class ExecutionPlan:
         global residual reduction): ``cg_solve`` fetches it one iteration
         late, so the reduce's host round trip overlaps the next iteration's
         interior pass; the serving layer syncs per scheduling turn.
+        ``u_phys`` selects the operator as in :meth:`cg_solve`.
         """
-        if overlap is None:
-            overlap = self.is_multi_host
         h = self._cg_helpers()
-        apply_fn = self._cg_apply(fused, bool(overlap))
+        apply_fn, sigma = self._cg_operator(u_phys, sigma, fused, overlap)
         coefs = h["coef"](state["beta"], sigma)
         p, ap = apply_fn(u_phys, state["r"], state["p"], coefs)
         alpha = h["scal"](state["rs"], h["dot"](p, ap))
@@ -1592,19 +1896,30 @@ class ExecutionPlan:
 
     def cg_solve(
         self,
-        u_phys: jax.Array,
+        u_phys: jax.Array | StaggeredField,
         b_p: jax.Array,
         *,
         tol: float = 1e-6,
         max_iters: int = 200,
-        sigma: float = CG_SHIFT,
+        sigma: float | None = None,
         fused: bool = True,
         overlap: bool | None = None,
         x0_p: jax.Array | None = None,
     ) -> CGResult:
-        """Conjugate gradients on ``A = sigma I + S`` to ``||r|| <= tol ||b||``.
+        """Conjugate gradients to ``||r|| <= tol ||b||`` on one of two
+        operators, chosen by what ``u_phys`` is:
 
-        The flagship iterative workload: each iteration is one fused
+        * a physical gauge lattice: ``A = sigma I + S``, S the site-local-
+          adjoint stencil (Hermitian only on fields constant along each
+          link's own direction), full-lattice vectors (:meth:`pack_rhs`);
+        * a :class:`StaggeredField` (:meth:`prepare_staggered`): MILC's
+          even/odd staggered normal operator ``4 m^2 - D_eo D_oe`` on
+          even-site vectors (:meth:`pack_half`), two half-lattice Dslash
+          passes per iteration; ``sigma``, ``fused`` and ``overlap`` do not
+          apply (on several devices the passes run on the site sharding
+          without an overlap schedule).
+
+        On the site-local operator each iteration is one fused
         stencil+axpy pallas pass (``fused=True``; ``fused=False`` composes
         ``stencil_step`` + the shared axpy — the bit-identity oracle) plus
         the shared scalar updates.  Convergence is checked one iteration
@@ -1616,13 +1931,16 @@ class ExecutionPlan:
 
         Args:
             u_phys: the plan's physical gauge lattice (``init_data`` /
-                ``codec.pack`` form, padded to ``padded_sites``).
+                ``codec.pack`` form, padded to ``padded_sites``), or a
+                :class:`StaggeredField`.
             b_p: planar right-hand side ``(2, 3, padded_sites)``
-                (``codec.pack_vec``), sharded like :attr:`vec_sharding`.
+                (``codec.pack_vec``), or ``(2, 3, half_sites)`` for the
+                staggered operator, sharded like :attr:`vec_sharding`.
             tol: relative residual target.
             max_iters: hard bound; exhaustion RAISES :class:`CGMaxItersError`
                 (never hangs — the loop is host-bounded).
-            sigma: SPD shift (see :data:`CG_SHIFT`).
+            sigma: the site-local operator's SPD shift (default
+                :data:`CG_SHIFT`).
             fused / overlap: iteration body selection, as above.
             x0_p: optional warm start (a prior partial iterate) — restarts
                 from ``r0 = b - A x0`` via :meth:`cg_state_init` instead of
@@ -1646,20 +1964,31 @@ class ExecutionPlan:
         if not math.isfinite(b_rs):
             raise CGDivergedError(0, float("nan"), tol,
                                   reason="non-finite right-hand side")
+        staggered = isinstance(u_phys, StaggeredField)
+        operator = KS_OPERATOR if staggered else SITE_LOCAL_OPERATOR
+        per_iter = 2 if staggered else 1  # half-lattice passes vs one stencil
         stop2 = (tol * tol) * b_rs
         state = self.cg_state_init(b_p, x0_p, u_phys=u_phys, sigma=sigma,
                                    fused=fused, overlap=overlap)
         residuals: list[float] = []
         prev: tuple[jax.Array, jax.Array] | None = None  # (x_i, rs_i)
         best: tuple[jax.Array, float, int] | None = None  # (x, rs_host, iter)
+        dispatched = 0  # iterations dispatched, the lagged one included
+
+        def result(x: jax.Array, iterations: int, converged: bool) -> CGResult:
+            applies = per_iter * dispatched + (0 if x0_p is None else per_iter)
+            if tr.enabled:
+                tr.count("dslash_applies", applies)
+            return CGResult(x_p=x, iterations=iterations,
+                            residuals=list(residuals), converged=converged,
+                            wall_s=time.perf_counter() - t0,
+                            dslash_applies=applies)
 
         def partial(iterations: int) -> CGResult | None:
             # the best-so-far iterate, packaged for x0_p resume
             if best is None:
                 return None
-            return CGResult(x_p=best[0], iterations=iterations,
-                            residuals=list(residuals), converged=False,
-                            wall_s=time.perf_counter() - t0)
+            return result(best[0], iterations, False)
 
         def check(rs_host: float, x: jax.Array, it: int) -> None:
             # NaN/Inf or blow-up means breakdown, not slow convergence
@@ -1678,13 +2007,15 @@ class ExecutionPlan:
             if tr.enabled:
                 # traced: the iter span blocks so it measures the iteration —
                 # tracing synchronizes, as with the stencil schedule spans
-                with tr.span("cg.iter", it=i, fused=bool(fused)):
+                with tr.span("cg.iter", it=i, fused=bool(fused) and not staggered,
+                             operator=operator):
                     state = self.cg_iterate(
                         u_phys, state, sigma=sigma, fused=fused, overlap=overlap)
                     jax.block_until_ready(state["rs"])
             else:
                 state = self.cg_iterate(
                     u_phys, state, sigma=sigma, fused=fused, overlap=overlap)
+            dispatched = i
             if prev is not None:
                 # lagged check: iteration i is already in flight; this fetch
                 # is the previous iteration's global reduce landing
@@ -1695,16 +2026,13 @@ class ExecutionPlan:
                     rs_host = float(jax.device_get(prev[1]))
                 residuals.append((rs_host / b_rs) ** 0.5)
                 if rs_host <= stop2:
-                    return CGResult(
-                        x_p=prev[0], iterations=i - 1, residuals=residuals,
-                        converged=True, wall_s=time.perf_counter() - t0)
+                    return result(prev[0], i - 1, True)
                 check(rs_host, prev[0], i - 1)
             prev = (state["x"], state["rs"])
         rs_host = float(jax.device_get(prev[1]))
         residuals.append((rs_host / b_rs) ** 0.5)
         if rs_host <= stop2:
-            return CGResult(x_p=prev[0], iterations=max_iters, residuals=residuals,
-                            converged=True, wall_s=time.perf_counter() - t0)
+            return result(prev[0], max_iters, True)
         check(rs_host, prev[0], max_iters)
         raise CGMaxItersError(max_iters, (rs_host / b_rs) ** 0.5, tol,
                               partial(max_iters))

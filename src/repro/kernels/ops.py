@@ -105,6 +105,7 @@ def su3_mult_planar_batched(
 def su3_stencil_planar(
     u_p: jax.Array,
     v_nbr: jax.Array,
+    u_bwd: jax.Array | None = None,
     *,
     tile: int = DEFAULT_TILE,
     interpret: bool | None = None,
@@ -114,12 +115,14 @@ def su3_stencil_planar(
     """Planar nearest-neighbor stencil entry: u_p (2, 36, S) links — or
     (2, 24, S) two-row compressed, reconstructed in-register —
     v_nbr (8, 2, 3, S) direction-major shifted neighbor vectors -> (2, 3, S).
+    ``u_bwd`` (2, 36, S), where given, is the backward hop's own links (the
+    staggered operator's); without it the hop adjoints ``u_p``.
     """
     if interpret is None:
         interpret = _use_interpret()
     return su3_stencil.su3_stencil_planar(
-        u_p, v_nbr, tile=tile, interpret=interpret, accum_dtype=accum_dtype,
-        compressed=compressed,
+        u_p, v_nbr, u_bwd, tile=tile, interpret=interpret,
+        accum_dtype=accum_dtype, compressed=compressed,
     )
 
 

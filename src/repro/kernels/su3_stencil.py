@@ -1,4 +1,5 @@
-"""Pallas nearest-neighbor SU(3) stencil kernel (staggered-Dslash style).
+"""Pallas nearest-neighbor SU(3) stencil kernel: the site-local-adjoint stencil
+and, with backward links, the staggered Dslash.
 
 The paper's lesson is that SU3_Bench's ceiling is set by how data moves; the
 stencil is the first workload in this repo where data moves *between shards*.
@@ -8,13 +9,20 @@ Per site ``x`` the kernel applies the 8-point nearest-neighbor operator
 
 over the 4 lattice directions (mu = x, y, z, t), where ``U`` is the site's
 gauge-link field (the same planar (2, 36, S) array the multiply kernels
-stream) and ``v`` is a color 3-vector field in planar (2, 3, S) form.  This
-is the staggered-Dslash access pattern of arXiv:1411.2087 with one
-simplification: the backward term uses the *site-local* adjoint link
-``U_mu(x)^dagger`` rather than the neighbor's ``U_mu(x - mu_hat)^dagger``,
-which keeps gauge-field traffic at ONE streamed read of U per application
-(the neighbor-gather cost all lands on the small vector field — exactly the
-halo traffic ``distributed.sharding.HaloSpec`` prices).
+stream) and ``v`` is a color 3-vector field in planar (2, 3, S) form.  With
+only ``u`` this is the *site-local-adjoint* stencil: the backward term uses
+the site's own adjoint link ``U_mu(x)^dagger`` rather than the neighbor's
+``U_mu(x - mu_hat)^dagger``, which keeps gauge-field traffic at ONE streamed
+read of U per application (the neighbor-gather cost all lands on the small
+vector field — exactly the halo traffic ``distributed.sharding.HaloSpec``
+prices).
+
+With a second link operand ``u_bwd`` the backward term reads
+``u_bwd_mu(x)^dagger`` instead: the staggered (Kogut-Susskind) Dslash of
+arXiv:1411.2087, whose links the plan prepares once per field with the
+phases, the antiperiodic t boundary and the backward hop's minus sign folded
+in (``u_bwd_mu(x) = -eta_mu(x) U_mu(x - mu_hat)``), so the FMA chain is the
+same and nothing branches on a sign per hop.
 
 Kernel formulation (same philosophy as ``su3_matmul``):
 
@@ -34,6 +42,7 @@ Layout contract:
   u:     (2, 36, S)    — planar gauge links, [re|im, link*row*col, site]
   v_nbr: (8, 2, 3, S)  — planar neighbor vectors, direction-major
                          (+x, +y, +z, +t, -x, -y, -z, -t)
+  u_bwd: (2, 36, S)    — optional backward-hop links, planar like ``u``
   -> out: (2, 3, S)    — planar result vector field
 
 The per-site accumulation order is FIXED (mu-major, then l, forward before
@@ -74,14 +83,19 @@ def _flat(j: int, k: int, l: int) -> int:
     return (j * SU3 + k) * SU3 + l
 
 
-def _stencil_tile(u: jax.Array, v_nbr: jax.Array) -> jax.Array:
-    """out-tile = sum_mu U_mu . v_fwd[mu] + U_mu^dag . v_bwd[mu], unrolled.
+def _stencil_tile(
+    u: jax.Array, v_nbr: jax.Array, u_bwd: jax.Array | None = None
+) -> jax.Array:
+    """out-tile = sum_mu U_mu . v_fwd[mu] + B_mu^dag . v_bwd[mu], unrolled,
+    with B = ``u_bwd`` where given and B = U (site-local adjoint) otherwise.
 
-    u: (2, 36, T) planar link tile, v_nbr: (8, 2, 3, T) neighbor tiles.
-    Accumulation order is fixed (mu outer, l inner, forward then backward
-    per (mu, k, l)) — the bit-identity contract of the module docstring.
+    u, u_bwd: (2, 36, T) planar link tiles, v_nbr: (8, 2, 3, T) neighbor
+    tiles.  Accumulation order is fixed (mu outer, l inner, forward then
+    backward per (mu, k, l)) — the bit-identity contract of the module
+    docstring.
     """
     ur, ui = u[0], u[1]
+    br, bi = (ur, ui) if u_bwd is None else (u_bwd[0], u_bwd[1])
     out_r: list = [None] * SU3
     out_i: list = [None] * SU3
     for mu in range(LINKS):
@@ -91,15 +105,15 @@ def _stencil_tile(u: jax.Array, v_nbr: jax.Array) -> jax.Array:
             acc_r, acc_i = out_r[k], out_i[k]
             for l in range(SU3):
                 f = _flat(mu, k, l)  # U[mu, k, l]
-                b = _flat(mu, l, k)  # U[mu, l, k], conjugated for the adjoint
+                b = _flat(mu, l, k)  # B[mu, l, k], conjugated for the adjoint
                 # forward: U[mu,k,l] * v(x+mu)[l]
                 tr = ur[f] * vf_r[l] - ui[f] * vf_i[l]
                 ti = ur[f] * vf_i[l] + ui[f] * vf_r[l]
                 acc_r = tr if acc_r is None else acc_r + tr
                 acc_i = ti if acc_i is None else acc_i + ti
-                # backward: conj(U[mu,l,k]) * v(x-mu)[l]
-                sr = ur[b] * vb_r[l] + ui[b] * vb_i[l]
-                si = ur[b] * vb_i[l] - ui[b] * vb_r[l]
+                # backward: conj(B[mu,l,k]) * v(x-mu)[l]
+                sr = br[b] * vb_r[l] + bi[b] * vb_i[l]
+                si = br[b] * vb_i[l] - bi[b] * vb_r[l]
                 acc_r = acc_r + sr
                 acc_i = acc_i + si
             out_r[k], out_i[k] = acc_r, acc_i
@@ -109,9 +123,12 @@ def _stencil_tile(u: jax.Array, v_nbr: jax.Array) -> jax.Array:
 
 
 def _su3_stencil_kernel(
-    u_ref, v_ref, o_ref, *, accum_dtype: str | None = None, compressed: bool = False
+    u_ref, v_ref, *refs, accum_dtype: str | None = None, compressed: bool = False
 ):
     """One grid step: the unrolled 8-direction FMA chain on resident tiles.
+
+    ``refs`` is ``(o_ref,)``, or ``(ub_ref, o_ref)`` when the backward hop
+    streams its own (2, 36, tile) link block.
 
     ``accum_dtype`` widens the VREG working precision exactly as in the
     multiply kernel: tiles upcast once on VMEM load, the chain accumulates
@@ -120,14 +137,18 @@ def _su3_stencil_kernel(
     multiply, the stencil genuinely needs row 2 (the adjoint term reads link
     COLUMNS), so the reconstruct-on-load cross product is load-bearing here.
     """
+    o_ref = refs[-1]
     u = u_ref[...]  # (2, 36 | 24, tile) in VMEM
     v = v_ref[...]  # (8, 2, 3, tile) in VMEM
+    ub = refs[0][...] if len(refs) == 2 else None
     if accum_dtype is not None:
         u = u.astype(accum_dtype)
         v = v.astype(accum_dtype)
+        if ub is not None:
+            ub = ub.astype(accum_dtype)
     if compressed:
         u = _expand_tile(u)  # f32 cross product, shared with su3_matmul
-    o_ref[...] = _stencil_tile(u, v).astype(o_ref.dtype)
+    o_ref[...] = _stencil_tile(u, v, ub).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -136,6 +157,7 @@ def _su3_stencil_kernel(
 def su3_stencil_planar(
     u: jax.Array,
     v_nbr: jax.Array,
+    u_bwd: jax.Array | None = None,
     *,
     tile: int = 512,
     interpret: bool = False,
@@ -148,27 +170,33 @@ def su3_stencil_planar(
     walks site tiles; per step one (2, 36, tile) link block — (2, 24, tile)
     for two-row ``compressed`` gauge, reconstructed in-register — and one
     (8, 2, 3, tile) neighbor block stream HBM->VMEM and the fully unrolled
-    complex FMA chain produces the (2, 3, tile) out block.
+    complex FMA chain produces the (2, 3, tile) out block.  With ``u_bwd``
+    a second (2, 36, tile) link block streams per step and the backward hop
+    reads it (full rows only: phase-folded links are not SU(3), so row 2
+    cannot be rebuilt from rows 0 and 1).
     """
     rows = COMP_ROWS if compressed else ROWS
     assert u.ndim == 3 and u.shape[:2] == (2, rows), (u.shape, compressed)
     n_sites = u.shape[2]
     assert v_nbr.shape == (NBR_DIRS, 2, SU3, n_sites), (v_nbr.shape, n_sites)
     assert n_sites % tile == 0, (n_sites, tile)
-    grid = (n_sites // tile,)
+    link_spec = pl.BlockSpec((2, rows, tile), lambda i: (0, 0, i))
+    in_specs = [link_spec, pl.BlockSpec((NBR_DIRS, 2, SU3, tile), lambda i: (0, 0, 0, i))]
+    operands = (u, v_nbr)
+    if u_bwd is not None:
+        assert not compressed and u_bwd.shape == u.shape, (u_bwd.shape, u.shape)
+        in_specs.append(link_spec)
+        operands += (u_bwd,)
     return pl.pallas_call(
         functools.partial(
             _su3_stencil_kernel, accum_dtype=accum_dtype, compressed=compressed
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2, rows, tile), lambda i: (0, 0, i)),
-            pl.BlockSpec((NBR_DIRS, 2, SU3, tile), lambda i: (0, 0, 0, i)),
-        ],
+        grid=(n_sites // tile,),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((2, SU3, tile), lambda i: (0, 0, i)),
         out_shape=jax.ShapeDtypeStruct((2, SU3, n_sites), u.dtype),
         interpret=interpret,
-    )(u, v_nbr)
+    )(*operands)
 
 
 # -- fused CG iteration kernel (stencil + search-direction axpy) --------------

@@ -65,9 +65,12 @@ class ServeRequest:
     operator, with ``a`` the canonical gauge lattice and ``b`` the canonical
     color-vector field (n_sites, 3); ``k`` is always 1 (the stencil is not
     chained — its output is a vector field, not a lattice).
-    ``kind="solve"``: a staggered CG solve ``(sigma I + S) x = b`` with
-    ``a`` the canonical gauge lattice and ``b`` the canonical right-hand
-    side (n_sites, 3); ``tol``/``max_iters`` bound the solver and the
+    ``kind="solve"``: a CG solve with ``a`` the canonical gauge lattice:
+    with ``mass`` None on the site-local-adjoint operator
+    ``(sigma I + S) x = b``, ``b`` the canonical right-hand side
+    (n_sites, 3); with a ``mass`` on the even/odd staggered operator
+    ``(4 m^2 - D_eo D_oe) x_e = b_e``, ``b`` an even-site vector
+    (n_sites // 2, 3).  ``tol``/``max_iters`` bound the solver and the
     request's iteration count is DATA-DEPENDENT — the service advances it a
     few CG iterations per scheduling turn and it retires mid-chain the turn
     its residual crosses tol.
@@ -76,6 +79,7 @@ class ServeRequest:
     req_id: int
     a: Any  # canonical complex (n_sites, 4, 3, 3)
     b: Any  # canonical complex (4, 3, 3) | (n_sites, 3) for stencil/solve
+    # | (n_sites // 2, 3) for a staggered solve
     L: int
     k: int
     arrival_s: float = 0.0  # perf_counter timestamp at admission
@@ -84,6 +88,8 @@ class ServeRequest:
     # (0.0 until seated; the request-lifecycle span derives queue_wait from it)
     tol: float = 0.0  # solve: relative-residual convergence target
     max_iters: int = 0  # solve: iteration cap (retires unconverged at cap)
+    mass: float | None = None  # solve: the staggered operator's valence mass
+    # (None = the site-local-adjoint operator)
     deadline_s: float = 0.0  # absolute perf_counter deadline (0 = none); a
     # request past it is EVICTED (queue slot and live chain/table seat freed)
     # and completes with a structured DeadlineExceededError

@@ -33,8 +33,10 @@ scheduling turn and return canonical vector fields; they never join multiply
 chains in any dispatch mode.
 
 Solve requests (``submit_solve``) are the flagship iterative workload: a
-staggered CG solve ``(sigma I + S) x = b`` through the plan's fused
-stencil+axpy iteration (``ExecutionPlan.cg_state_init`` / ``cg_iterate``).
+CG solve through ``ExecutionPlan.cg_state_init`` / ``cg_iterate`` on one of
+two operators — the site-local-adjoint ``(sigma I + S) x = b`` (fused
+stencil+axpy iteration), or, for a request with a ``mass``, MILC's even/odd
+staggered ``(4 m^2 - D_eo D_oe) x_e = b_e`` on the field the seat prepares.
 Each host runs ONE active solve at a time, advanced
 ``solve_iters_per_step`` CG iterations per scheduling turn — its iteration
 count is data-dependent, so it retires *mid-chain* the turn its residual
@@ -89,6 +91,8 @@ from repro.core import autotune
 from repro.core.su3.layouts import Layout
 from repro.core.su3.plan import (
     CG_DIVERGENCE_FACTOR,
+    KS_OPERATOR,
+    SITE_LOCAL_OPERATOR,
     BatchedLatticeRunner,
     CGDivergedError,
     EngineConfig,
@@ -147,6 +151,12 @@ _REQUEST_LANES = 32
 
 def _request_lane(req_id: int) -> int:
     return _REQUEST_LANE_BASE + req_id % _REQUEST_LANES
+
+
+def _solve_operator(req: ServeRequest) -> str:
+    """The CG operator a solve request asks for (``plan.KS_OPERATOR`` when it
+    carries a mass)."""
+    return SITE_LOCAL_OPERATOR if req.mass is None else KS_OPERATOR
 
 
 @dataclasses.dataclass(frozen=True)
@@ -921,20 +931,30 @@ class SU3Service:
                      max_iters: int = 200,
                      deadline_s: float | None = None,
                      tenant: str = DEFAULT_TENANT,
-                     slo: str | None = None) -> int | None:
-        """Queue one staggered CG solve ``(sigma I + S) x = b`` on its home
-        host.
+                     slo: str | None = None,
+                     mass: float | None = None) -> int | None:
+        """Queue one CG solve on its home host, on one of two operators:
+
+        * ``mass=None``: the site-local-adjoint operator
+          ``(sigma I + S) x = b`` (sigma = ``plan.CG_SHIFT``; S Hermitian
+          only on fields constant along each link's own direction);
+        * ``mass=m``: MILC's even/odd staggered operator
+          ``(4 m^2 - D_eo D_oe) x_e = b_e`` (phases, antiperiodic t, the
+          neighbour's backward link), on the field the seat prepares.
 
         Args:
             u: canonical complex gauge lattice ``(L**4, 4, 3, 3)``.
-            b: canonical complex right-hand side ``(L**4, 3)``.
+            b: canonical complex right-hand side ``(L**4, 3)``, or the
+                even-site ``(L**4 // 2, 3)`` (``layouts.parity_sites``
+                order) with a ``mass``.
             tol: relative-residual target ``||r|| <= tol ||b||``.
             max_iters: iteration cap; the request retires (best iterate
                 delivered) rather than spinning past it.
+            mass: the staggered operator's valence mass (> 0).
 
         Returns:
-            A request id (result: the canonical ``(L**4, 3)`` solution
-            field), or None under backpressure — same contract as
+            A request id (result: the canonical solution, shaped like
+            ``b``), or None under backpressure — same contract as
             :meth:`submit`.  The solve rides the SAME warm pool, locality
             router, and per-host batcher; its iteration count is
             data-dependent, so it advances ``solve_iters_per_step`` CG
@@ -942,11 +962,16 @@ class SU3Service:
             kernel and retires mid-chain when the residual crosses tol.
         """
         L = self._infer_L(u)
-        if b.shape != (L**4, 3):
+        sites = L**4 if mass is None else L**4 // 2
+        if b.shape != (sites, 3):
             raise ValueError(
-                f"solve right-hand side must be (L**4, 3) canonical complex "
-                f"matching the lattice, got {b.shape} for L={L}"
+                f"solve right-hand side must be ({sites}, 3) canonical complex "
+                f"matching the lattice (its even sites with a mass), got "
+                f"{b.shape} for L={L}"
             )
+        if mass is not None and not (mass > 0 and L % 2 == 0):
+            raise ValueError(f"a staggered solve needs mass > 0 and an even L, "
+                             f"got mass={mass}, L={L}")
         if tol < 0:
             raise ValueError(f"tol must be >= 0, got {tol}")
         if max_iters < 1:
@@ -961,7 +986,7 @@ class SU3Service:
         req = ServeRequest(
             req_id=self._next_id, a=u, b=b, L=L, k=1,
             arrival_s=arrival, kind="solve",
-            tol=tol, max_iters=max_iters,
+            tol=tol, max_iters=max_iters, mass=mass,
             deadline_s=self._deadline(deadline_s, arrival, slo),
             priority=PRIORITY["solve"], tenant=tenant, slo=slo,
         )
@@ -1104,7 +1129,7 @@ class SU3Service:
             req = active["req"]
             if req.deadline_s and req.deadline_s <= now:
                 # best iterate so far rides out as the timeout's partial
-                partial = active["plan"].unpack_vec(active["state"]["x"])
+                partial = active["unpack"](active["state"]["x"])
                 del self._solves[host]
                 self._timeout(req, now, partial=partial)
         for chain, arrays in self._chains.values():
@@ -1595,13 +1620,24 @@ class SU3Service:
                     runner = cand
                     break
         plan = runner.plan
-        u_phys = plan.pack_gauge(req.a)
-        b_p = plan.pack_rhs(req.b)
-        state = plan.cg_state_init(b_p)
-        b_rs = float(jax.device_get(state["rs"]))  # r_0 = b, so rs_0 = ||b||^2
+        tr = self.tracer
+        with (tr.span("serve.seat", lane=host, req_id=req.req_id,
+                      operator=_solve_operator(req)) if tr.enabled
+              else NULL_TRACER.span("serve.seat")):
+            if req.mass is None:
+                u_phys, b_p = plan.pack_gauge(req.a), plan.pack_rhs(req.b)
+                unpack = plan.unpack_vec
+            else:
+                u_phys = plan.prepare_staggered(req.a, req.mass)
+                b_p, unpack = plan.pack_half(req.b), plan.unpack_half
+            state = plan.cg_state_init(b_p)
+            if tr.enabled:
+                jax.block_until_ready(u_phys)
+            b_rs = float(jax.device_get(state["rs"]))  # r_0 = b: rs_0 = ||b||^2
         active = {
             "req": req, "plan": plan, "runner": runner, "u_phys": u_phys,
-            "state": state, "b_rs": b_rs, "stop2": req.tol * req.tol * b_rs,
+            "unpack": unpack, "state": state, "b_rs": b_rs,
+            "stop2": req.tol * req.tol * b_rs,
             "best": None,  # (rs_host, x) — carried on structured failures
         }
         self._solves[host] = active
@@ -1648,9 +1684,12 @@ class SU3Service:
             n = max(1, n // self.cfg.brownout.degrade_solve_factor)
             self.metrics.record_degraded_solve_turn()
         runner = active["runner"]
-        shape_key = ("solve", req.L)
+        operator = _solve_operator(req)
+        shape_key = ("solve", req.L, operator, req.mass)
         cold = shape_key not in self._seen_shapes
         tr = self.tracer
+        # the staggered iteration's two half-lattice passes cost one stencil
+        # pass over the lattice: the same nominal flops per site
         flops = float(CG_ITER_FLOPS_PER_SITE) * req.n_sites * n
         with self._dispatch_span(runner, host, "solve", req.L, n, "solve",
                                  live=1, padded=1, flops=flops, cold=cold):
@@ -1658,11 +1697,13 @@ class SU3Service:
             for _ in range(n):
                 if tr.enabled:
                     with tr.span("cg.iter", lane=host, req_id=req.req_id,
-                                 it=state["iterations"] + 1):
+                                 it=state["iterations"] + 1, operator=operator):
                         state = plan.cg_iterate(active["u_phys"], state)
                         jax.block_until_ready(state["rs"])
                 else:
                     state = plan.cg_iterate(active["u_phys"], state)
+            if tr.enabled:
+                tr.count("dslash_applies", n * (1 if req.mass is None else 2))
             if self.faults.enabled:
                 # "kernel" seam for solves: poison the chunk's residual
                 # scalar — the corrupted-iterate case the residual guard
@@ -1701,7 +1742,7 @@ class SU3Service:
                 # canonical best iterate rides along for the caller (same
                 # shape the request's normal result would have had)
                 terminal.partial = (
-                    None if best is None else plan.unpack_vec(best[1]))
+                    None if best is None else active["unpack"](best[1]))
                 self._retry_or_fail(req, f"cg {reason}", terminal=terminal)
                 if quarantined:
                     self._quarantine(host)
@@ -1723,8 +1764,8 @@ class SU3Service:
     def _retire_solve(self, host: int, active: dict[str, Any],
                       state: dict[str, Any]) -> int:
         """Deliver the active solve's iterate and free its seat."""
-        req, plan = active["req"], active["plan"]
-        self._results[req.req_id] = plan.unpack_vec(state["x"])
+        req = active["req"]
+        self._results[req.req_id] = active["unpack"](state["x"])
         del self._solves[host]
         done_s = time.perf_counter()
         self.metrics.record_completion(

@@ -516,3 +516,73 @@ def test_turns_with_nothing_to_dispatch_record_no_span():
     assert tracer.spans() == []
     _served_batch(svc)
     assert [s.name for s in tracer.spans()].count("serve.step") == 1
+
+
+# -- the staggered operator's spans and counters ------------------------------
+
+
+def _hot_field(L=2, seed=7):
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    z = rng.normal(size=(L**4 * 4, 3, 3)) + 1j * rng.normal(size=(L**4 * 4, 3, 3))
+    q, _r = np.linalg.qr(z)
+    u = q.reshape(L**4, 4, 3, 3).astype(np.complex64)
+    b = (rng.normal(size=(L**4 // 2, 3)) + 1j * rng.normal(size=(L**4 // 2, 3)))
+    return u, b.astype(np.complex64)
+
+
+def test_staggered_solve_records_prepare_iterations_and_applies():
+    from repro.core.su3.plan import EngineConfig, build_plan
+
+    plan = build_plan(EngineConfig(L=2, tile=16))
+    plan.tracer = tracer = Tracer()
+    u, b = _hot_field()
+    field = plan.prepare_staggered(u, 0.635)
+    res = plan.cg_solve(field, plan.pack_half(b), tol=1e-6, max_iters=100)
+    (prep,) = [s for s in tracer.spans() if s.name == "ks.prepare"]
+    assert prep.attrs == {"L": 2, "mass": 0.635, "bytes": field.nbytes}
+    assert field.nbytes == 4 * 2 * 36 * 16 * 4 + 2 * 8 * 16 * 4
+    iters = [s for s in tracer.spans() if s.name == "cg.iter"]
+    assert iters and all(s.attrs["operator"] == "ks_eo" for s in iters)
+    # every dispatched iteration, the lagged one included, two passes each
+    assert len(iters) == res.iterations + 1
+    assert tracer.counters["dslash_applies"] == res.dslash_applies == 2 * len(iters)
+
+
+def test_site_local_solve_counts_one_apply_an_iteration():
+    import numpy as np
+    from repro.core.su3.plan import EngineConfig, build_plan
+
+    plan = build_plan(EngineConfig(L=2, tile=16))
+    plan.tracer = tracer = Tracer()
+    u = np.broadcast_to(np.eye(3, dtype=np.complex64), (16, 4, 3, 3))
+    b = np.ones((16, 3), np.complex64)
+    res = plan.cg_solve(plan.pack_gauge(u), plan.pack_rhs(b), tol=1e-6)
+    iters = [s for s in tracer.spans() if s.name == "cg.iter"]
+    assert all(s.attrs["operator"] == "site_local" for s in iters)
+    assert tracer.counters["dslash_applies"] == res.dslash_applies == len(iters)
+
+
+@pytest.mark.parametrize("mass", [None, 0.635])
+def test_served_solve_is_seated_under_a_live_span(mass):
+    import numpy as np
+
+    tracer = Tracer()
+    svc = _small_service(tracer)
+    u, b = _hot_field()
+    if mass is None:  # the site-local operator, on a field it is Hermitian on
+        u = np.broadcast_to(np.eye(3, dtype=np.complex64), (16, 4, 3, 3))
+        b = np.ones((16, 3), np.complex64)
+    rid = svc.submit_solve(u, b, tol=1e-6, max_iters=100, mass=mass)
+    svc.run_until_drained()
+    assert np.asarray(svc.pop_result(rid)).shape == b.shape
+    spans = tracer.spans()
+    operator = "site_local" if mass is None else "ks_eo"
+    (seat,) = [s for s in spans if s.name == "serve.seat"]
+    assert seat.attrs == {"req_id": rid, "operator": operator}
+    first = min(s.t0_s for s in spans if s.name == "cg.iter")
+    assert seat.t1_s <= first
+    assert all(s.attrs["operator"] == operator for s in spans if s.name == "cg.iter")
+    iters = sum(s.name == "cg.iter" for s in spans)
+    assert tracer.counters["dslash_applies"] == iters * (1 if mass is None else 2)

@@ -141,6 +141,35 @@ def test_stencil_and_fused_cg_compile(one_chip):
     assert _kernels(cg) == 1
 
 
+def test_staggered_prepare_and_apply_compile(one_chip):
+    """The even/odd staggered operator at L=32: the field preparation (rolls
+    and a parity split along the site axis, no gather), one half-lattice
+    Dslash (one kernel streaming forward and backward links) and the CG
+    apply (two of them), within the chip's 16 GB.  Read with jax 0.9.0 and
+    libtpu 0.0.34: temp 1,228,349,952 B for the preparation (604 MB out),
+    605,270,016 for a Dslash, 621,821,440 for the apply."""
+    from repro.core.su3.plan import StaggeredField
+
+    plan = build_plan(EngineConfig(L=L, tile=TILE), one_chip)
+    parts, half = plan._ks_parts(), plan.half_sites
+    assert half == L**4 // 2
+    prepare = parts["prepare"].lower(
+        _gauge(plan), _shape(parts["phases"].shape, jnp.float32, plan.replicated)
+    ).compile()
+    links = _shape((2, 36, half), jnp.float32, plan.vec_sharding)
+    nbr = _shape((8, half), jnp.int32, plan.replicated)
+    v = _shape((2, 3, half), jnp.float32, plan.vec_sharding)
+    dslash = parts["dslash"].lower(links, links, v, nbr).compile()
+    assert _kernels(dslash) == 1
+    field = StaggeredField(links, links, links, links, nbr, nbr, mass=0.635)
+    coefs = _shape((1, 2), jnp.float32, plan.replicated)
+    apply = jax.jit(plan._ks_apply()).lower(field, v, v, coefs).compile()
+    assert _kernels(apply) == 2
+    for compiled, most in ((prepare, 2.5e9), (dslash, 1.2e9), (apply, 1.2e9)):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes + mem.output_size_in_bytes < most
+
+
 def test_sharded_plan_compiles_on_four_chips(four_chips):
     """Mosaic refuses a pallas_call on a sharded operand outside a
     shard_map; the plan's multiply, overlapped stencil and fused CG must
