@@ -461,6 +461,84 @@ def gather_neighbors(v_p: jax.Array, idx: np.ndarray) -> jax.Array:
     return jax.lax.optimization_barrier(nbrs)
 
 
+# -- neighbours by rolls of the flat site axis ---------------------------------
+#
+# Every periodic neighbour is a fixed shift of the t-major site axis: +-mu is
+# a roll by L**mu, by (L - 1) L**mu the other way on the face where the
+# coordinate wraps.  Built from rolls and selects on the flat axis, so the
+# site axis stays the lane axis on a TPU, and the face masks come from an
+# iota inside the program: no per-site table or mask is an HLO constant.
+
+
+def _sites(n: int) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
+
+
+def _coord(s: jax.Array, stride: int, L: int) -> jax.Array:
+    """``s // stride % L`` for sites ``s >= 0``."""
+    return jax.lax.rem(jax.lax.div(s, stride), L)
+
+
+def _hop(a: jax.Array, s: jax.Array, L: int, stride: int, sign: int) -> jax.Array:
+    """``a`` at ``s + sign * stride`` on the site axis, periodic in the
+    coordinate ``s // stride % L``: a roll of the last axis, and on the face
+    where the coordinate wraps a roll by ``(L - 1) * stride`` the other way."""
+    face = _coord(s, stride, L) == (L - 1 if sign > 0 else 0)
+    return jnp.where(face, jnp.roll(a, sign * (L - 1) * stride, axis=-1),
+                     jnp.roll(a, -sign * stride, axis=-1))
+
+
+def _odd_rows(row: jax.Array, L: int) -> jax.Array:
+    """Whether the row of fixed ``(t, z, y)`` numbered ``row`` has
+    ``t + z + y`` odd: its sites of parity p start at x = 1 - p."""
+    tzy = _coord(row, 1, L) + _coord(row, L, L) + _coord(row, L**2, L)
+    return jax.lax.rem(tzy, 2) == 1
+
+
+def periodic_neighbors(v_p: jax.Array, L: int) -> jax.Array:
+    """Planar ``(2, 3, N)`` field, ``N >= L**4`` -> the ``(8, 2, 3, N)``
+    operand :func:`gather_neighbors` reads through the exact periodic table
+    of :func:`stencil_neighbor_tables`, value for value, built from rolls:
+    direction order (+x, +y, +z, +t, -x, -y, -z, -t), and padding sites
+    (``>= L**4``) reading themselves."""
+    n = v_p.shape[-1]
+    s = _sites(n)
+    hops = [_hop(v_p, s, L, L**mu, sign) for sign in (1, -1) for mu in range(4)]
+    nbrs = jnp.stack(hops)
+    if n > L**4:
+        nbrs = jnp.where(s >= L**4, v_p, nbrs)
+    return jax.lax.optimization_barrier(nbrs)
+
+
+def half_neighbors(v_p: jax.Array, L: int, parity: int) -> jax.Array:
+    """The other parity's planar half vector ``(2, 3, H)``, ``H >= L**4 // 2``
+    -> the ``(8, 2, 3, H)`` operand of the parity-``parity`` sites: what a
+    gather through ``staggered_neighbor_tables(L, H)[parity]`` reads, value
+    for value, built from rolls.
+
+    A site of either parity at half position h is site 2h or 2h + 1, so
+    +-y/z/t are rolls by ``L**mu // 2`` with the full lattice's face
+    correction; +-x stays put or moves one position, as the row's parity
+    says whether the site's x is odd, and wraps by ``L // 2 - 1`` at x = 0
+    and x = L - 1.  Padding sites read themselves."""
+    n, half = v_p.shape[-1], L // 2
+    h = _sites(n)
+    xh = jax.lax.rem(h, half)
+    x_odd = _odd_rows(jax.lax.div(h, half), L) != (parity == 1)
+    plus_x = jnp.where(x_odd, jnp.where(xh == half - 1,
+                                        jnp.roll(v_p, half - 1, axis=-1),
+                                        jnp.roll(v_p, -1, axis=-1)), v_p)
+    minus_x = jnp.where(x_odd, v_p, jnp.where(xh == 0,
+                                              jnp.roll(v_p, 1 - half, axis=-1),
+                                              jnp.roll(v_p, 1, axis=-1)))
+    plus = [plus_x] + [_hop(v_p, h, L, L**mu // 2, 1) for mu in range(1, 4)]
+    minus = [minus_x] + [_hop(v_p, h, L, L**mu // 2, -1) for mu in range(1, 4)]
+    nbrs = jnp.stack(plus + minus)
+    if n > L**4 // 2:
+        nbrs = jnp.where(h >= L**4 // 2, v_p, nbrs)
+    return jax.lax.optimization_barrier(nbrs)
+
+
 def init_stencil_canonical(n_sites: int) -> tuple[jax.Array, jax.Array]:
     """Canonical stencil benchmark data: U entries (1, 0), v entries (1/24, 0).
 
@@ -639,27 +717,26 @@ def split_parity(a: jax.Array, L: int) -> tuple[jax.Array, jax.Array]:
     starting at x = (t + z + y + parity) % 2: a shift by one site where the
     row starts odd, then every other site.  Site-last and gather-free, so
     the site axis stays the lane axis of every intermediate on a TPU."""
-    row = np.arange(L**4) // L
-    odd_row = (row // L**2 + row // L % L + row % L) % 2 == 1
+    odd_row = _odd_rows(jax.lax.div(_sites(L**4), L), L)
     shifted = jnp.roll(a, -1, axis=-1)
-    even = jnp.where(odd_row, shifted, a)[..., ::2]
-    odd = jnp.where(odd_row, a, shifted)[..., ::2]
-    return even, odd
+
+    def every_other(x: jax.Array) -> jax.Array:  # a strided slice, not a gather
+        return jax.lax.slice_in_dim(x, 0, x.shape[-1], stride=2, axis=x.ndim - 1)
+
+    return (every_other(jnp.where(odd_row, shifted, a)),
+            every_other(jnp.where(odd_row, a, shifted)))
 
 
 def roll_back(a: jax.Array, L: int, mu: int) -> jax.Array:
     """``(..., L**4)`` site-last array -> its value at x - mu at each site x
     (periodic): a roll of the flat site axis, corrected on the face where
     the coordinate wraps."""
-    stride = L**mu
-    return jnp.where(np.arange(L**4) // stride % L == 0,
-                     jnp.roll(a, -(L - 1) * stride, axis=-1),
-                     jnp.roll(a, stride, axis=-1))
+    return _hop(a, _sites(L**4), L, L**mu, -1)
 
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["fwd_e", "bwd_e", "fwd_o", "bwd_o", "nbr_e", "nbr_o"],
+    data_fields=["fwd_e", "bwd_e", "fwd_o", "bwd_o"],
     meta_fields=["mass"])
 @dataclasses.dataclass(frozen=True)
 class StaggeredField:
@@ -668,9 +745,8 @@ class StaggeredField:
 
     Per parity, planar ``(2, 36, half)`` links in the plan's word dtype:
     ``fwd`` = eta_mu(x) U_mu(x), ``bwd`` = -eta_mu(x) U_mu(x - mu), both with
-    the antiperiodic t sign; and ``nbr (8, half)``, where each site's
-    neighbours sit in the other parity's vector
-    (:func:`staggered_neighbor_tables`).
+    the antiperiodic t sign.  A site's neighbours sit in the other
+    parity's vector (:func:`half_neighbors`).
     Passed to ``cg_solve`` / ``cg_iterate`` in place of a physical gauge
     lattice, it selects the operator ``4 m^2 - D_eo D_oe`` on even-site
     vectors (``pack_half``).
@@ -680,8 +756,6 @@ class StaggeredField:
     bwd_e: jax.Array
     fwd_o: jax.Array
     bwd_o: jax.Array
-    nbr_e: jax.Array
-    nbr_o: jax.Array
     mass: float
 
     @property
@@ -1051,6 +1125,25 @@ class ExecutionPlan:
             )
         return self._stencil_tables
 
+    def _neighbor_access(self, overlap: bool) -> str:
+        """How a whole-lattice stencil or CG pass with ``overlap`` forms its
+        neighbour operand: ``"roll"`` where it reads the exact periodic
+        neighbours (no overlap, or one slab: no boundary sites, so the
+        slab-local wrap is the periodic one), ``"gather"`` through the
+        slab-local table otherwise.  The one decision
+        :meth:`_neighbor_operand` acts on and the spans report."""
+        n_boundary = self._stencil_geometry()[2].size
+        return "gather" if (overlap and n_boundary) else "roll"
+
+    def _neighbor_operand(self, v_p: jax.Array, overlap: bool) -> jax.Array:
+        """The ``(8, 2, 3, N)`` neighbour operand of a whole-lattice pass:
+        rolls of the site axis (:func:`periodic_neighbors`) or a gather
+        through the slab-local table (:func:`gather_neighbors`), as
+        :meth:`_neighbor_access` says."""
+        if self._neighbor_access(overlap) == "roll":
+            return periodic_neighbors(v_p, self.cfg.L)
+        return gather_neighbors(v_p, self._stencil_geometry()[1])
+
     def _stencil_kernel(
         self, variant: str = STENCIL_VARIANT, sharded: bool = True,
         backward_links: bool = False,
@@ -1108,25 +1201,25 @@ class ExecutionPlan:
     ) -> Callable[[jax.Array, jax.Array], jax.Array]:
         """Unjitted reference stencil ``(u_phys, v_p) -> out_p``.
 
-        Gathers all 8 neighbor fields through the exact periodic table and
-        runs ONE kernel pass over every site — the bit-identity oracle the
-        overlapped schedule is pinned against.  ``sharded=False`` is the
+        Forms all 8 neighbor fields of the exact periodic table (rolls,
+        :func:`periodic_neighbors`) and runs ONE kernel pass over every
+        site — the bit-identity oracle the overlapped schedule is pinned
+        against.  ``sharded=False`` is the
         per-lattice form the serving layer vmaps over request batches.
         """
         kernel = self._stencil_kernel(sharded=sharded)
-        glob, _local, _bidx = self._stencil_geometry()
         codec = self.codec
 
         def reference(u_phys: jax.Array, v_p: jax.Array) -> jax.Array:
             u_p = codec.planar_view(u_phys)
-            v_nbr = gather_neighbors(v_p, glob)  # (8, 2, 3, S)
+            v_nbr = self._neighbor_operand(v_p, overlap=False)  # (8, 2, 3, S)
             return kernel(u_p, v_nbr)
 
         return reference
 
     def stencil_reference_step(self) -> Callable[[jax.Array, jax.Array], jax.Array]:
         """Jitted non-overlapped reference stencil — ONE dispatch whose +-t
-        neighbor gathers carry the halo traffic inline (compute waits for
+        neighbor rolls carry the halo traffic inline (compute waits for
         the exchange; the baseline the overlap schedule is measured against
         and pinned bit-identical to)."""
         return self.stencil_step(overlap=False)
@@ -1142,8 +1235,8 @@ class ExecutionPlan:
         ``depth`` is the number of stencil applications the returned callable
         performs (``step(u, v)`` with depth=2 equals two depth-1 steps).
 
-        overlap=False (the pinned reference): one jitted dispatch; neighbor
-        gathers through the exact periodic table, kernel over all sites.
+        overlap=False (the pinned reference): one jitted dispatch; the exact
+        periodic neighbors by rolls of the site axis, kernel over all sites.
 
         overlap=True (default on multi-host meshes): the interior/boundary
         split schedule —
@@ -1198,13 +1291,13 @@ class ExecutionPlan:
         if self._stencil_parts is not None:
             return self._stencil_parts
         kernel = self._stencil_kernel()
-        glob, local, bidx = self._stencil_geometry()
+        glob, _local, bidx = self._stencil_geometry()
         codec = self.codec
         out_sh = self.vec_sharding
 
         def interior_fn(u_phys: jax.Array, v_p: jax.Array) -> jax.Array:
-            # slab-local gathers only: independent of the in-flight exchange
-            v_nbr = gather_neighbors(v_p, local)  # (8, 2, 3, S)
+            # slab-local neighbours only: independent of the in-flight exchange
+            v_nbr = self._neighbor_operand(v_p, overlap=True)  # (8, 2, 3, S)
             return kernel(codec.planar_view(u_phys), v_nbr)
 
         parts: dict[str, Any] = {
@@ -1258,6 +1351,7 @@ class ExecutionPlan:
             "L": cfg.L, "tile": cfg.tile, "dtype": cfg.dtype,
             "compression": cfg.compression, "hosts": self.n_hosts,
             "overlap": bool(overlap), "depth": depth,
+            "neighbours": self._neighbor_access(overlap),
             "flops": float(STENCIL_FLOPS_PER_SITE) * cfg.shape.n_sites * depth,
         }
 
@@ -1526,15 +1620,15 @@ class ExecutionPlan:
         return -(-(self.cfg.shape.n_sites // 2) // chunk) * chunk
 
     def _ks_parts(self) -> dict[str, Any]:
-        """The staggered operator's host tables
-        (:func:`staggered_neighbor_tables`, :func:`staggered_phases`) and
+        """The staggered operator's phases (:func:`staggered_phases`) and
         jitted pieces, built once per plan.
 
         ``prepare`` folds the phases into the links, rolls each direction's
         links one site on for the backward hop (:func:`roll_back`) and
         splits both by parity (:func:`split_parity`); ``dslash`` is one
-        half-lattice pass ``D_{p,1-p} v``: the gather of the other parity's
-        vector through ``nbr`` and one stencil kernel with backward links;
+        half-lattice pass ``D_{p,1-p} v``: the other parity's vector rolled
+        to each neighbour (:func:`half_neighbors`) and one stencil kernel
+        with backward links;
         ``shift`` the normal operator's epilogue ``sigma p - s``.
         """
         if self._ks is not None:
@@ -1558,16 +1652,16 @@ class ExecutionPlan:
             pad = ((0, 0), (0, 0), (0, H - S // 2))  # zero links: zero outputs
             return tuple(jnp.pad(a, pad) for a in (fwd_e, bwd_e, fwd_o, bwd_o))
 
-        def dslash(fwd, bwd, v, nbr):
-            return kernel(fwd, gather_neighbors(v, nbr), bwd)
+        def dslash(fwd, bwd, v, parity):
+            return kernel(fwd, half_neighbors(v, L, parity), bwd)
 
         def shift(p, sigma, s):
             return (sigma.astype(f32) * p.astype(f32) - s.astype(f32)).astype(p.dtype)
 
         self._ks = dict(
-            nbr=staggered_neighbor_tables(L, H), phases=staggered_phases(L),
+            phases=staggered_phases(L),
             prepare=jax.jit(prepare, out_shardings=(vec_sh,) * 4),
-            dslash=jax.jit(dslash, out_shardings=vec_sh),
+            dslash=jax.jit(dslash, out_shardings=vec_sh, static_argnames="parity"),
             shift=jax.jit(shift, out_shardings=vec_sh),
         )
         return self._ks
@@ -1578,7 +1672,7 @@ class ExecutionPlan:
         mass ``mass``: packed (on the host, as :meth:`pack_gauge`), then, on
         the device, each link times its phase, each direction's links rolled
         one site on for the backward hop, both split by parity.  Run once per
-        field; the field's solves then gather only vectors."""
+        field; the field's solves then move only vectors."""
         if not mass > 0:
             raise ValueError(f"the staggered mass must be > 0, got {mass}")
         parts = self._ks_parts()
@@ -1586,8 +1680,7 @@ class ExecutionPlan:
         with tr.span("ks.prepare", L=self.cfg.L, mass=float(mass)) as span:
             u_p = self.codec.planar_view(self.pack_gauge(u))
             links = parts["prepare"](u_p, parts["phases"])
-            nbr = (jax.device_put(t, self.replicated) for t in parts["nbr"])
-            field = StaggeredField(*links, *nbr, mass=float(mass))
+            field = StaggeredField(*links, mass=float(mass))
             if tr.enabled:
                 jax.block_until_ready(field)
                 span.set(bytes=field.nbytes)
@@ -1597,8 +1690,8 @@ class ExecutionPlan:
         """One half-lattice Dslash: ``D_eo v`` (``parity=0``, v odd) or
         ``D_oe v`` (``parity=1``, v even), planar ``(2, 3, half_sites)``."""
         if parity == 0:
-            return self._ks_parts()["dslash"](field.fwd_e, field.bwd_e, v_p, field.nbr_e)
-        return self._ks_parts()["dslash"](field.fwd_o, field.bwd_o, v_p, field.nbr_o)
+            return self._ks_parts()["dslash"](field.fwd_e, field.bwd_e, v_p, parity=0)
+        return self._ks_parts()["dslash"](field.fwd_o, field.bwd_o, v_p, parity=1)
 
     def _ks_apply(self) -> Callable[..., Any]:
         """The staggered CG apply ``(field, r_p, p_p, coefs) -> (p', ap)``:
@@ -1737,21 +1830,20 @@ class ExecutionPlan:
             return composed
 
         kernel = self._stencil_kernel(CG_VARIANT)
-        glob, local, bidx = self._stencil_geometry()
+        glob, _local, bidx = self._stencil_geometry()
         codec = self.codec
         vec_sh = self.vec_sharding
         n_boundary = int(bidx.size)
-        gather_idx = local if (overlap and n_boundary) else glob
 
         def whole_fn(u_phys, r_p, p_p, coefs):
-            r_nbr = gather_neighbors(r_p, gather_idx)  # (8, 2, 3, S)
-            p_nbr = gather_neighbors(p_p, gather_idx)
+            r_nbr = self._neighbor_operand(r_p, overlap)  # (8, 2, 3, S)
+            p_nbr = self._neighbor_operand(p_p, overlap)
             return kernel(codec.planar_view(u_phys), r_nbr, p_nbr, r_p, p_p, coefs)
 
         whole_j = jax.jit(whole_fn, out_shardings=(vec_sh, vec_sh))
 
         if not (overlap and n_boundary):
-            # single shard (or overlap off): the periodic/local gather is one
+            # single shard (or overlap off): the periodic neighbors and one
             # fused pass; nothing to exchange
             def fused_whole(u_phys, r_p, p_p, coefs):
                 tr = plan.tracer
@@ -1966,6 +2058,9 @@ class ExecutionPlan:
                                   reason="non-finite right-hand side")
         staggered = isinstance(u_phys, StaggeredField)
         operator = KS_OPERATOR if staggered else SITE_LOCAL_OPERATOR
+        # the staggered Dslash has one access: rolls of the half lattice
+        neighbours = "roll" if staggered else self._neighbor_access(
+            self.is_multi_host if overlap is None else overlap)
         per_iter = 2 if staggered else 1  # half-lattice passes vs one stencil
         stop2 = (tol * tol) * b_rs
         state = self.cg_state_init(b_p, x0_p, u_phys=u_phys, sigma=sigma,
@@ -2008,7 +2103,7 @@ class ExecutionPlan:
                 # traced: the iter span blocks so it measures the iteration —
                 # tracing synchronizes, as with the stencil schedule spans
                 with tr.span("cg.iter", it=i, fused=bool(fused) and not staggered,
-                             operator=operator):
+                             operator=operator, neighbours=neighbours):
                     state = self.cg_iterate(
                         u_phys, state, sigma=sigma, fused=fused, overlap=overlap)
                     jax.block_until_ready(state["rs"])

@@ -542,9 +542,10 @@ def test_staggered_solve_records_prepare_iterations_and_applies():
     res = plan.cg_solve(field, plan.pack_half(b), tol=1e-6, max_iters=100)
     (prep,) = [s for s in tracer.spans() if s.name == "ks.prepare"]
     assert prep.attrs == {"L": 2, "mass": 0.635, "bytes": field.nbytes}
-    assert field.nbytes == 4 * 2 * 36 * 16 * 4 + 2 * 8 * 16 * 4
+    assert field.nbytes == 4 * 2 * 36 * 16 * 4  # links only: no tables
     iters = [s for s in tracer.spans() if s.name == "cg.iter"]
     assert iters and all(s.attrs["operator"] == "ks_eo" for s in iters)
+    assert all(s.attrs["neighbours"] == "roll" for s in iters)
     # every dispatched iteration, the lagged one included, two passes each
     assert len(iters) == res.iterations + 1
     assert tracer.counters["dslash_applies"] == res.dslash_applies == 2 * len(iters)
@@ -561,6 +562,7 @@ def test_site_local_solve_counts_one_apply_an_iteration():
     res = plan.cg_solve(plan.pack_gauge(u), plan.pack_rhs(b), tol=1e-6)
     iters = [s for s in tracer.spans() if s.name == "cg.iter"]
     assert all(s.attrs["operator"] == "site_local" for s in iters)
+    assert all(s.attrs["neighbours"] == "roll" for s in iters)
     assert tracer.counters["dslash_applies"] == res.dslash_applies == len(iters)
 
 

@@ -8,7 +8,9 @@ flip, solves through ``cg_solve`` and ``SU3Service.submit_solve`` against
 ``staggered_eo_reference_solve``, the same solve on four devices, and the
 site-local-adjoint stencil and CG left bit for bit as they were.
 """
+import functools
 import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -22,8 +24,11 @@ from repro.core.su3.plan import (
     EngineConfig,
     StaggeredField,
     build_plan,
+    gather_neighbors,
+    half_neighbors,
     staggered_apply_reference,
     staggered_eo_reference_solve,
+    staggered_neighbor_tables,
     staggered_phases,
     verify_tolerance,
 )
@@ -250,6 +255,50 @@ def test_kernel_with_its_own_backward_links_equal_to_u_is_the_site_local_stencil
     a = ops.su3_stencil_planar(u, nbr, tile=128)
     b = ops.su3_stencil_planar(u, nbr, u, tile=128)
     assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("L, half", [(4, 128), (4, 192), (6, 648), (6, 704)])
+@pytest.mark.parametrize("parity", [0, 1])
+def test_rolled_half_lattice_neighbors_equal_the_table_gather(L, half, parity):
+    """The half-lattice operand built from rolls reads, for the sites of
+    each parity, the values the gather through
+    ``staggered_neighbor_tables`` reads: on L**4 // 2 sites and past it,
+    where padding sites read themselves."""
+    rng = np.random.default_rng(100 * L + half + parity)
+    v_p = jnp.asarray(rng.standard_normal((2, 3, half)).astype(np.float32))
+    table = staggered_neighbor_tables(L, half)[parity]
+    rolled = jax.jit(lambda v: half_neighbors(v, L, parity))(v_p)
+    gathered = jax.jit(lambda v: gather_neighbors(v, table))(v_p)
+    assert rolled.shape == (8, 2, 3, half)
+    np.testing.assert_array_equal(np.asarray(rolled), np.asarray(gathered))
+
+
+def _site_tables(hlo):
+    """The integer and boolean array constants of a compiled program: a
+    neighbour table or a per-site mask, had one been closed over."""
+    return re.findall(r"(?:s32|s64|u32|pred)\[\d[\d,]*\]\S* constant\(", hlo)
+
+
+def test_cg_apply_and_dslash_programs_hold_no_gather_and_no_table():
+    """The site-local fused CG apply and both half-lattice Dslash passes
+    form their neighbours from rolls: no gather op, and no ``s32[8,...]``
+    table or any per-site integer or boolean constant in the program."""
+    L = 4
+    u, v = _hot(L)
+    plan = _plan(L)
+    v_p = plan.pack_rhs(v)
+    coefs = jnp.zeros((1, 2), jnp.float32)
+    programs = [jax.jit(plan._cg_apply(fused=True, overlap=False)).lower(
+        plan.pack_gauge(u), v_p, v_p, coefs)]
+    field = plan.prepare_staggered(u, MASS)
+    v_half = plan.pack_half(v[: L**4 // 2])
+    programs += [jax.jit(functools.partial(plan.dslash_half, parity=p)).lower(field, v_half)
+                 for p in (0, 1)]
+    for lowered in programs:
+        hlo = lowered.compile().as_text()
+        assert " gather(" not in hlo
+        assert "s32[8," not in hlo
+        assert _site_tables(hlo) == []
 
 
 # sha256 of the site-local stencil's and CG's outputs on these fixtures,

@@ -154,6 +154,9 @@ for layout, dtype, accum in (
     p2 = build_plan(cfg, MeshSpec(hosts=2, devices_per_host=2))
     p4 = build_plan(cfg, MeshSpec(hosts=4, devices_per_host=1))  # slab == face
     assert p2.is_multi_host and p2.stencil_step() is p2.stencil_step(overlap=True)
+    # one slab rolls under either schedule; slabs gather their local wrap
+    assert p1._neighbor_access(True) == "roll" == p2._neighbor_access(False)
+    assert p2._neighbor_access(True) == "gather" == p4._neighbor_access(True)
     outs = []
     for p in (p1, p2, p4):
         u_phys = p.codec.pack(a)
@@ -202,6 +205,27 @@ def test_neighbor_tables_local_equals_global_on_interior():
     # padding sites self-neighbor
     glob_p, local_p, _ = stencil_neighbor_tables(2, 64, 1)
     np.testing.assert_array_equal(glob_p[:, 16:], np.tile(np.arange(16, 64), (8, 1)))
+
+
+@pytest.mark.parametrize("L, tile", [(4, 128), (6, 16), (6, 128)])
+def test_rolled_neighbors_equal_the_periodic_gather(L, tile):
+    """The plan's neighbour operand for the exact periodic table is built
+    from rolls, and reads the very values the gather through that table
+    reads, in all 8 directions; at L=6 with tile 128 the plan pads its
+    sites past L**4, and the padding sites read themselves."""
+    plan = build_plan(EngineConfig(L=L, tile=tile))
+    n = plan.padded_sites
+    assert (n > L**4) == (tile == 128 and L == 6)
+    glob = plan._stencil_geometry()[0]
+    # one slab: with or without the overlap schedule the neighbours roll
+    assert plan._neighbor_access(overlap=False) == "roll"
+    assert plan._neighbor_access(overlap=True) == "roll"
+    rng = np.random.default_rng(L * tile)
+    v_p = jnp.asarray(rng.standard_normal((2, 3, n)).astype(np.float32))
+    rolled = jax.jit(lambda v: plan._neighbor_operand(v, overlap=False))(v_p)
+    gathered = jax.jit(lambda v: su3_plan.gather_neighbors(v, glob))(v_p)
+    assert rolled.shape == (8, 2, 3, n)
+    np.testing.assert_array_equal(np.asarray(rolled), np.asarray(gathered))
 
 
 # -- HaloSpec edge cases (satellite) ------------------------------------------

@@ -144,10 +144,11 @@ def test_stencil_and_fused_cg_compile(one_chip):
 def test_staggered_prepare_and_apply_compile(one_chip):
     """The even/odd staggered operator at L=32: the field preparation (rolls
     and a parity split along the site axis, no gather), one half-lattice
-    Dslash (one kernel streaming forward and backward links) and the CG
-    apply (two of them), within the chip's 16 GB.  Read with jax 0.9.0 and
-    libtpu 0.0.34: temp 1,228,349,952 B for the preparation (604 MB out),
-    605,270,016 for a Dslash, 621,821,440 for the apply."""
+    Dslash (neighbours rolled, one kernel streaming forward and backward
+    links) and the CG apply (two of them), within the chip's 16 GB.  Read
+    with jax 0.9.0 and libtpu 0.0.34: temp 1,227,559,424 B for the
+    preparation (604 MB out), 605,173,248 for a Dslash, 624,595,968 for the
+    apply."""
     from repro.core.su3.plan import StaggeredField
 
     plan = build_plan(EngineConfig(L=L, tile=TILE), one_chip)
@@ -157,11 +158,10 @@ def test_staggered_prepare_and_apply_compile(one_chip):
         _gauge(plan), _shape(parts["phases"].shape, jnp.float32, plan.replicated)
     ).compile()
     links = _shape((2, 36, half), jnp.float32, plan.vec_sharding)
-    nbr = _shape((8, half), jnp.int32, plan.replicated)
     v = _shape((2, 3, half), jnp.float32, plan.vec_sharding)
-    dslash = parts["dslash"].lower(links, links, v, nbr).compile()
+    dslash = parts["dslash"].lower(links, links, v, parity=0).compile()
     assert _kernels(dslash) == 1
-    field = StaggeredField(links, links, links, links, nbr, nbr, mass=0.635)
+    field = StaggeredField(links, links, links, links, mass=0.635)
     coefs = _shape((1, 2), jnp.float32, plan.replicated)
     apply = jax.jit(plan._ks_apply()).lower(field, v, v, coefs).compile()
     assert _kernels(apply) == 2
