@@ -239,7 +239,6 @@ def fault_storm(L: int = 2, n_multiply: int = 20, seed: int = 0) -> dict:
         "timeouts": snap["timeouts"],
         "shed": snap["shed"],
         "quarantines": snap["quarantines"],
-        "degraded_dispatches": snap["degraded_dispatches"],
         "wall_s_baseline": round(base["wall_s"], 3),
         "wall_s_chaos": round(chaos["wall_s"], 3),
         "health": chaos_svc.health.snapshot(),

@@ -12,8 +12,8 @@ run under a seeded :func:`repro.chaos.storm`, and a same-seed storm replay
 
   bounded latency tail  the steady tenant's latency-class p99 under the
                         bulk burst stays within ``LATENCY_P99_CEILING`` x
-                        its unloaded p99 (deficit-weighted turns + seat
-                        preemption are what make this hold);
+                        its unloaded p99 (deficit-weighted turns are what
+                        make this hold);
   fairness              Jain's index over per-bulk-tenant delivered
                         completions, sampled the moment the smaller bulk
                         tenant finishes — a fair scheduler serves both at
@@ -304,7 +304,6 @@ def tenancy_mix(L: int = 2, n_latency: int = 8, n_burst: int = 24,
         "shed_by_class": snap["shed_by_class"],
         "quota_rejected": snap["quota_rejected"],
         "quota_rejected_by_tenant": snap["quota_rejected_by_tenant"],
-        "preemptions": snap["preemptions"],
         "scale_ups": snap["scale_ups"],
         "scale_downs": snap["scale_downs"],
         "brownout_rung_turns": snap["brownout_rung_turns"],

@@ -10,22 +10,6 @@ Load models over ``repro.serve.su3.SU3Service``:
                batch occupancy > 1 — machine-speed independent.
   closed loop  U concurrent users, each submit -> await -> resubmit for R
                rounds: the sustained-throughput view with a fixed population.
-  continuous   the SAME mixed-k open-loop schedule served batch-per-step vs
-               continuous-batching vs megakernel at a FIXED slot count.
-               Batch-per-step fragments the stream into per-(L, k) buckets —
-               every chain depth dispatches separately, each padded to the
-               slot count — while the continuous path merges all depths of
-               an L into one in-flight chain and admits at iteration
-               boundaries, so its dispatched slots run measurably fuller
-               (the acceptance bar: continuous occupancy > batch occupancy
-               under open-loop load).  The megakernel path additionally
-               collapses host dispatches to ONE per iteration at no-worse
-               occupancy (second acceptance bar, same row).
-  dispatch     per-chain continuous vs megakernel on a MIXED-L stream: the
-               chain path pays one dispatch per (host, L) per iteration,
-               the slot table pays 1 — dispatch counts and sustained GFLOPS
-               recorded (the paper's §5.3 pipeline-throughput tax, measured
-               at the serving layer).
   bf16 row     the same request stream served by a bf16-storage /
                f32-accumulate plan pool vs the f32 pool: measured HLO
                bytes/site must drop, results must agree within 1e-2.
@@ -209,13 +193,10 @@ def closed_loop(
     return row
 
 
-def _make_slot_service(slots: int, continuous: bool, megakernel: bool = False,
-                       horizon: int = 1, tracer=None) -> SU3Service:
-    """Fixed-slot service (every dispatch padded to ``slots``) so occupancy
-    is directly comparable across batch / continuous / megakernel modes."""
+def _make_slot_service(slots: int, tracer=None) -> SU3Service:
+    """Fixed-slot service: every dispatch padded to ``slots``."""
     return SU3Service(ServiceConfig(
-        autotune=False, tile=TILE, continuous=continuous,
-        megakernel=megakernel, chain_horizon=horizon, chain_slots=slots,
+        autotune=False, tile=TILE,
         batcher=BatcherConfig(
             max_batch=slots, warm_batch_sizes=(slots,), max_queue_depth=256,
         ),
@@ -255,70 +236,6 @@ def _replay_open_loop(
     return svc.metrics.snapshot()
 
 
-def continuous_comparison(
-    L: int = 2, n_requests: int = 24, seed: int = 0, slots: int = 4,
-    ks: tuple[int, ...] = (1, 2, 4),
-) -> dict:
-    """Batch-per-step vs continuous vs megakernel on one mixed-k stream.
-
-    All three services pad every dispatch to ``slots`` (one warm batch size /
-    ``chain_slots``), so ``mean_batch_occupancy`` — live slots over
-    dispatched slots — is directly comparable.  The stream mixes chain
-    depths ``ks`` at one lattice size; arrivals are Poisson at an offered
-    rate of ~1.5 requests per measured warm iteration.  The megakernel
-    acceptance bar rides on this row: host dispatches collapse to ONE per
-    iteration with occupancy no worse than the per-chain continuous path.
-    """
-    n_sites = L**4
-    probe = _make_slot_service(slots, continuous=False)
-    rng = np.random.default_rng(seed)
-    probe.warm((L,), ks=ks, batch_sizes=(slots,))
-    iter_s = _measure_step_s(probe, L, 1, slots, rng)
-    rate = 1.5 / max(iter_s, 1e-5)  # ~1.5 arrivals per iteration time
-
-    def replay(svc: SU3Service) -> dict:
-        return _replay_open_loop(svc, (L,), ks, n_requests, rate, seed, slots)
-
-    batch_snap = replay(_make_slot_service(slots, continuous=False))
-    cont_snap = replay(_make_slot_service(slots, continuous=True))
-    mega_snap = replay(_make_slot_service(slots, continuous=True, megakernel=True))
-    return {
-        "name": "serve_continuous_vs_batch",
-        "L": L,
-        "mix_k": list(ks),
-        "n_requests": n_requests,
-        "slots": slots,
-        "offered_rate_rps": round(rate, 2),
-        "occupancy_batch": batch_snap["mean_batch_occupancy"],
-        "occupancy_continuous": cont_snap["mean_batch_occupancy"],
-        "occupancy_megakernel": mega_snap["mean_batch_occupancy"],
-        "occupancy_gain": round(
-            cont_snap["mean_batch_occupancy"]
-            / max(batch_snap["mean_batch_occupancy"], 1e-9), 3
-        ),
-        "continuous_higher_occupancy": (
-            cont_snap["mean_batch_occupancy"] > batch_snap["mean_batch_occupancy"]
-        ),
-        "megakernel_occupancy_no_worse": (
-            mega_snap["mean_batch_occupancy"]
-            >= 0.95 * cont_snap["mean_batch_occupancy"]
-        ),
-        "midchain_admits": cont_snap["midchain_admits"],
-        "midchain_admits_megakernel": mega_snap["midchain_admits"],
-        "latency_p50_ms_batch": batch_snap["latency_p50_ms"],
-        "latency_p50_ms_continuous": cont_snap["latency_p50_ms"],
-        "latency_p50_ms_megakernel": mega_snap["latency_p50_ms"],
-        "dispatches_batch": batch_snap["dispatches"],
-        "dispatches_continuous": cont_snap["dispatches"],
-        "dispatches_megakernel": mega_snap["dispatches"],
-        "dispatches_per_iteration_megakernel": mega_snap["dispatches_per_iteration"],
-        "megakernel_single_dispatch_per_iteration": (
-            mega_snap["dispatches_per_iteration"] <= 1.0
-        ),
-        "sustained_gflops_busy": cont_snap["sustained_gflops_busy"],
-    }
-
-
 def traced_serving(
     L: int = 2, n_requests: int = 16, seed: int = 0, slots: int = 4,
     ks: tuple[int, ...] = (1, 2), n_stencil: int = 4,
@@ -332,8 +249,9 @@ def traced_serving(
     sustained-GFLOPS delta between the two.  The traced service then
     serves a short stencil stream, and the 2-host overlap schedule runs
     under the SAME tracer (oversubscribed on the local device), so one
-    exported trace covers the full request lifecycle (admit -> queue ->
-    seat -> dispatch -> complete, multiply AND stencil kinds) plus the
+    exported trace covers the full request lifecycle (admit -> dispatch ->
+    complete, the queue wait an attribute of the request span, multiply AND
+    stencil kinds) plus the
     stencil exchange/interior/boundary phases.  The row asserts both
     coverages and names the trace files (``{trace_prefix}.jsonl`` and
     ``{trace_prefix}.chrome.json`` — the latter loads in
@@ -342,19 +260,19 @@ def traced_serving(
     """
     from repro.obs import Tracer, attribution_report, provenance_block
 
-    probe = _make_slot_service(slots, continuous=False)
+    probe = _make_slot_service(slots)
     rng = np.random.default_rng(seed)
     probe.warm((L,), ks=ks, batch_sizes=(slots,))
     iter_s = _measure_step_s(probe, L, 1, slots, rng)
     rate = 1.5 / max(iter_s, 1e-5)
 
-    # min-of-N walls: the first continuous-mode replay pays the chain jit
-    # compiles and every replay carries scheduler/sleep jitter; the min
-    # discards both while any persistent per-span tracer cost survives
+    # min-of-N walls: the first replay pays the jit compiles and every
+    # replay carries scheduler/sleep jitter; the min discards both while
+    # any persistent per-span tracer cost survives
     def best_replay(tracer, reps=3):
         best, svc = None, None
         for _ in range(reps):
-            svc = _make_slot_service(slots, continuous=True, tracer=tracer)
+            svc = _make_slot_service(slots, tracer=tracer)
             snap = _replay_open_loop(svc, (L,), ks, n_requests, rate, seed, slots)
             if best is None or snap["wall_s"] < best["wall_s"]:
                 best = snap
@@ -387,7 +305,7 @@ def traced_serving(
         tracer.absorb(phase_records, lane_offset=200)
 
     names = {s.name for s in tracer.spans()}
-    lifecycle = {"admit", "seat", "dispatch", "request"}
+    lifecycle = {"admit", "dispatch", "request"}
     phases = {"stencil.exchange", "stencil.interior", "stencil.boundary"}
     jsonl_path = f"{trace_prefix}.jsonl"
     chrome_path = f"{trace_prefix}.chrome.json"
@@ -425,54 +343,6 @@ def traced_serving(
     if phase_err:
         row["phase_subprocess_error"] = phase_err
     return row
-
-
-def dispatch_overhead(
-    Ls: tuple[int, ...] = (2, 3), n_requests: int = 16, seed: int = 0,
-    slots: int = 4, ks: tuple[int, ...] = (1, 2),
-) -> dict:
-    """Per-chain continuous vs megakernel dispatch bill on a MIXED-L stream.
-
-    With two lattice sizes in flight the per-chain path pays one dispatch
-    per (host, L) chain per iteration; the megakernel slot table pays ONE.
-    This row records the dispatch counts, dispatches/iteration, and
-    sustained GFLOPS of both paths on an identical Poisson stream — the
-    serving-side measurement of the paper's §5.3 pipeline-throughput tax.
-    """
-    probe = _make_slot_service(slots, continuous=False)
-    rng = np.random.default_rng(seed)
-    probe.warm((min(Ls),), ks=(1,), batch_sizes=(slots,))
-    iter_s = _measure_step_s(probe, min(Ls), 1, slots, rng)
-    rate = 1.5 / max(iter_s, 1e-5)
-
-    chain_snap = _replay_open_loop(
-        _make_slot_service(slots, continuous=True),
-        Ls, ks, n_requests, rate, seed, slots)
-    mega_snap = _replay_open_loop(
-        _make_slot_service(slots, continuous=True, megakernel=True),
-        Ls, ks, n_requests, rate, seed, slots)
-    return {
-        "name": "serve_dispatch_overhead",
-        "mix_L": list(Ls),
-        "mix_k": list(ks),
-        "n_requests": n_requests,
-        "slots": slots,
-        "offered_rate_rps": round(rate, 2),
-        "dispatches_chains": chain_snap["dispatches"],
-        "dispatches_megakernel": mega_snap["dispatches"],
-        "dispatch_ratio": round(
-            chain_snap["dispatches"] / max(mega_snap["dispatches"], 1), 3
-        ),
-        "dispatches_per_iteration_chains": chain_snap["dispatches_per_iteration"],
-        "dispatches_per_iteration_megakernel": mega_snap["dispatches_per_iteration"],
-        "megakernel_fewer_dispatches": (
-            mega_snap["dispatches"] < chain_snap["dispatches"]
-        ),
-        "occupancy_chains": chain_snap["mean_batch_occupancy"],
-        "occupancy_megakernel": mega_snap["mean_batch_occupancy"],
-        "gflops_busy_chains": chain_snap["sustained_gflops_busy"],
-        "sustained_gflops_busy": mega_snap["sustained_gflops_busy"],
-    }
 
 
 def bf16_plan_comparison(L: int, seed: int) -> dict:
@@ -617,8 +487,6 @@ def run(quick: bool = True, seed: int = 0, use_autotune: bool = False) -> list[d
         open_loop(n_req, Ls, ks, seed, use_autotune=use_autotune),
         closed_loop(users, rounds, max(Ls), None if use_autotune else max(ks),
                     seed, use_autotune=use_autotune),
-        continuous_comparison(min(Ls), n_requests=16 if quick else 48, seed=seed),
-        dispatch_overhead(Ls, n_requests=12 if quick else 32, seed=seed),
         bf16_plan_comparison(max(Ls), seed),
         traced_serving(min(Ls), n_requests=12 if quick else 32, seed=seed),
         solve_mix(min(Ls), n_multiply=4 if quick else 8, seed=seed),
@@ -640,21 +508,6 @@ def main(argv: list[str] | None = None) -> int:
         print(r)
         if r["name"] == "serve_open_loop" and r["mean_live_batch"] <= 1.0:
             print("FAIL: open-loop batch occupancy did not exceed 1", file=sys.stderr)
-            ok = False
-        if r["name"] == "serve_continuous_vs_batch" and not r["continuous_higher_occupancy"]:
-            print("FAIL: continuous batching did not beat batch-per-step "
-                  "occupancy under open-loop load", file=sys.stderr)
-            ok = False
-        if r["name"] == "serve_continuous_vs_batch" and not (
-            r["megakernel_single_dispatch_per_iteration"]
-            and r["megakernel_occupancy_no_worse"]
-        ):
-            print("FAIL: megakernel did not hold 1 dispatch/host/iteration "
-                  "at no-worse occupancy", file=sys.stderr)
-            ok = False
-        if r["name"] == "serve_dispatch_overhead" and not r["megakernel_fewer_dispatches"]:
-            print("FAIL: megakernel did not reduce mixed-L dispatch count",
-                  file=sys.stderr)
             ok = False
         if r["name"] == "serve_bf16_vs_f32" and not (
             r["bf16_fewer_bytes"] and r["within_1e-2"] and r["bf16_verified"]

@@ -6,7 +6,7 @@
 One chip runs, in one process and in this order: the paper's ``PAPER_L32``
 plan (``SU3Engine`` plus fused chains), the default ``SU3Service`` with
 autotune on (multiplies, a stencil and a CG solve at L=32), the autotuner's
-stencil and CG sweeps, the megakernel service over a mixed L in {16, 32}
+stencil and CG sweeps, the default service over a mixed L in {16, 32}
 stream, and the bf16-storage and two-row plans.  ``--chips 4`` runs only the
 plan sharded over four chips and the same plan on one chip beside it, and a
 four-host service.
@@ -292,15 +292,15 @@ def phase_autotune(L: int) -> dict:
             "cg": {k: cg[k] for k in ("tile", "fused", "cached")}}
 
 
-def phase_megakernel(Ls: tuple[int, ...]) -> dict:
-    """ServiceConfig(continuous=True, megakernel=True) over a mixed-L stream."""
+def phase_mixed_L(Ls: tuple[int, ...]) -> dict:
+    """The default SU3Service over a mixed-L, mixed-k stream: one dispatch
+    per (L, k) bucket."""
     import jax
 
     from repro.core.su3.plan import verify_tolerance
     from repro.serve.su3 import ServiceConfig, SU3Service
 
-    svc = SU3Service(ServiceConfig(continuous=True, megakernel=True,
-                                   cache_directory=str(AUTOTUNE_DIR)))
+    svc = SU3Service(ServiceConfig(cache_directory=str(AUTOTUNE_DIR)))
     key = jax.random.PRNGKey(SEED + 2)
     reqs = []
     stream = [(Ls[0], 2), (Ls[1], 1), (Ls[0], 3), (Ls[1], 2)]
@@ -313,9 +313,8 @@ def phase_megakernel(Ls: tuple[int, ...]) -> dict:
     errors = {f"L{L}_k{k}_{i}": (_err(svc.pop_result(rid), _chain_ref(a, b, k)), tol)
               for i, (rid, a, b, k, L) in enumerate(reqs)}
     snap = svc.metrics.snapshot()
-    return {"errors": errors,
-            "dispatches_per_iteration": snap["dispatches_per_iteration"],
-            "completed": snap["completed"]}
+    return {"errors": errors, "dispatches": snap["dispatches"],
+            "buckets": len(set(stream)), "completed": snap["completed"]}
 
 
 def phase_other_plans(L: int, tile: int) -> dict:
@@ -447,7 +446,7 @@ def main(argv: list[str] | None = None) -> None:
         smoke.phase("paper_plan", phase_paper_plan, 32, 512)
         smoke.phase("served", phase_served, 32)
         smoke.phase("autotune", phase_autotune, 16)
-        smoke.phase("megakernel", phase_megakernel, (16, 32))
+        smoke.phase("mixed_L", phase_mixed_L, (16, 32))
         smoke.phase("other_plans", phase_other_plans, 32, 512)
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,

@@ -11,11 +11,6 @@ trajectory is gated like every other row:
   dispatch_overhead_L{L}
       K single-step dispatches vs ONE fused(K) dispatch of the same K
       multiplies; the wall difference over K-1 is the per-dispatch overhead.
-  megakernel_amortization_L{L}
-      a SLOTS-slot table advanced one iteration as SLOTS single-lattice
-      dispatches (the per-chain continuous path) vs ONE batched megakernel
-      dispatch — the dispatch-count collapse the slot-table serving mode
-      banks every iteration.
 
 All timing runs through the ``repro.obs`` tracer — every rep is a
 ``profile.dispatch`` span on the same monotonic clock and span schema the
@@ -35,15 +30,10 @@ import os
 import statistics
 import sys
 
-import numpy as np
-
-import jax.numpy as jnp
-
 from repro.core.su3.engine import EngineConfig, SU3Engine
 from repro.core.su3.layouts import Layout
 from repro.obs import Tracer
 
-SLOTS = 4
 FUSED_K = 4
 TILE = 128
 
@@ -83,63 +73,8 @@ def dispatch_overhead_row(L: int, k: int = FUSED_K, reps: int = 5) -> dict:
     }
 
 
-def megakernel_amortization_row(L: int, slots: int = SLOTS, reps: int = 5) -> dict:
-    """SLOTS single-lattice dispatches vs ONE megakernel dispatch per
-    iteration, on identical slot data — the serving-layer collapse."""
-    cfg = EngineConfig(L=L, dtype="float32", variant="pallas",
-                       layout=Layout.SOA, tile=TILE, iterations=1, warmups=1)
-    plan = SU3Engine(cfg).plan
-    rng = np.random.default_rng(0)
-    S = plan.padded_sites
-    a = rng.standard_normal((slots, S, 4, 3, 3, 2)).astype(np.float32)
-    b = rng.standard_normal((slots, 4, 3, 3, 2)).astype(np.float32)
-    import jax
-    a_phys = jax.vmap(plan.codec.pack)(
-        jnp.asarray(a[..., 0] + 1j * a[..., 1], jnp.complex64))
-    b_p = jax.vmap(plan.codec.pack_b)(
-        jnp.asarray(b[..., 0] + 1j * b[..., 1], jnp.complex64))
-    ones = jnp.ones((slots,), jnp.int32)
-    mega = plan.fused_batched_step(slots, max_k=1)
-    # the megakernel donates its slot table: it advances its own copy, the
-    # way the serving loop rebinds its table every iteration
-    table = jnp.copy(a_phys)
-
-    def per_chain():
-        outs = [plan.step(a_phys[s], b_p[s]) for s in range(slots)]
-        outs[-1].block_until_ready()
-
-    def megakernel():
-        nonlocal table
-        table = mega(table, b_p, ones)
-        table.block_until_ready()
-
-    per_chain()  # warm both compiled shapes before timing
-    megakernel()
-    chain_s = _median_wall(per_chain, reps, "per_chain", L=L, slots=slots)
-    mega_s = _median_wall(megakernel, reps, "megakernel", L=L, slots=slots)
-    useful_flops = 864.0 * (L**4) * slots
-    return {
-        "name": f"megakernel_amortization_L{L}",
-        "L": L,
-        "slots": slots,
-        "dispatches_per_iter_chains": slots,
-        "dispatches_per_iter_megakernel": 1,
-        "chains_s": round(chain_s, 6),
-        "megakernel_s": round(mega_s, 6),
-        "dispatch_amortization_speedup": round(chain_s / max(mega_s, 1e-9), 3),
-        "per_dispatch_overhead_us": round(
-            max(chain_s - mega_s, 0.0) / (slots - 1) * 1e6, 1),
-        "GFLOPS": round(useful_flops / mega_s / 1e9, 3),
-    }
-
-
 def run(quick: bool = True) -> list[dict]:
-    Ls = (2, 4) if quick else (4, 8)
-    rows = []
-    for L in Ls:
-        rows.append(dispatch_overhead_row(L))
-        rows.append(megakernel_amortization_row(L))
-    return rows
+    return [dispatch_overhead_row(L) for L in ((2, 4) if quick else (4, 8))]
 
 
 def merge_into_artifact(rows: list[dict], path: str) -> None:

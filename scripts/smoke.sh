@@ -2,8 +2,8 @@
 # Quick-mode smoke: fast-tier suite + machine-readable benchmark rows.
 #
 #   scripts/smoke.sh            # fast tests (-m "not slow") + benchmarks
-#   scripts/smoke.sh --full     # also run the slow tier (serving/megakernel/
-#                               # e2e tests — the ~12-minute tail)
+#   scripts/smoke.sh --full     # also run the slow tier (serving/e2e
+#                               # tests — the ~12-minute tail)
 #   scripts/smoke.sh --no-bench # tests only
 #
 # The tier-1 gate (`python -m pytest -x -q`, no marker filter) still runs
@@ -73,7 +73,7 @@ echo "== fast tier (-m 'not slow') =="
 python -m pytest -x -q -m "not slow"
 
 if [[ "$RUN_FULL" == 1 ]]; then
-  echo "== slow tier (-m slow: serving/megakernel/e2e) =="
+  echo "== slow tier (-m slow: serving/e2e) =="
   python -m pytest -x -q -m slow
 fi
 

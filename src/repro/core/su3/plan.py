@@ -304,11 +304,6 @@ def make_raw_step(
             f"kernel {kernel.name!r} does not support layout {codec.layout.value!r} "
             f"(supported: {[l.value for l in kernel.layouts]})"
         )
-    if kernel.form == registry.BATCHED:
-        raise ValueError(
-            f"kernel {kernel.name!r} is slot-batched; it dispatches through "
-            f"ExecutionPlan.fused_batched_step, not a single-lattice step"
-        )
     if kernel.form == registry.STENCIL:
         raise ValueError(
             f"kernel {kernel.name!r} is a nearest-neighbor stencil; it "
@@ -370,7 +365,6 @@ def make_raw_step(
     return raw_step
 
 
-MEGAKERNEL_VARIANT = "pallas_megakernel"
 STENCIL_VARIANT = "pallas_stencil"
 CG_VARIANT = "pallas_cg"
 
@@ -851,65 +845,6 @@ def _textbook_cg(apply_j, u, b, tol, max_iters):
     return x, residuals, False
 
 
-def make_raw_batched_step(
-    codec: LayoutCodec,
-    kernel: registry.KernelEntry,
-    *,
-    tile: int,
-    max_k: int,
-    interpret: bool | None = None,
-    alias: bool = False,
-) -> Callable[[jax.Array, jax.Array, jax.Array], jax.Array]:
-    """Unjitted slot-batched step (a_batch, b_batch, slot_k) -> c_batch.
-
-    The megakernel analogue of :func:`make_raw_step`: the physical slot table
-    ``a_batch (slots, ...)`` flattens to the batched planar view, advances by
-    ``slot_k`` chained multiplies per slot in ONE kernel dispatch, and folds
-    back into the physical layout.
-    """
-    if kernel.form != registry.BATCHED:
-        raise ValueError(
-            f"kernel {kernel.name!r} has form {kernel.form!r}; the batched "
-            f"step needs a {registry.BATCHED!r}-form kernel"
-        )
-    if not kernel.supports_layout(codec.layout):
-        raise ValueError(
-            f"kernel {kernel.name!r} does not support layout {codec.layout.value!r} "
-            f"(supported: {[l.value for l in kernel.layouts]})"
-        )
-    if not codec.supports_planar_view:
-        raise ValueError(
-            f"batched kernel {kernel.name!r} needs a planar-view layout, "
-            f"got {codec.layout.value!r}"
-        )
-    if codec.is_mixed_precision and not kernel.supports_accum_dtype():
-        raise ValueError(
-            f"kernel {kernel.name!r} cannot accumulate at {codec.accum_dtype!r} "
-            f"over {codec.dtype!r} storage (no accum_dtype support)"
-        )
-    if codec.is_compressed and not kernel.supports_compression():
-        raise ValueError(
-            f"kernel {kernel.name!r} cannot stream two-row compressed gauge "
-            f"(no reconstruct-on-load path)"
-        )
-
-    def raw_batched(
-        a_batch: jax.Array, b_batch: jax.Array, slot_k: jax.Array
-    ) -> jax.Array:
-        a_p = jax.vmap(codec.planar_view)(a_batch)
-        kw: dict[str, Any] = {"tile": tile, "max_k": max_k, "alias": alias}
-        if codec.is_mixed_precision:
-            kw["accum_dtype"] = codec.accum_dtype
-        if codec.is_compressed:
-            kw["compressed"] = True
-        if interpret is not None:
-            kw["interpret"] = interpret
-        c_p = kernel.fn(a_p, b_batch, slot_k, **kw)
-        return jax.vmap(codec.from_planar_view)(c_p, a_batch)
-
-    return raw_batched
-
-
 class ExecutionPlan:
     """Compiled execution of one EngineConfig tuple on one mesh.
 
@@ -961,9 +896,6 @@ class ExecutionPlan:
         )
         self.step = jax.jit(self.raw_step, out_shardings=self.sharding)
         self._fused_steps: dict[int, Callable[[jax.Array, jax.Array], jax.Array]] = {}
-        self._batched_steps: dict[
-            tuple[int, int], Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
-        ] = {}
         self._stencil_steps: dict[
             tuple[bool, int], Callable[[jax.Array, jax.Array], jax.Array]
         ] = {}
@@ -1007,8 +939,8 @@ class ExecutionPlan:
         )
 
     def lattice_batch_sharding(self) -> NamedSharding:
-        """Sharding for a LEADING whole-lattice batch axis (request batches,
-        megakernel slot tables): the batch axis shards over the mesh's site
+        """Sharding for a LEADING whole-lattice batch axis (request
+        batches): the batch axis shards over the mesh's site
         axes — whole lattices per device, host-major — and every physical
         dimension is replicated.  The single owner of the layout ->
         physical-rank mapping for batched forms."""
@@ -1042,55 +974,6 @@ class ExecutionPlan:
                 raw, out_shardings=self.sharding, donate_argnums=(0,)
             )
         return self._fused_steps[k]
-
-    def fused_batched_step(
-        self, slots: int, max_k: int = 8
-    ) -> Callable[[jax.Array, jax.Array, jax.Array], jax.Array]:
-        """ONE megakernel dispatch advancing a whole slot table.
-
-        ``fused_batched_step(slots, max_k)(a_batch, b_batch, slot_k)`` equals
-        applying ``step`` ``slot_k[s]`` times to slot ``s`` independently —
-        bit-identical, but every slot's chain runs inside one pallas_call
-        whose grid spans (slots x site tiles), so a serving iteration costs
-        one host dispatch however many chains are in flight.  Per-slot depths
-        are data (scalar-prefetched), clamped to the static ``max_k``; a slot
-        with depth 0 passes through untouched.
-
-        The slot table is donated and the kernel's C block aliases A's
-        buffer, so in-flight slots update in place with zero copies.  When
-        ``slots`` divides the device count each device runs whole slots;
-        otherwise every device runs its site shard of every slot.
-
-        Args:
-            slots: slot-table size (the leading axis of ``a_batch``).
-            max_k: static in-kernel chain bound; one compiled program serves
-                every per-slot depth in ``[0, max_k]``.
-        """
-        if slots < 1:
-            raise ValueError(f"slots must be >= 1, got {slots}")
-        if max_k < 1:
-            raise ValueError(f"max_k must be >= 1, got {max_k}")
-        key = (slots, max_k)
-        if key not in self._batched_steps:
-            kernel = registry.get_kernel(MEGAKERNEL_VARIANT)
-            raw = make_raw_batched_step(
-                self.codec, kernel, tile=self.cfg.tile, max_k=max_k, alias=True
-            )
-            if slots % self.n_devices == 0:
-                # whole lattices per device — the same sharding
-                # BatchedLatticeRunner gives request batches
-                a_spec = self.lattice_batch_sharding().spec
-                per_slot = P(a_spec[0])
-            else:
-                a_spec = P(None, *self.sharding.spec)
-                per_slot = P()
-            b_spec = P(*per_slot, None, None)
-            self._batched_steps[key] = jax.jit(
-                site_local(raw, self.mesh, (a_spec, b_spec, per_slot), a_spec),
-                out_shardings=NamedSharding(self.mesh, a_spec),
-                donate_argnums=(0,),
-            )
-        return self._batched_steps[key]
 
     # -- nearest-neighbor stencil (Dslash-style) -------------------------------
 

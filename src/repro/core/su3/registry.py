@@ -13,11 +13,6 @@ enough metadata for a plan to validate and wire a kernel without per-kernel
       ``"planar"`` — fn(a_p, b_p, *, tile, k_iters, interpret) on the
       flattened planar view (a_p: (2, 36, S), b_p: (2, 36)); the plan feeds
       it the codec's planar view directly (zero-copy for SoA).
-      ``"batched"`` — fn(a_p, b_p, slot_k, *, tile, max_k, interpret) on a
-      slot-batched planar view (a_p: (slots, 2, 36, S), b_p: (slots, 2, 36),
-      slot_k: (slots,) int32); ONE dispatch advances every slot by its own
-      chain depth.  Consumed only by ``ExecutionPlan.fused_batched_step`` —
-      a batched kernel cannot serve as a plan's single-lattice ``step``.
       ``"stencil"`` — fn(u_p, v_nbr, *, tile, interpret, accum_dtype?) on the
       planar link view plus direction-major shifted neighbor vectors
       (u_p: (2, 36, S), v_nbr: (8, 2, 3, S) -> out (2, 3, S)); the
@@ -53,7 +48,6 @@ from repro.core.su3.layouts import Layout
 
 CANONICAL = "canonical"
 PLANAR = "planar"
-BATCHED = "batched"
 STENCIL = "stencil"
 STENCIL_AXPY = "stencil_axpy"
 
@@ -137,7 +131,7 @@ def register_kernel(
     Raises:
         ValueError: on an unknown ``form``.
     """
-    if form not in (CANONICAL, PLANAR, BATCHED, STENCIL, STENCIL_AXPY):
+    if form not in (CANONICAL, PLANAR, STENCIL, STENCIL_AXPY):
         raise ValueError(f"unknown kernel form {form!r}")
 
     def deco(fn: Callable) -> Callable:

@@ -63,38 +63,6 @@ def su3_mult_planar(
 
 
 @registry.register_kernel(
-    "pallas_megakernel",
-    layouts=(Layout.SOA, Layout.AOSOA),
-    backends=("pallas",),
-    form=registry.BATCHED,
-    supports_fused=True,
-    supports_accum=True,
-    supports_compressed=True,
-)
-def su3_mult_planar_batched(
-    a_p: jax.Array,
-    b_p: jax.Array,
-    slot_k: jax.Array,
-    *,
-    tile: int = DEFAULT_TILE,
-    max_k: int = su3_matmul._UNROLL_MAX,
-    interpret: bool | None = None,
-    alias: bool = False,
-    accum_dtype: str | None = None,
-    compressed: bool = False,
-) -> jax.Array:
-    """Slot-batched megakernel entry: a_p (slots, 2, 36, S), b_p (slots, 2, 36),
-    slot_k (slots,) per-slot chain depths — one dispatch for the whole table.
-    """
-    if interpret is None:
-        interpret = _use_interpret()
-    return su3_matmul.su3_mult_planar_batched(
-        a_p, b_p, slot_k, tile=tile, max_k=max_k, interpret=interpret,
-        alias=alias, accum_dtype=accum_dtype, compressed=compressed,
-    )
-
-
-@registry.register_kernel(
     "pallas_stencil",
     layouts=(Layout.SOA, Layout.AOSOA),
     backends=("pallas",),

@@ -20,10 +20,6 @@ Layout contract (planar SoA, packed by kernels.ops / core.su3.layouts):
   a: (2, 36, S)  — [re|im, link*row*col, site], S % tile == 0
   b: (2, 36)     — [re|im, link*row*col]
   -> c: (2, 36, S)
-
-``su3_mult_planar_batched`` is the serving megakernel: the same body over a
-(slots x site-tiles) grid with a scalar-prefetched per-slot chain depth, so
-a whole slot table of in-flight chains advances in ONE dispatch.
 """
 from __future__ import annotations
 
@@ -32,7 +28,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 LINKS, SU3 = 4, 3
 ROWS = LINKS * SU3 * SU3  # 36 complex entries per site
@@ -241,112 +236,6 @@ def su3_mult_planar(
         input_output_aliases={0: 0} if alias else {},
         interpret=interpret,
     )(a, b)
-
-
-def _su3_megakernel(
-    k_ref,
-    a_ref,
-    b_ref,
-    c_ref,
-    *,
-    max_k: int,
-    accum_dtype: str | None = None,
-    compressed: bool = False,
-):
-    """One (slot, tile) grid step of the batched K-chain megakernel.
-
-    ``k_ref`` is the scalar-prefetched per-slot chain-depth table (SMEM, the
-    whole ``(slots,)`` array — available before the body runs, so Mosaic can
-    schedule the DMAs); the grid walks ``slot`` major, ``site-tile`` minor, and
-    the BlockSpec pipeline double-buffers the A-tile HBM->VMEM staging across
-    grid steps exactly as in the single-lattice kernel.  Each step chains
-    ``k = clamp(k_ref[slot], 0, max_k)`` multiplies on the resident tile: a
-    dead slot (k=0) copies A through untouched, a live slot runs its own
-    chain depth — mixed-depth batches share ONE dispatch, which is the whole
-    point (the per-(L, chain) dispatch tax is the pipeline-throughput ceiling
-    the paper measures on PIUMA).
-    """
-    slot = pl.program_id(0)
-    k = jnp.clip(k_ref[slot], 0, max_k)
-    a = a_ref[0]  # (2, 36 | 24, tile) in VMEM
-    b = b_ref[0]  # (2, 36)            per-slot B, VMEM-resident across tiles
-    if accum_dtype is not None:
-        a = a.astype(accum_dtype)
-        b = b.astype(accum_dtype)
-    if compressed:
-        a = _expand_tile(a)
-    # dynamic trip count: the chain body is identical to the fused kernel's,
-    # so a slot's k-chain is bit-identical to k sequential single steps
-    c = jax.lax.fori_loop(0, k, lambda _, x: _mult_tile(x, b), a)
-    if compressed:
-        c = _compress_tile(c)
-    c_ref[0] = c.astype(c_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "tile", "max_k", "interpret", "alias", "accum_dtype", "compressed"
-    ),
-)
-def su3_mult_planar_batched(
-    a: jax.Array,
-    b: jax.Array,
-    slot_k: jax.Array,
-    *,
-    tile: int = 512,
-    max_k: int = _UNROLL_MAX,
-    interpret: bool = False,
-    alias: bool = False,
-    accum_dtype: str | None = None,
-    compressed: bool = False,
-) -> jax.Array:
-    """Batched K-chain megakernel: ONE pallas_call over (slots x site tiles).
-
-    The serving dispatch amortizer: where the single-lattice kernel pays one
-    dispatch per (lattice, chain) per iteration, this kernel walks a grid of
-    ``slots * (S // tile)`` steps in one dispatch, chaining ``slot_k[s]``
-    multiplies in-kernel for slot ``s`` (scalar-prefetched, so per-slot chain
-    depths are data, not compiled shapes).
-
-    Layout contract (planar, batched over the leading slot axis):
-      a:      (slots, 2, 36, S) — per-slot planar lattice, S % tile == 0
-      b:      (slots, 2, 36)    — per-slot planar B
-      slot_k: (slots,) int32    — chain depth per slot; 0 = pass-through
-      -> c:   (slots, 2, 36, S)
-
-    ``alias`` writes C into A's buffer (``input_output_aliases``; index 1 —
-    the scalar-prefetch operand occupies index 0) so donated in-flight slot
-    tables update in place with zero copies.  ``max_k`` is the static chain
-    bound the dynamic per-slot depth is clamped to (one compiled program
-    serves every depth up to it).
-    """
-    rows = COMP_ROWS if compressed else ROWS
-    assert a.ndim == 4 and a.shape[1:3] == (2, rows), (a.shape, compressed)
-    slots, n_sites = a.shape[0], a.shape[3]
-    assert b.shape == (slots, 2, ROWS), (b.shape, slots)
-    assert slot_k.shape == (slots,), (slot_k.shape, slots)
-    assert n_sites % tile == 0, (n_sites, tile)
-    assert max_k >= 1, max_k
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(slots, n_sites // tile),
-        in_specs=[
-            pl.BlockSpec((1, 2, rows, tile), lambda s, i, k_ref: (s, 0, 0, i)),
-            pl.BlockSpec((1, 2, ROWS), lambda s, i, k_ref: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 2, rows, tile), lambda s, i, k_ref: (s, 0, 0, i)),
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _su3_megakernel, max_k=max_k, accum_dtype=accum_dtype,
-            compressed=compressed,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-        input_output_aliases={1: 0} if alias else {},
-        interpret=interpret,
-    )(slot_k.astype(jnp.int32), a, b)
 
 
 def vmem_bytes(tile: int, word_bytes: int = 4, accum_word_bytes: int | None = None) -> int:

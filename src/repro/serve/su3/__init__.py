@@ -7,9 +7,7 @@ Public surface:
                                via dtype="bfloat16", accum_dtype="float32")
   BatcherConfig / DynamicBatcher / ServeRequest / CoalescedBatch
                                the (L, k)-bucketed coalescing queue
-  LocalityRouter / InflightChain
-                               host-locality routing and continuous-batching
-                               chain admission (multi-host serving)
+  LocalityRouter               host-locality routing (multi-host serving)
   ServiceMetrics               latency/throughput/occupancy accounting
   RequestFailure taxonomy      structured per-request failures delivered
                                through the result channel (DeadlineExceeded
@@ -25,7 +23,6 @@ from repro.serve.su3.batcher import (
     BatcherConfig,
     CoalescedBatch,
     DynamicBatcher,
-    InflightChain,
     LocalityRouter,
     ServeRequest,
 )
@@ -66,7 +63,6 @@ __all__ = [
     "DeficitFairScheduler",
     "DynamicBatcher",
     "HostHealth",
-    "InflightChain",
     "LoadShedError",
     "LocalityRouter",
     "PRIORITY",

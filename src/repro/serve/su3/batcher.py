@@ -23,25 +23,13 @@ The batcher is a plain steppable object — no threads, no event loop — so it
 drops into a synchronous replay harness (benchmarks/serve_traffic.py), an
 asyncio front-end (``SU3Service.arun``), or a test with the same semantics.
 
-Two scheduling policies layered on top (both host-side bookkeeping only —
-no jax in this module):
+One scheduling policy is layered on top (host-side bookkeeping only — no
+jax in this module):
 
   * **locality routing** — :class:`LocalityRouter` pins each lattice size L
     to one host, sticky after first sight: the host that paid the compile +
     tile sweep for an L's warm runner keeps serving that L (the serving
     analog of the paper's first-touch rule — work follows the warm data).
-  * **continuous batching** — :class:`InflightChain` tracks the slots of a
-    chain that is being *re-dispatched one iteration at a time*: requests
-    with the same L join at any iteration boundary (mid-chain admission)
-    instead of waiting for the whole chain to drain; a request for another
-    L can never join (the lattice shapes differ) and queues for its own
-    chain.
-  * **megakernel slot table** — :class:`SlotTable` is the per-host
-    generalization the batched K-chain megakernel dispatches against: slots
-    hold requests of ANY lattice size (the kernel pads every slot to one
-    site capacity), each with its own remaining-iteration count, and one
-    dispatch per host per iteration advances them all.  Mid-chain admission
-    degenerates to a slot swap — seat the request, set its depth.
 """
 from __future__ import annotations
 
@@ -72,8 +60,8 @@ class ServeRequest:
     ``(4 m^2 - D_eo D_oe) x_e = b_e``, ``b`` an even-site vector
     (n_sites // 2, 3).  ``tol``/``max_iters`` bound the solver and the
     request's iteration count is DATA-DEPENDENT — the service advances it a
-    few CG iterations per scheduling turn and it retires mid-chain the turn
-    its residual crosses tol.
+    few CG iterations per scheduling turn and it retires the turn its
+    residual crosses tol.
     """
 
     req_id: int
@@ -84,22 +72,22 @@ class ServeRequest:
     k: int
     arrival_s: float = 0.0  # perf_counter timestamp at admission
     kind: str = "multiply"  # "multiply" | "stencil" | "solve"
-    seated_s: float = 0.0  # perf_counter timestamp when seated in a slot/batch
+    seated_s: float = 0.0  # perf_counter timestamp when seated (batch popped)
     # (0.0 until seated; the request-lifecycle span derives queue_wait from it)
     tol: float = 0.0  # solve: relative-residual convergence target
     max_iters: int = 0  # solve: iteration cap (retires unconverged at cap)
     mass: float | None = None  # solve: the staggered operator's valence mass
     # (None = the site-local-adjoint operator)
     deadline_s: float = 0.0  # absolute perf_counter deadline (0 = none); a
-    # request past it is EVICTED (queue slot and live chain/table seat freed)
+    # request past it is EVICTED (queue slot or active solve seat freed)
     # and completes with a structured DeadlineExceededError
     priority: int = 0  # shedding priority (robustness.PRIORITY[kind]): under
     # backpressure, lower priorities shed first to admit higher ones
     attempts: int = 0  # dispatch attempts consumed (retry accounting)
     tenant: str = DEFAULT_TENANT  # tenant identity (quota + fairness group)
-    slo: str = SLO_BULK  # SLO class: "latency" (preempting, never shed) or
-    # "bulk" (preemptible, the only sheddable lane); defaults bulk so a raw
-    # request stays sheddable — the service sets the per-kind class default
+    slo: str = SLO_BULK  # SLO class: "latency" (never shed) or "bulk" (the
+    # only sheddable lane); defaults bulk so a raw request stays sheddable —
+    # the service sets the per-kind class default
 
     @property
     def n_sites(self) -> int:
@@ -191,7 +179,7 @@ class DynamicBatcher:
         self._buckets: "OrderedDict[tuple[GroupKey, BucketKey], list[ServeRequest]]" \
             = OrderedDict()
         # stencil requests coalesce by L only (no chain depth); they never
-        # ride multiply chains, so they live in their own queue family
+        # ride a multiply batch, so they live in their own queue family
         self._stencil: "OrderedDict[tuple[GroupKey, int], list[ServeRequest]]" \
             = OrderedDict()
         # solve requests also queue by L; the service advances ONE active
@@ -251,21 +239,6 @@ class DynamicBatcher:
             len(q) for _kind, (group, _key), q in self._family_items()
             if group[1] == slo
         )
-
-    def has_waiting(self, kind: str, L: int | None = None,
-                    slo: str | None = None) -> bool:
-        """Any queued request of ``kind`` (optionally restricted to one
-        lattice size and/or SLO class) — the preemption trigger check."""
-        for fam_kind, (group, key), q in self._family_items():
-            if fam_kind != kind or not q:
-                continue
-            if slo is not None and group[1] != slo:
-                continue
-            fam_L = key[0] if fam_kind == "multiply" else key
-            if L is not None and fam_L != L:
-                continue
-            return True
-        return False
 
     def submit(self, req: ServeRequest) -> bool:
         """Admit a request; False under backpressure (queue budget exhausted).
@@ -411,46 +384,6 @@ class DynamicBatcher:
         self._depth = 0
         return out
 
-    # -- continuous-batching admission views ----------------------------------
-
-    def queued_Ls(self, group: GroupKey | None = None) -> list[int]:
-        """Distinct lattice sizes with waiting requests, oldest-head first
-        (optionally restricted to one (tenant, class) group)."""
-        heads: dict[int, float] = {}
-        for (g, (L, _k)), q in self._buckets.items():
-            if q and (group is None or g == group):
-                heads[L] = min(heads.get(L, q[0].arrival_s), q[0].arrival_s)
-        return sorted(heads, key=heads.__getitem__)
-
-    def next_for_L(self, L: int, max_n: int,
-                   group: GroupKey | None = None) -> list[ServeRequest]:
-        """Pop up to ``max_n`` oldest waiting requests of lattice size ``L``,
-        across every chain depth k.
-
-        Continuous batching admits by *shape* compatibility only — a chain
-        in flight for L can absorb requests of any k (each slot tracks its
-        own remaining iterations), so the (L, k) buckets merge here by
-        arrival order.  ``group`` restricts the pops to one (tenant, class)
-        — a fair turn admits only the turn owner's requests, though seated
-        slots of every group still advance together (the chain's dispatch
-        is shared).  Returns ``[]`` when nothing eligible of size L waits.
-        """
-        if max_n < 1:
-            return []
-        out: list[ServeRequest] = []
-        while len(out) < max_n:
-            candidates = [
-                (gkey, q) for gkey, q in self._buckets.items()
-                if q and gkey[1][0] == L
-                and (group is None or gkey[0] == group)
-            ]
-            if not candidates:
-                break
-            _gkey, queue = min(candidates, key=lambda kv: kv[1][0].arrival_s)
-            out.append(queue.pop(0))
-            self._depth -= 1
-        return out
-
 
 class LocalityRouter:
     """Sticky (lattice size -> host) routing for a host-sharded warm pool.
@@ -502,275 +435,3 @@ class LocalityRouter:
 
     def loads(self) -> list[float]:
         return list(self._load)
-
-
-@dataclasses.dataclass
-class InflightChain:
-    """Slot bookkeeping of one continuously-batched chain (one L, one host).
-
-    The chain's lattice batch is dispatched ONE iteration at a time; between
-    iterations (`advance`) this object decides who occupies the slots:
-
-      * ``admit`` places a same-L request into a free slot with its own
-        remaining-iteration count — mid-chain admission at an iteration
-        boundary, the continuous-batching move;
-      * a request for a different L is *rejected* (``can_admit`` False):
-        its lattice shape is incompatible with the in-flight batch and it
-        must queue for its own chain;
-      * ``advance`` decrements every live slot and frees the finished ones.
-
-    Array state (the physical lattice batch) lives with the service; this is
-    the scheduling half, testable without a device.
-    """
-
-    L: int
-    slots: int
-    iterations_run: int = 0
-    _req: list[ServeRequest | None] = dataclasses.field(default_factory=list)
-    _remaining: list[int] = dataclasses.field(default_factory=list)
-
-    def __post_init__(self) -> None:
-        if self.slots < 1:
-            raise ValueError(f"chain needs >= 1 slot, got {self.slots}")
-        self._req = [None] * self.slots
-        self._remaining = [0] * self.slots
-
-    # -- occupancy -------------------------------------------------------------
-
-    @property
-    def live(self) -> int:
-        """Slots currently carrying a request."""
-        return sum(1 for r in self._req if r is not None)
-
-    @property
-    def occupancy(self) -> float:
-        return self.live / self.slots
-
-    def free_slots(self) -> list[int]:
-        return [i for i, r in enumerate(self._req) if r is None]
-
-    def requests(self) -> list[ServeRequest]:
-        return [r for r in self._req if r is not None]
-
-    def occupants(self) -> list[tuple[int, ServeRequest, int]]:
-        """Live ``(slot, request, remaining)`` triples (eviction scans)."""
-        return [
-            (i, r, self._remaining[i])
-            for i, r in enumerate(self._req)
-            if r is not None
-        ]
-
-    # -- admission -------------------------------------------------------------
-
-    def can_admit(self, req: ServeRequest) -> bool:
-        """Shape-compatible (same L) and a slot is free."""
-        return req.L == self.L and self.live < self.slots
-
-    def admit(self, req: ServeRequest) -> int:
-        """Seat ``req`` in a free slot; returns the slot index.
-
-        Raises ValueError on an incompatible lattice size — the caller must
-        check :meth:`can_admit` (or catch) and queue the request for its own
-        chain instead.
-        """
-        if req.L != self.L:
-            raise ValueError(
-                f"request L={req.L} cannot join an in-flight L={self.L} chain "
-                f"(incompatible lattice shape); it must wait for its own chain"
-            )
-        for i, r in enumerate(self._req):
-            if r is None:
-                self._req[i] = req
-                self._remaining[i] = req.k
-                return i
-        raise ValueError(f"chain L={self.L} is full ({self.slots} slots)")
-
-    @property
-    def midchain(self) -> bool:
-        """True once the chain has advanced at least one iteration — a later
-        admit is a mid-chain admit (the case batch-per-step cannot serve)."""
-        return self.iterations_run > 0
-
-    def evict(self, slot: int) -> ServeRequest:
-        """Free a LIVE slot mid-chain (deadline eviction / quarantine
-        re-seating) and return its request; the freed slot is immediately
-        admissible — the same re-seating machinery mid-chain admission
-        uses.  A fully-drained chain resets to fresh, exactly as a drain
-        through :meth:`advance` does."""
-        req = self._req[slot]
-        if req is None:
-            raise ValueError(f"slot {slot} is not live")
-        self._req[slot] = None
-        self._remaining[slot] = 0
-        if self.live == 0:
-            self.iterations_run = 0
-        return req
-
-    # -- advancement -----------------------------------------------------------
-
-    def advance(self) -> list[tuple[int, ServeRequest]]:
-        """Account one executed iteration; returns [(slot, request)] finished.
-
-        Call AFTER the iteration's dispatch: every live slot consumed one
-        multiply; slots reaching zero remaining iterations complete and
-        free.  A chain that fully drains resets to fresh (``midchain``
-        False): an admit into a retained-but-empty chain is exactly a new
-        batch start, not a mid-chain join, and must not be counted as one.
-        """
-        done: list[tuple[int, ServeRequest]] = []
-        for i, r in enumerate(self._req):
-            if r is None:
-                continue
-            self._remaining[i] -= 1
-            if self._remaining[i] <= 0:
-                done.append((i, r))
-                self._req[i] = None
-                self._remaining[i] = 0
-        self.iterations_run = 0 if self.live == 0 else self.iterations_run + 1
-        return done
-
-
-@dataclasses.dataclass
-class SlotTable:
-    """Slot bookkeeping of one host's megakernel dispatch table.
-
-    The megakernel generalization of :class:`InflightChain`: ONE table per
-    host, slots hold in-flight requests of ANY lattice size (the batched
-    K-chain kernel pads every slot to a common site capacity), and one
-    dispatch per host per iteration advances every live slot by its own
-    scheduled depth.  What was "mid-chain admission" in the per-L chain
-    becomes a *slot swap*: seat the request in a free slot, set its
-    remaining count — no shape compatibility gate, because the dispatched
-    shape is the table's, not the request's.
-
-    Array state (the physical slot-table batch) lives with the service; this
-    is the scheduling half, testable without a device.
-    """
-
-    slots: int
-    iterations_run: int = 0
-    _req: list[ServeRequest | None] = dataclasses.field(default_factory=list)
-    _remaining: list[int] = dataclasses.field(default_factory=list)
-
-    def __post_init__(self) -> None:
-        if self.slots < 1:
-            raise ValueError(f"slot table needs >= 1 slot, got {self.slots}")
-        self._req = [None] * self.slots
-        self._remaining = [0] * self.slots
-
-    # -- occupancy -------------------------------------------------------------
-
-    @property
-    def live(self) -> int:
-        return sum(1 for r in self._req if r is not None)
-
-    @property
-    def occupancy(self) -> float:
-        return self.live / self.slots
-
-    def free_slots(self) -> list[int]:
-        return [i for i, r in enumerate(self._req) if r is None]
-
-    def requests(self) -> list[ServeRequest]:
-        return [r for r in self._req if r is not None]
-
-    def occupants(self) -> list[tuple[int, ServeRequest, int]]:
-        """Live ``(slot, request, remaining)`` triples — what a capacity-grow
-        re-seats into the replacement table."""
-        return [
-            (i, r, self._remaining[i])
-            for i, r in enumerate(self._req)
-            if r is not None
-        ]
-
-    @property
-    def max_live_L(self) -> int:
-        """Largest lattice size seated (0 when empty) — the capacity floor."""
-        return max((r.L for r in self._req if r is not None), default=0)
-
-    # -- admission (slot swap) -------------------------------------------------
-
-    def can_admit(self) -> bool:
-        return self.live < self.slots
-
-    def admit(self, req: ServeRequest, remaining: int | None = None) -> int:
-        """Seat ``req`` in a free slot; returns the slot index.
-
-        Any lattice size is admissible — the megakernel pads every slot to
-        the table's site capacity, so there is no shape gate to fail (the
-        *capacity* gate lives with the service, which grows the physical
-        table when a larger L arrives).
-        """
-        for i, r in enumerate(self._req):
-            if r is None:
-                self._req[i] = req
-                self._remaining[i] = req.k if remaining is None else remaining
-                return i
-        raise ValueError(f"slot table is full ({self.slots} slots)")
-
-    @property
-    def midchain(self) -> bool:
-        """True once the table has advanced at least one iteration with live
-        slots — a later admit is a mid-chain slot swap."""
-        return self.iterations_run > 0
-
-    def evict(self, slot: int) -> ServeRequest:
-        """Free a LIVE slot mid-chain (deadline eviction / quarantine
-        re-seating) and return its request — the inverse slot swap of
-        :meth:`admit`, leaving the slot immediately admissible.  A table
-        drained by evictions resets to fresh like one drained by
-        :meth:`advance`."""
-        req = self._req[slot]
-        if req is None:
-            raise ValueError(f"slot {slot} is not live")
-        self._req[slot] = None
-        self._remaining[slot] = 0
-        if self.live == 0:
-            self.iterations_run = 0
-        return req
-
-    def slot_of(self, req_id: int) -> int | None:
-        """The slot seating ``req_id`` (None when not seated)."""
-        for i, r in enumerate(self._req):
-            if r is not None and r.req_id == req_id:
-                return i
-        return None
-
-    # -- advancement -----------------------------------------------------------
-
-    def plan_k(self, horizon: int = 1) -> list[int]:
-        """Per-slot chain depths for the NEXT megakernel dispatch.
-
-        Each live slot advances ``min(remaining, horizon)`` multiplies; dead
-        slots get 0 (the kernel passes them through).  ``horizon`` trades
-        admission latency for dispatch amortization: 1 re-opens admission at
-        every multiply, larger values chain deeper in-kernel between
-        boundaries.
-        """
-        if horizon < 1:
-            raise ValueError(f"horizon must be >= 1, got {horizon}")
-        return [
-            min(self._remaining[i], horizon) if self._req[i] is not None else 0
-            for i in range(self.slots)
-        ]
-
-    def advance(self, applied: list[int]) -> list[tuple[int, ServeRequest]]:
-        """Account one executed dispatch that ran ``applied[i]`` multiplies on
-        slot ``i``; returns [(slot, request)] finished.
-
-        Call AFTER the dispatch with the ``plan_k`` schedule that was run.
-        A table that fully drains resets to fresh (``midchain`` False).
-        """
-        if len(applied) != self.slots:
-            raise ValueError(f"applied must cover all {self.slots} slots")
-        done: list[tuple[int, ServeRequest]] = []
-        for i, r in enumerate(self._req):
-            if r is None:
-                continue
-            self._remaining[i] -= applied[i]
-            if self._remaining[i] <= 0:
-                done.append((i, r))
-                self._req[i] = None
-                self._remaining[i] = 0
-        self.iterations_run = 0 if self.live == 0 else self.iterations_run + 1
-        return done

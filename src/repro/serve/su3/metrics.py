@@ -63,16 +63,9 @@ class ServiceMetrics:
     occupancies: RunningStat = dataclasses.field(default_factory=RunningStat)
     queue_depths: RunningStat = dataclasses.field(default_factory=RunningStat)
     compiles: int = 0  # cold (first-shape) dispatches, charged to busy_s too
-    midchain_admits: int = 0  # continuous mode: requests seated into an
-    # already-running chain (the admissions batch-per-step cannot make)
     host_dispatches: dict = dataclasses.field(default_factory=dict)  # host -> n
-    iterations: int = 0  # continuous/megakernel scheduling turns (iteration
-    # boundaries); dispatches/iterations is the dispatch-amortization figure
-    # the megakernel path drives to 1.0 per host
-    host_iterations: dict = dataclasses.field(default_factory=dict)  # host -> n
     kind_iterations: dict = dataclasses.field(default_factory=dict)  # kind -> n
-    # per-kind iteration counts: "multiply"/"stencil" turns vs "solve" CG
-    # iterations — the traffic mix's iteration bill by request family
+    # per-kind iteration counts: "solve" counts the CG iterations dispatched
     rejected_by_kind: dict = dataclasses.field(default_factory=dict)  # kind -> n
     # backpressure rejects split per request family (``rejected`` stays the
     # total, so the pre-existing snapshot key is unchanged)
@@ -83,8 +76,6 @@ class ServiceMetrics:
     retries: int = 0  # re-dispatches consumed from the retry budget
     retries_exhausted: int = 0  # requests that gave up with a structured error
     faults_injected: int = 0  # chaos faults applied to this service's seams
-    degraded_dispatches: int = 0  # megakernel batches re-run down the
-    # per-(L) chained fallback path after a dispatch failure
     quarantines: int = 0  # hosts latched out by the health tracker
     reseated: int = 0  # requests moved off a quarantined host onto a healthy one
     # -- tenancy splits (ISSUE 10) --------------------------------------------
@@ -101,7 +92,6 @@ class ServiceMetrics:
     # paid for — sums to ``shed``, so shed accounting reconciles with admits
     quota_rejected: int = 0  # submits refused by a tenant's token bucket
     quota_rejected_by_tenant: dict = dataclasses.field(default_factory=dict)
-    preemptions: int = 0  # bulk seats evicted for a waiting latency request
     scale_ups: int = 0  # autoscaler grow events
     scale_downs: int = 0  # autoscaler shrink events
     active_hosts: int = 0  # current active pool size (gauge; 0 = unset)
@@ -156,9 +146,6 @@ class ServiceMetrics:
         if tenant is not None and slo is not None:
             self._bump(self.timeouts_by_class, class_key(tenant, slo))
 
-    def record_preemption(self) -> None:
-        self.preemptions += 1
-
     def record_scale(self, delta: int, active: int) -> None:
         if delta > 0:
             self.scale_ups += 1
@@ -185,9 +172,6 @@ class ServiceMetrics:
     def record_fault(self, n: int = 1) -> None:
         self.faults_injected += n
 
-    def record_degraded(self) -> None:
-        self.degraded_dispatches += 1
-
     def record_quarantine(self, reseated: int = 0) -> None:
         self.quarantines += 1
         self.reseated += reseated
@@ -198,10 +182,8 @@ class ServiceMetrics:
     ) -> None:
         """Account one device dispatch.
 
-        ``live``/``padded`` are request slots (continuous mode charges each
-        per-iteration dispatch at its chain's slot count, so occupancy is
-        directly comparable with batch-per-step at the same warm size);
-        ``host`` attributes the dispatch to a pool shard.
+        ``live``/``padded`` are request slots (a dispatch is charged at its
+        warm batch size); ``host`` attributes the dispatch to a pool shard.
         """
         self.dispatches += 1
         self.live_slots += live
@@ -213,18 +195,9 @@ class ServiceMetrics:
         if cold:
             self.compiles += 1
 
-    def record_midchain_admits(self, n: int = 1) -> None:
-        self.midchain_admits += n
-
-    def record_iteration(self, host: int = 0, kind: str = "multiply",
-                         n: int = 1) -> None:
-        """Account ``n`` iteration boundaries (continuous/megakernel
-        scheduling turns, or solver CG iterations) of ``kind`` for ``host``
-        — the denominator of dispatches-per-iteration, split per request
-        family in ``kind_iterations``."""
-        self.iterations += n
-        self.host_iterations[host] = self.host_iterations.get(host, 0) + n
-        self.kind_iterations[kind] = self.kind_iterations.get(kind, 0) + n
+    def record_iteration(self, kind: str, n: int = 1) -> None:
+        """Account ``n`` iterations of ``kind`` (a solve's CG iterations)."""
+        self._bump(self.kind_iterations, kind, n)
 
     def record_completion(self, latency_s: float, tenant: str | None = None,
                           slo: str | None = None) -> None:
@@ -272,11 +245,6 @@ class ServiceMetrics:
             "padded_slot_fraction": round(
                 self.padded_slots / total_slots, 3
             ) if total_slots else 0.0,
-            "midchain_admits": self.midchain_admits,
-            "iterations": self.iterations,
-            "dispatches_per_iteration": round(
-                self.dispatches / self.iterations, 3
-            ) if self.iterations else 0.0,
             "host_dispatches": {str(h): n for h, n in sorted(self.host_dispatches.items())},
             "kind_iterations": {k: n for k, n in sorted(self.kind_iterations.items())},
             "rejected_by_kind": {k: n for k, n in sorted(self.rejected_by_kind.items())},
@@ -287,7 +255,6 @@ class ServiceMetrics:
             "retries": self.retries,
             "retries_exhausted": self.retries_exhausted,
             "faults_injected": self.faults_injected,
-            "degraded_dispatches": self.degraded_dispatches,
             "quarantines": self.quarantines,
             "reseated": self.reseated,
             "queue_depth_max": int(self.queue_depths.max_or(0)),
@@ -314,7 +281,6 @@ class ServiceMetrics:
             "quota_rejected": self.quota_rejected,
             "quota_rejected_by_tenant": {
                 k: n for k, n in sorted(self.quota_rejected_by_tenant.items())},
-            "preemptions": self.preemptions,
             "scale_ups": self.scale_ups,
             "scale_downs": self.scale_downs,
             "active_hosts": self.active_hosts,

@@ -57,7 +57,7 @@ class RequestFailure(RuntimeError):
 
 class DeadlineExceededError(RequestFailure):
     """The request's deadline passed while queued or seated; it was evicted
-    (queue slot and any live chain/slot-table seat freed).  ``partial``
+    (its queue slot or active solve seat freed).  ``partial``
     carries the best iterate for solves evicted mid-CG (None otherwise)."""
 
     def __init__(self, *, req_id: int, kind: str, deadline_s: float,

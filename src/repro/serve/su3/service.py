@@ -7,8 +7,7 @@ the service adds the queueing discipline and the warm-pool policy):
           │ LocalityRouter — sticky L -> host (work follows the warm data)
           ▼
     per-host DynamicBatcher — (L, k) buckets, warm-size padding, admission
-          │  next_batch()  one CoalescedBatch per step()        [batch mode]
-          │  next_for_L()  iteration-boundary admission     [continuous mode]
+          │  next_batch()  one CoalescedBatch per step()
           ▼
     host-sharded warm pool:
           {(host, L, dtype, layout, tile) -> BatchedLatticeRunner}
@@ -30,7 +29,7 @@ Stencil requests (``submit_stencil``) ride the same front door: same
 locality router, same per-host batcher (their own by-L queue family), same
 warm runner pool.  They coalesce into one vmapped stencil dispatch per
 scheduling turn and return canonical vector fields; they never join multiply
-chains in any dispatch mode.
+dispatches.
 
 Solve requests (``submit_solve``) are the flagship iterative workload: a
 CG solve through ``ExecutionPlan.cg_state_init`` / ``cg_iterate`` on one of
@@ -39,37 +38,15 @@ stencil+axpy iteration), or, for a request with a ``mass``, MILC's even/odd
 staggered ``(4 m^2 - D_eo D_oe) x_e = b_e`` on the field the seat prepares.
 Each host runs ONE active solve at a time, advanced
 ``solve_iters_per_step`` CG iterations per scheduling turn — its iteration
-count is data-dependent, so it retires *mid-chain* the turn its residual
-crosses tol (or at ``max_iters``), freeing its seat and queue budget while
-multiply chains are still in flight.  When a host has several kinds
-pending, turns rotate multiply → stencil → solve so no sustained stream of
-one kind starves the others.
+count is data-dependent, so it retires the turn its residual crosses tol
+(or at ``max_iters``), freeing its seat and queue budget.  When a host has
+several kinds pending, turns rotate multiply → stencil → solve so no
+sustained stream of one kind starves the others.
 
-Dispatch modes
---------------
-``batch-per-step`` (default): one ``step()`` call dispatches one coalesced
-(L, k) bucket through one fused-k vmapped call.  Requests arriving while a
-chain runs wait for the next ``step()``.
-
-``continuous`` (``ServiceConfig(continuous=True)``): each (host, L) keeps an
-:class:`~repro.serve.su3.batcher.InflightChain` whose lattice batch is
-re-dispatched ONE iteration at a time; at every iteration boundary, waiting
-same-L requests are admitted into free slots (mid-chain admission — each
-slot carries its own remaining-iteration count, so mixed k coexists in one
-chain).  A request for a different L is shape-incompatible with the
-in-flight batch and queues for its own chain.  Under open-loop load this
-keeps the dispatched slots fuller than batch-per-step — measured by
-``benchmarks/serve_traffic.py``'s continuous-vs-batch row.
-
-``megakernel`` (``ServiceConfig(continuous=True, megakernel=True)``): the
-continuous path's dispatch bill — one kernel launch per (host, L) chain per
-iteration, the pipeline-throughput tax the paper measures on PIUMA — is
-collapsed to ONE batched K-chain ``pallas_call`` per host per iteration.
-Each host keeps a single mixed-L :class:`SlotTable`; every slot is padded to
-the table's site capacity (grown, with live slots re-seated, when a larger L
-arrives), per-slot chain depths ride in as scalar-prefetched data, and
-mid-chain admission becomes a slot swap.  ``chain_horizon`` chains that many
-multiplies in-kernel between admission boundaries.
+A multiply dispatches one coalesced (L, k) bucket per ``step()`` through
+one fused-k vmapped call (``BatchedLatticeRunner.multiply``, whose codec
+runs on the device).  Requests arriving while a dispatch runs wait for the
+next ``step()``.
 """
 from __future__ import annotations
 
@@ -107,10 +84,8 @@ from repro.obs.tracer import NULL_TRACER, Tracer
 from repro.serve.su3.batcher import (
     BatcherConfig,
     DynamicBatcher,
-    InflightChain,
     LocalityRouter,
     ServeRequest,
-    SlotTable,
 )
 from repro.serve.su3.metrics import ServiceMetrics, request_flops
 from repro.serve.su3.robustness import (
@@ -162,7 +137,7 @@ def _solve_operator(req: ServeRequest) -> str:
 @dataclasses.dataclass(frozen=True)
 class ServiceConfig:
     """The serving tuple: storage/compute dtypes, layout, tuning, batching,
-    host topology, and dispatch mode.
+    host topology, and robustness and tenancy policy.
 
     Attributes:
         dtype: storage dtype of every plan in the pool.
@@ -184,19 +159,6 @@ class ServiceConfig:
             On a device pool smaller than the host count, hosts
             oversubscribe the local devices — the routing/batching semantics
             are identical, only physical placement collapses (simulation).
-        continuous: continuous-batching dispatch (iteration-boundary
-            admission into in-flight chains) instead of batch-per-step.
-        chain_slots: slots per in-flight chain (continuous mode);
-            0 = the batcher's ``padded_size(max_batch)``.
-        megakernel: continuous mode dispatches ONE batched K-chain
-            megakernel per host per iteration over a single mixed-L slot
-            table (``ExecutionPlan.fused_batched_step``) instead of one
-            k=1 dispatch per (host, L) chain — the dispatch-amortized path
-            (requires ``continuous=True``).
-        chain_horizon: megakernel in-kernel chain depth per slot between
-            admission boundaries; 1 re-opens admission at every multiply,
-            larger values amortize more dispatches per request at the cost
-            of admission latency.
         solve_iters_per_step: CG iterations the host's active solve advances
             per scheduling turn; small values re-open kind rotation (and
             thus multiply/stencil service) more often, large values amortize
@@ -209,7 +171,7 @@ class ServiceConfig:
             retry budget for failed dispatches.
         default_deadline_s: relative deadline applied to every request that
             does not pass its own (0 = none); a request past its deadline is
-            evicted — from the queue OR its live chain/table seat — and
+            evicted — from the queue OR the active solve seat — and
             completes with a structured ``DeadlineExceededError``.
         quarantine_after: consecutive dispatch failures that latch a host
             out of service (its requests re-seat onto healthy hosts);
@@ -244,10 +206,6 @@ class ServiceConfig:
     batcher: BatcherConfig = dataclasses.field(default_factory=BatcherConfig)
     cache_directory: str | None = None  # autotune cache override (tests)
     hosts: int = 1  # shard the warm pool across this many hosts
-    continuous: bool = False  # iteration-boundary admission dispatch
-    chain_slots: int = 0  # continuous-chain slots; 0 = padded max_batch
-    megakernel: bool = False  # one batched dispatch/host/iteration (continuous)
-    chain_horizon: int = 1  # megakernel in-kernel chain depth between boundaries
     solve_iters_per_step: int = 4  # CG iterations per solve scheduling turn
     faults: FaultPlan | None = None  # chaos plan armed over the serve seams
     retry: RetryPolicy = dataclasses.field(default_factory=RetryPolicy)
@@ -278,16 +236,6 @@ class ServiceConfig:
             )
         if self.hosts < 1:
             raise ValueError(f"hosts must be >= 1, got {self.hosts}")
-        if self.chain_slots < 0:
-            raise ValueError(f"chain_slots must be >= 0, got {self.chain_slots}")
-        if self.megakernel and not self.continuous:
-            raise ValueError(
-                "megakernel dispatch is the continuous path's amortizer; "
-                "set continuous=True (batch-per-step already fuses its k "
-                "chain in one dispatch)"
-            )
-        if self.chain_horizon < 1:
-            raise ValueError(f"chain_horizon must be >= 1, got {self.chain_horizon}")
         if self.solve_iters_per_step < 1:
             raise ValueError(
                 f"solve_iters_per_step must be >= 1, got "
@@ -327,74 +275,6 @@ def _host_stack(xs: list[Any], pad: int) -> np.ndarray:
     if pad:
         a = np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)])
     return a
-
-
-class _ChainArrays:
-    """Device-array half of one in-flight chain (scheduling half:
-    :class:`~repro.serve.su3.batcher.InflightChain`).
-
-    Holds the physical lattice batch ``a_phys (slots, ...)`` and planar B
-    batch ``b_p (slots, 2, 36)``; free slots carry zero lattices (they step
-    harmlessly and are charged as padding by the metrics).  Both are made
-    on the device in the physical form; canonical arrays stay on the host.
-    """
-
-    def __init__(self, runner: BatchedLatticeRunner, slots: int):
-        self.runner = runner
-        plan = runner.plan
-        phys = jax.eval_shape(
-            jax.vmap(plan.codec.pack),
-            jax.ShapeDtypeStruct((slots, plan.padded_sites, 4, 3, 3), jnp.complex64),
-        )
-        self.a_phys = jax.device_put(
-            jnp.zeros(phys.shape, phys.dtype), runner.batch_sharding(slots))
-        self.b_p = jax.device_put(
-            jnp.zeros((slots, 2, 36), plan.codec.word_dtype), plan.replicated)
-
-    def seat(self, slot: int, a: jax.Array, b: jax.Array) -> None:
-        """Pack one request's canonical (A, B) into ``slot``, zero-padding
-        its sites up to the runner's capacity."""
-        self.a_phys = self.a_phys.at[slot].set(self.runner.pack_batch(a[None])[0])
-        self.b_p = self.b_p.at[slot].set(self.runner.plan.pack_links(b))
-
-    def advance(self) -> None:
-        """One vmapped physical multiply over every slot (k=1)."""
-        self.a_phys = self.runner.run(self.a_phys, self.b_p, k=1)
-
-    def result(self, slot: int, n_sites: int) -> jax.Array:
-        """Canonical complex C of ``slot`` on the host, sliced to the live
-        sites."""
-        return self.runner.plan.unpack(self.a_phys[slot], n_sites)
-
-    def clear(self, slot: int) -> None:
-        """Zero a freed slot (its stale lattice would otherwise keep
-        stepping and confuse a later occupant's first iteration)."""
-        self.a_phys = self.a_phys.at[slot].set(jnp.zeros_like(self.a_phys[slot]))
-        self.b_p = self.b_p.at[slot].set(jnp.zeros_like(self.b_p[slot]))
-
-
-class _SlotTableArrays(_ChainArrays):
-    """Device-array half of one host's megakernel slot table (scheduling
-    half: :class:`~repro.serve.su3.batcher.SlotTable`).
-
-    Every slot is padded to ``cap_L``'s site capacity, so requests of ANY
-    L <= cap_L share the one dispatched shape; the whole table advances in
-    ONE ``fused_batched_step`` dispatch with per-slot chain depths.  Dead
-    slots carry zero lattices and depth 0 (the kernel passes them through).
-    """
-
-    def __init__(self, runner: BatchedLatticeRunner, slots: int, max_k: int):
-        super().__init__(runner, slots)
-        self.slots = slots
-        self.max_k = max_k
-        self.cap_L = runner.cfg.L
-        self._step = runner.plan.fused_batched_step(slots, max_k=max_k)
-
-    def advance(self, slot_k: list[int]) -> None:
-        """ONE megakernel dispatch: slot ``i`` advances ``slot_k[i]``
-        multiplies in-kernel (0 = pass-through)."""
-        ks = jnp.asarray(slot_k, jnp.int32)
-        self.a_phys = self._step(self.a_phys, self.b_p, ks)
 
 
 class SU3Service:
@@ -450,10 +330,6 @@ class SU3Service:
         self._seen_shapes: set[tuple] = set()
         self._next_id = 0
         self._rr_host = 0  # round-robin cursor over hosts for step()
-        # continuous mode: (host, L) -> (InflightChain, _ChainArrays)
-        self._chains: dict[tuple[int, int], tuple[InflightChain, _ChainArrays]] = {}
-        # megakernel mode: host -> (SlotTable, _SlotTableArrays)
-        self._tables: dict[int, tuple[SlotTable, _SlotTableArrays]] = {}
         # -- robustness state (ISSUE 9) ---------------------------------------
         self.faults = self.cfg.faults if self.cfg.faults is not None \
             else NULL_FAULT_PLAN
@@ -585,20 +461,13 @@ class SU3Service:
             )
         return self._tuned_k[L]
 
-    def _chain_slots(self) -> int:
-        return self.cfg.chain_slots or self.cfg.batcher.padded_size(
-            self.cfg.batcher.max_batch
-        )
-
     def warm(self, Ls: tuple[int, ...], ks: tuple[int, ...] = (1,),
              batch_sizes: tuple[int, ...] = (), stencil: bool = False) -> None:
         """Pre-build runners (and optionally compile dispatch shapes).
 
         Serving cold-start control: first-touch compiles happen here instead
-        of inside a user request's latency.  In continuous mode this also
-        compiles the (chain_slots, k=1) iteration shape each chain
-        re-dispatches.  ``stencil=True`` additionally compiles the vmapped
-        stencil dispatch at each warm batch size.
+        of inside a user request's latency.  ``stencil=True`` additionally
+        compiles the vmapped stencil dispatch at each warm batch size.
         """
         for L in Ls:
             runner = self.runner_for(L)
@@ -619,21 +488,6 @@ class SU3Service:
                     step = self._stencil_step_for(runner, host, L)
                     step(u_phys, v_p).block_until_ready()
                     self._seen_shapes.add(("stencil", L, dispatched))
-            if self.cfg.megakernel:
-                # per-slot depths are data, so ONE compile at this capacity
-                # serves every (k mix, admission pattern) the table will see
-                slots = self._chain_slots()
-                arrays = _SlotTableArrays(runner, slots, max_k=self.cfg.chain_horizon)
-                arrays.advance([0] * slots)
-                arrays.a_phys.block_until_ready()
-                self._seen_shapes.add(("mega", L, slots, self.cfg.chain_horizon))
-            elif self.cfg.continuous:
-                arrays = _ChainArrays(runner, self._chain_slots())
-                arrays.advance()
-                arrays.a_phys.block_until_ready()
-                self._seen_shapes.add(
-                    self._shape_key(runner, L, 1, self._chain_slots())
-                )
 
     @staticmethod
     def _shape_key(runner: BatchedLatticeRunner, L: int, k: int, bsz: int) -> tuple:
@@ -660,7 +514,7 @@ class SU3Service:
 
     @staticmethod
     def _seat_popped(reqs: list[ServeRequest]) -> None:
-        """Batch mode seats a request when its batch is popped: the queue
+        """A queued request is seated when its batch is popped: the queue
         wait ends there, before the batch is stacked on the host."""
         now = time.perf_counter()
         for r in reqs:
@@ -790,28 +644,6 @@ class SU3Service:
                 "shed", lane=_request_lane(victim.req_id),
                 req_id=victim.req_id, kind=victim.kind)
 
-    def _preempt_bulk(self, occupants: list, evict_fn: Any, host: int) -> bool:
-        """Latency-lane seat preemption: evict the youngest-arrival seated
-        BULK request to free one slot for a waiting latency-class multiply.
-        The victim is not failed — it re-queues on its home batcher (the
-        deterministic re-run the quarantine re-seat path already relies on)
-        and only resolves as a structured shed if its queue is full."""
-        bulk = [(slot, req) for slot, req, _rem in occupants
-                if req.slo == SLO_BULK]
-        if not bulk:
-            return False
-        slot, victim = max(bulk, key=lambda t: t[1].arrival_s)
-        evict_fn(slot)
-        self.metrics.record_preemption()
-        if self.tracer.enabled:
-            self.tracer.event(
-                "preempt", lane=_request_lane(victim.req_id),
-                req_id=victim.req_id, kind=victim.kind, host=host, slot=slot,
-                tenant=victim.tenant)
-        if not self._batchers[host].submit(victim):
-            self._shed(victim, "latency-preempt")
-        return True
-
     def _admit(self, req: ServeRequest, host: int, load_flops: float,
                depth: int) -> int | None:
         """Shared admission tail: queue-budget check with priority-aware
@@ -900,7 +732,7 @@ class SU3Service:
             :meth:`submit`.  Stencil requests ride the SAME warm pool,
             locality router, and per-host batcher as multiplies; they
             coalesce by lattice size into one vmapped stencil dispatch and
-            never join multiply chains (their output is a vector field).
+            never join a multiply dispatch (their output is a vector field).
         """
         L = self._infer_L(u)
         if v.shape != (L**4, 3):
@@ -959,7 +791,7 @@ class SU3Service:
             router, and per-host batcher; its iteration count is
             data-dependent, so it advances ``solve_iters_per_step`` CG
             iterations per scheduling turn through the fused stencil+axpy
-            kernel and retires mid-chain when the residual crosses tol.
+            kernel and retires the turn its residual crosses tol.
         """
         L = self._infer_L(u)
         sites = L**4 if mass is None else L**4 // 2
@@ -1004,19 +836,14 @@ class SU3Service:
         return bool(self._retry_q) or self._work_ready()
 
     def _work_ready(self) -> bool:
-        """True while a turn has something to dispatch: a queued request, an
-        active solve or a live chain or slot table (retry backoffs aside)."""
-        if any(len(b) for b in self._batchers):
-            return True
-        if self._solves:
-            return True
-        if any(chain.live for chain, _ in self._chains.values()):
-            return True
-        return any(table.live for table, _ in self._tables.values())
+        """True while a turn has something to dispatch: a queued request or
+        an active solve (retry backoffs aside)."""
+        return bool(self._solves) or any(len(b) for b in self._batchers)
 
     def pending(self) -> bool:
         """True while any request waits in a queue, a retry backoff, or an
-        in-flight chain — the loop condition for external step() drivers."""
+        active solve — the loop condition for callers that step the
+        service themselves."""
         return self._work_pending()
 
     # -- failure lifecycle (ISSUE 9) ------------------------------------------
@@ -1073,28 +900,6 @@ class SU3Service:
         self._fail(req, terminal)
         return False
 
-    def _charge_seated(self, occupants: list, evict_fn: Any,
-                       cause: str) -> None:
-        """Charge one failed dispatch to every seated occupant of a chain or
-        slot table: seated requests KEEP their seats while attempts remain
-        (the next turn re-dispatches the same state, bitwise clean); past
-        the per-request cap — or with the service retry budget dry — they
-        are evicted with a structured error.  One budget unit covers the
-        whole failed dispatch, not one per occupant."""
-        policy = self.cfg.retry
-        budget_dry = self._retry_budget <= 0
-        if not budget_dry:
-            self._retry_budget -= 1
-            self.metrics.record_retry()
-        for slot, req, _rem in occupants:
-            req.attempts += 1
-            if budget_dry or req.attempts > policy.max_retries:
-                evict_fn(slot)
-                self.metrics.record_retries_exhausted()
-                self._fail(req, RetriesExhaustedError(
-                    req_id=req.req_id, kind=req.kind, attempts=req.attempts,
-                    cause=cause, budget_exhausted=budget_dry))
-
     def _drain_retry_queue(self, now: float) -> None:
         """Move backoff-expired requests back into their (healthy) home
         host's queue; a still-full queue waits another beat rather than
@@ -1109,10 +914,9 @@ class SU3Service:
 
     def _evict_expired(self, now: float) -> None:
         """The deadline sweep: evict every expired request wherever it sits
-        — queued, waiting out a backoff, seated in a live chain/table slot,
-        or the active solve — and deliver structured timeouts.  Freed seats
-        are immediately admissible (the same re-seating machinery mid-chain
-        admission uses)."""
+        — queued, waiting out a backoff, or the active solve — and deliver
+        structured timeouts.  A freed solve seat is filled on the host's
+        next solve turn."""
         for batcher in self._batchers:
             for req in batcher.evict_expired(now):
                 self._timeout(req, now)
@@ -1132,41 +936,15 @@ class SU3Service:
                 partial = active["unpack"](active["state"]["x"])
                 del self._solves[host]
                 self._timeout(req, now, partial=partial)
-        for chain, arrays in self._chains.values():
-            for slot, req, _rem in chain.occupants():
-                if req.deadline_s and req.deadline_s <= now:
-                    chain.evict(slot)
-                    arrays.clear(slot)
-                    self._timeout(req, now)
-        for table, arrays in self._tables.values():
-            for slot, req, _rem in table.occupants():
-                if req.deadline_s and req.deadline_s <= now:
-                    table.evict(slot)
-                    arrays.clear(slot)
-                    self._timeout(req, now)
 
     def _drain_host(self, host: int) -> list[ServeRequest]:
-        """Pull every request ``host`` holds — queued, the active solve, and
-        seated chain/table slots — off the host (mid-chain progress is
-        discarded; the re-run is deterministic).  Shared by the quarantine
-        and scale-down paths."""
+        """Pull every request ``host`` holds — queued, and the active solve —
+        off the host (the solve's progress is discarded; the re-run is
+        deterministic).  Shared by the quarantine and scale-down paths."""
         moved: list[ServeRequest] = list(self._batchers[host].drain())
         active = self._solves.pop(host, None)
         if active is not None:
             moved.append(active["req"])
-        for key in [k for k in self._chains if k[0] == host]:
-            chain, arrays = self._chains.pop(key)
-            for slot, req, _rem in chain.occupants():
-                chain.evict(slot)
-                arrays.clear(slot)
-                moved.append(req)
-        entry = self._tables.pop(host, None)
-        if entry is not None:
-            table, arrays = entry
-            for slot, req, _rem in table.occupants():
-                table.evict(slot)
-                arrays.clear(slot)
-                moved.append(req)
         return moved
 
     def _reseat(self, moved: list[ServeRequest], cause: str) -> int:
@@ -1235,38 +1013,11 @@ class SU3Service:
                 seq=f.seq, host=host, kind=kind)
         return poison_array(x, f.action)
 
-    def _host_busy(self, host: int) -> bool:
-        """A live seat on ``host``: the active solve, a live chain, or a
-        live slot table (the occupancy half of the pressure signal)."""
-        if host in self._solves:
-            return True
-        if self.cfg.megakernel:
-            entry = self._tables.get(host)
-            return bool(entry and entry[0].live)
-        if self.cfg.continuous:
-            return any(
-                h == host and chain.live
-                for (h, _L), (chain, _a) in self._chains.items()
-            )
-        return False
-
     def _seated_latency(self, host: int) -> bool:
-        """True when ``host`` holds a seated latency-class request (a shrink
-        must never evict one — the veto the autoscaler docs promise)."""
+        """True when ``host``'s active solve is latency-class (a shrink must
+        never evict one — the veto the autoscaler docs promise)."""
         active = self._solves.get(host)
-        if active is not None and active["req"].slo == SLO_LATENCY:
-            return True
-        for (h, _L), (chain, _a) in self._chains.items():
-            if h == host and any(
-                    req.slo == SLO_LATENCY
-                    for _s, req, _rem in chain.occupants()):
-                return True
-        entry = self._tables.get(host)
-        if entry is not None and any(
-                req.slo == SLO_LATENCY
-                for _s, req, _rem in entry[0].occupants()):
-            return True
-        return False
+        return active is not None and active["req"].slo == SLO_LATENCY
 
     def _scale_down(self) -> None:
         """Retire the top active host: drain its queued/seated work onto the
@@ -1299,7 +1050,8 @@ class SU3Service:
         n = max(1, len(active))
         depth = self.queued()
         cap = max(1, self.cfg.batcher.max_queue_depth) * n
-        occupancy = sum(1 for h in active if self._host_busy(h)) / n
+        # a host's one live seat is its active solve
+        occupancy = sum(1 for h in active if h in self._solves) / n
         pressure = min(1.0, depth / cap)
         if depth:
             pressure = max(pressure, occupancy)
@@ -1338,11 +1090,8 @@ class SU3Service:
         provable bound (tests/test_tenancy.py pins it).  Within the granted
         group, kinds rotate multiply -> stencil -> solve exactly as before
         — per (host, group) now — so no sustained stream of one kind
-        starves the others *inside* a group.  Dispatch is unchanged:
-        batch-per-step serves one coalesced (L, k) bucket from the group's
-        buckets; continuous admits the group's waiters at the iteration
-        boundary then advances ALL the host's live chains; megakernel
-        slot-swaps then fires one batched K-chain dispatch.  Each step also
+        starves the others *inside* a group.  A multiply turn serves one
+        coalesced (L, k) bucket from the group's buckets.  Each step also
         feeds one pressure sample to the brownout ladder and the warm-pool
         autoscaler (when configured).
 
@@ -1391,19 +1140,14 @@ class SU3Service:
                     return self._step_stencil(host, group)
                 if kind == "solve":
                     return self._step_solve(host, group)
-                if self.cfg.megakernel:
-                    return self._step_megakernel(host, group)
-                if self.cfg.continuous:
-                    return self._step_continuous(host, group)
                 return self._step_batch(host, group)
         return 0
 
     def _pending_groups(self, host: int) -> dict[GroupKey, set[str]]:
         """Pending work on ``host`` keyed by (tenant, SLO class) group, each
-        with its waiting kinds.  Live chain/table seats count as multiply
-        work for their occupants' groups; the single active solve counts
-        for ITS group only and suppresses other groups' queued solves (one
-        solve seat per host — their turn comes when it retires)."""
+        with its waiting kinds.  The single active solve counts for ITS
+        group only and suppresses other groups' queued solves (one solve
+        seat per host — their turn comes when it retires)."""
         groups = {
             g: set(kinds)
             for g, kinds in
@@ -1416,23 +1160,12 @@ class SU3Service:
                 if g != owner:
                     kinds.discard("solve")
             groups.setdefault(owner, set()).add("solve")
-        if self.cfg.megakernel:
-            entry = self._tables.get(host)
-            if entry is not None:
-                for _slot, req, _rem in entry[0].occupants():
-                    groups.setdefault(req.group, set()).add("multiply")
-        elif self.cfg.continuous:
-            for (h, _L), (chain, _arr) in self._chains.items():
-                if h != host:
-                    continue
-                for _slot, req, _rem in chain.occupants():
-                    groups.setdefault(req.group, set()).add("multiply")
         return {g: kinds for g, kinds in groups.items() if kinds}
 
     def _step_batch(self, host: int, group: GroupKey | None = None) -> int:
-        """One coalesced fused-k dispatch for ``host`` (batch-per-step),
-        drawn from ``group``'s buckets when the fair scheduler granted the
-        turn to a specific (tenant, class) group."""
+        """One coalesced fused-k dispatch for ``host``, drawn from
+        ``group``'s buckets when the fair scheduler granted the turn to a
+        specific (tenant, class) group."""
         batch = self._batchers[host].next_batch(group=group)
         if batch is None:
             return 0
@@ -1645,14 +1378,14 @@ class SU3Service:
             req.seated_s = time.perf_counter()
             self.tracer.event(
                 "seat", lane=_request_lane(req.req_id), req_id=req.req_id,
-                L=req.L, host=host, kind="solve", midchain=False)
+                L=req.L, host=host, kind="solve")
         return active
 
     def _step_solve(self, host: int, group: GroupKey | None = None) -> int:
         """Advance the host's active solve by ``solve_iters_per_step`` CG
         iterations (seating the granted group's oldest queued solve first
-        if none is active); retires it — mid-chain, its seat and queue
-        budget free immediately — once the residual crosses tol or
+        if none is active); retires it — its seat and queue budget free
+        immediately — once the residual crosses tol or
         ``max_iters`` runs out, delivering the best iterate either way."""
         active = self._solves.get(host)
         if active is None:
@@ -1755,7 +1488,7 @@ class SU3Service:
         self.metrics.record_dispatch(
             live=1, padded=1, step_s=step_s, flops=flops, cold=cold, host=host,
         )
-        self.metrics.record_iteration(host, kind="solve", n=n)
+        self.metrics.record_iteration("solve", n)
         if rs_host <= active["stop2"] or state["iterations"] >= req.max_iters:
             return self._retire_solve(host, active, state)
         self.metrics.record_queue_depth(self.queued())
@@ -1775,285 +1508,8 @@ class SU3Service:
         self.metrics.record_queue_depth(self.queued())
         return 1
 
-    def _step_continuous(self, host: int, group: GroupKey | None = None) -> int:
-        """One iteration boundary for ``host``: admit the granted group's
-        waiters, then advance each of its chains by one multiply."""
-        batcher = self._batchers[host]
-        self.metrics.record_iteration(host)
-        slots = self._chain_slots()
-
-        # 1) admission — existing chains first (mid-chain admits), then new
-        #    chains for queued Ls that have none.  A request whose L differs
-        #    from a chain's is never seated in it (InflightChain.admit
-        #    enforces the shape incompatibility); it reaches its own chain
-        #    here.
-        for L in batcher.queued_Ls(group):
-            chain_key = (host, L)
-            if chain_key not in self._chains:
-                runner = self.runner_for(L, host)
-                self._chains[chain_key] = (
-                    InflightChain(L=L, slots=slots),
-                    _ChainArrays(runner, slots),
-                )
-            chain, arrays = self._chains[chain_key]
-            free = slots - chain.live
-            if not free and group is not None and group[1] == SLO_LATENCY:
-                # a full chain never blocks the latency lane: the youngest
-                # bulk seat is preempted (re-queued) to admit this turn
-                if self._preempt_bulk(
-                        chain.occupants(),
-                        lambda s, c=chain, a=arrays: (c.evict(s), a.clear(s)),
-                        host):
-                    free = 1
-            if not free:
-                continue
-            admitted = batcher.next_for_L(L, free, group=group)
-            for req in admitted:
-                slot = chain.admit(req)
-                arrays.seat(slot, req.a, req.b)
-                if self.tracer.enabled:
-                    req.seated_s = time.perf_counter()
-                    self.tracer.event(
-                        "seat", lane=_request_lane(req.req_id),
-                        req_id=req.req_id, slot=slot, L=L, host=host,
-                        midchain=chain.midchain)
-            if admitted and chain.midchain:
-                self.metrics.record_midchain_admits(len(admitted))
-
-        # 2) advance every live chain of this host by ONE iteration
-        completed = 0
-        queued_Ls = set(batcher.queued_Ls())
-        for (h, L) in [key for key in self._chains if key[0] == host]:
-            chain, arrays = self._chains[(h, L)]
-            if not chain.live:
-                if L not in queued_Ls:
-                    # dead chain with nothing queued: drop it (its compiled
-                    # shape stays warm in the jit cache)
-                    del self._chains[(h, L)]
-                continue
-            runner = arrays.runner
-            n_sites = L**4
-            if self.faults.enabled:
-                f = self._dispatch_fault(host, "multiply", "continuous")
-                if f is not None:
-                    quarantined = self.health.record_failure(host, "dispatch")
-                    self._charge_seated(
-                        chain.occupants(),
-                        lambda s, c=chain, a=arrays: (c.evict(s), a.clear(s)),
-                        "injected dispatch failure")
-                    if quarantined:
-                        self._quarantine(host)
-                        return completed  # this host's chains are gone
-                    continue  # seated survivors re-dispatch next turn
-            shape_key = self._shape_key(runner, L, 1, slots)
-            cold = shape_key not in self._seen_shapes
-            live = chain.live
-            flops = request_flops(n_sites, 1) * live
-            prev_a = arrays.a_phys
-            with self._dispatch_span(runner, host, "multiply", L, 1,
-                                     "continuous", live=live, padded=slots,
-                                     flops=flops, cold=cold):
-                t0 = time.perf_counter()
-                arrays.advance()
-                if self.faults.enabled:
-                    arrays.a_phys = self._poison_output(
-                        arrays.a_phys, host, "multiply")
-                arrays.a_phys.block_until_ready()
-                step_s = time.perf_counter() - t0
-            if (self.faults.enabled or self.cfg.numerics_guard) \
-                    and not self._finite(arrays.a_phys):
-                # roll the chain state back: the retried advance re-runs
-                # from the same iterate, bitwise clean
-                arrays.a_phys = prev_a
-                quarantined = self.health.record_failure(
-                    host, "non-finite output")
-                self._charge_seated(
-                    chain.occupants(),
-                    lambda s, c=chain, a=arrays: (c.evict(s), a.clear(s)),
-                    "non-finite kernel output")
-                if quarantined:
-                    self._quarantine(host)
-                    return completed
-                continue
-            if self.faults.enabled or self.cfg.numerics_guard:
-                self.health.record_success(host)
-            self._seen_shapes.add(shape_key)
-            self.metrics.record_dispatch(
-                live=live, padded=slots, step_s=step_s, flops=flops,
-                cold=cold, host=host,
-            )
-            done_s = time.perf_counter()
-            for slot, req in chain.advance():
-                self._results[req.req_id] = arrays.result(slot, n_sites)
-                arrays.clear(slot)
-                self.metrics.record_completion(
-                    done_s - req.arrival_s, tenant=req.tenant, slo=req.slo)
-                if self.tracer.enabled:
-                    self._trace_request(req, done_s, host, "continuous")
-                completed += 1
-        self.metrics.record_queue_depth(self.queued())
-        return completed
-
-    # -- megakernel dispatch (one batched K-chain call per host) --------------
-
-    def _table_for(self, host: int, cap_L: int) -> tuple[SlotTable, _SlotTableArrays]:
-        """The host's slot table, built (or capacity-grown) for ``cap_L``.
-
-        Growing re-seats every live slot's *current* mid-chain lattice into
-        the larger-capacity arrays at the same slot index — the scheduling
-        half (SlotTable) is untouched, so remaining counts and admission
-        bookkeeping survive the grow.
-        """
-        slots = self._chain_slots()
-        entry = self._tables.get(host)
-        if entry is not None and cap_L <= entry[1].cap_L:
-            return entry
-        runner = self.runner_for(cap_L, host)
-        arrays = _SlotTableArrays(runner, slots, max_k=self.cfg.chain_horizon)
-        if entry is None:
-            self._tables[host] = (SlotTable(slots), arrays)
-        else:
-            table, old = entry
-            for slot, req, _remaining in table.occupants():
-                a_mid = old.result(slot, req.n_sites)  # mid-chain state
-                arrays.seat(slot, a_mid, req.b)
-            self._tables[host] = (table, arrays)
-        return self._tables[host]
-
-    def _step_megakernel(self, host: int, group: GroupKey | None = None) -> int:
-        """One iteration boundary for ``host``: slot-swap admission across
-        the granted group's queued lattice sizes, then ONE batched K-chain
-        dispatch."""
-        batcher = self._batchers[host]
-        self.metrics.record_iteration(host)
-        queued = batcher.queued_Ls(group)
-        entry = self._tables.get(host)
-        if entry is None and not queued:
-            return 0
-
-        # 1) admission — a slot swap per request, any L (grow capacity first
-        #    so every queued size fits the one dispatched shape)
-        if queued:
-            cap_L = max(queued + ([entry[1].cap_L] if entry else []))
-            table, arrays = self._table_for(host, cap_L)
-            for L in queued:
-                free = self._chain_slots() - table.live
-                if not free and group is not None \
-                        and group[1] == SLO_LATENCY:
-                    # full table: preempt the youngest bulk seat so the
-                    # latency lane admits this turn
-                    if self._preempt_bulk(
-                            table.occupants(),
-                            lambda s, t=table, a=arrays: (
-                                t.evict(s), a.clear(s)),
-                            host):
-                        free = 1
-                if not free:
-                    break
-                admitted = batcher.next_for_L(L, free, group=group)
-                for req in admitted:
-                    slot = table.admit(req)
-                    arrays.seat(slot, req.a, req.b)
-                    if self.tracer.enabled:
-                        req.seated_s = time.perf_counter()
-                        self.tracer.event(
-                            "seat", lane=_request_lane(req.req_id),
-                            req_id=req.req_id, slot=slot, L=L, host=host,
-                            midchain=table.midchain)
-                if admitted and table.midchain:
-                    self.metrics.record_midchain_admits(len(admitted))
-        table, arrays = self._tables[host]
-
-        # 2) ONE megakernel dispatch advancing every live slot by its own
-        #    scheduled depth (min(remaining, horizon))
-        completed = 0
-        ks = table.plan_k(self.cfg.chain_horizon)
-        if any(ks):
-            occupants = table.occupants()
-            degraded = False
-            quarantine_pending = False
-            if self.faults.enabled:
-                f = self._dispatch_fault(host, "multiply", "megakernel")
-                if f is not None:
-                    # degradation ladder: the failed megakernel batch
-                    # re-dispatches down the per-(L) chained path this turn
-                    # (one runner.multiply per live slot); repeated failures
-                    # still walk the host toward quarantine
-                    degraded = True
-                    self.metrics.record_degraded()
-                    quarantine_pending = self.health.record_failure(
-                        host, "dispatch")
-            shape_key = ("mega", arrays.cap_L, table.slots, self.cfg.chain_horizon)
-            cold = shape_key not in self._seen_shapes
-            live = table.live
-            guard = self.faults.enabled or self.cfg.numerics_guard
-            # the megakernel donates the slot table: roll back to a copy
-            prev_a = jnp.copy(arrays.a_phys) if guard and not degraded else None
-            dispatch_flops = sum(
-                request_flops(req.n_sites, ks[slot])
-                for slot, req, _rem in occupants
-            )
-            with self._dispatch_span(arrays.runner, host, "multiply",
-                                     arrays.cap_L, self.cfg.chain_horizon,
-                                     "megakernel", live=live,
-                                     padded=table.slots, flops=dispatch_flops,
-                                     cold=cold):
-                t0 = time.perf_counter()
-                if degraded:
-                    for slot, req, _rem in occupants:
-                        if not ks[slot]:
-                            continue
-                        a_mid = arrays.result(slot, req.n_sites)
-                        c = self.runner_for(req.L, host).multiply(
-                            a_mid[None], req.b[None], k=ks[slot])[0]
-                        arrays.seat(slot, c, req.b)
-                else:
-                    arrays.advance(ks)
-                    if self.faults.enabled:
-                        arrays.a_phys = self._poison_output(
-                            arrays.a_phys, host, "multiply")
-                arrays.a_phys.block_until_ready()
-                step_s = time.perf_counter() - t0
-            if prev_a is not None and not self._finite(arrays.a_phys):
-                arrays.a_phys = prev_a  # retried advance is bitwise clean
-                quarantined = self.health.record_failure(
-                    host, "non-finite output")
-                self._charge_seated(
-                    table.occupants(),
-                    lambda s, t=table, a=arrays: (t.evict(s), a.clear(s)),
-                    "non-finite kernel output")
-                if quarantined:
-                    self._quarantine(host)
-                else:
-                    self.metrics.record_queue_depth(self.queued())
-                return 0
-            if prev_a is not None:
-                self.health.record_success(host)
-            self._seen_shapes.add(shape_key)
-            self.metrics.record_dispatch(
-                live=live, padded=table.slots, step_s=step_s,
-                flops=dispatch_flops,
-                cold=cold, host=host,
-            )
-            done_s = time.perf_counter()
-            for slot, req in table.advance(ks):
-                self._results[req.req_id] = arrays.result(slot, req.n_sites)
-                arrays.clear(slot)
-                self.metrics.record_completion(
-                    done_s - req.arrival_s, tenant=req.tenant, slo=req.slo)
-                if self.tracer.enabled:
-                    self._trace_request(req, done_s, host, "megakernel")
-                completed += 1
-            if quarantine_pending:
-                # crossed the consecutive-failure latch this turn: deliver
-                # the degraded batch's completions above, then re-seat the
-                # survivors onto healthy hosts
-                self._quarantine(host)
-        self.metrics.record_queue_depth(self.queued())
-        return completed
-
     def run_until_drained(self, max_steps: int = 10_000) -> int:
-        """Step until queues, retry backoffs AND in-flight chains empty;
+        """Step until queues, retry backoffs AND active solves empty;
         returns completed."""
         total = 0
         for _ in range(max_steps):
@@ -2061,8 +1517,7 @@ class SU3Service:
                 return total
             n = self.step()
             total += n
-            if n == 0 and self._retry_q and not self._solves \
-                    and not any(len(b) for b in self._batchers):
+            if n == 0 and self._retry_q and not self._work_ready():
                 # only backoff waits remain: sleep to the earliest eligible
                 # retry instead of spinning max_steps away
                 nxt = min(t for t, _ in self._retry_q)

@@ -7,8 +7,8 @@ the control plane that keeps one tenant's burst from becoming everyone's
 tail latency.  Pure host-side scheduling state — no jax — so every policy
 is unit-testable without a device:
 
-  SLO classes       two lanes: ``latency`` (preempting, never shed) and
-                    ``bulk`` (preemptible, the only sheddable lane).  Each
+  SLO classes       two lanes: ``latency`` (never shed) and ``bulk`` (the
+                    only sheddable lane).  Each
                     request kind has a default class (multiplies are bulk;
                     stencils and solves are the interactive tier) that
                     ``submit_*(slo=...)`` overrides per request.
@@ -46,10 +46,8 @@ is unit-testable without a device:
                     observation index — not wall clock — so a same-seed
                     replay reproduces the transition log bit-for-bit.
 
-Latency-class work is protected three ways, in escalating order: fair
-turns (the scheduler), seats (latency preempts the youngest bulk seat via
-the PR 4/PR 9 re-seating machinery), and admission (brownout only ever
-sheds the bulk lane).
+Latency-class work is protected two ways: fair turns (the scheduler) and
+admission (shedding and brownout only ever take the bulk lane).
 """
 from __future__ import annotations
 

@@ -2,9 +2,9 @@
 
 The codec-level pack/unpack properties live in
 ``test_layout_codec_roundtrip.py``; here the compressed PATH is exercised —
-the Pallas multiply / megakernel / stencil kernels streaming (2, 24, S)
-gauge blocks with in-register third-row reconstruction — against the
-18-real full-width kernels on the same canonical data.
+the Pallas multiply and stencil kernels and the served multiply streaming
+(2, 24, S) gauge blocks with in-register third-row reconstruction — against
+the 18-real full-width kernels on the same canonical data.
 """
 import numpy as np
 import pytest
@@ -15,7 +15,8 @@ from repro.core.su3 import layouts, registry
 from repro.core.su3.engine import EngineConfig as _EngineConfig  # noqa: F401
 from repro.core.su3.engine import SU3Engine
 from repro.core.su3.layouts import Layout
-from repro.core.su3.plan import EngineConfig, build_plan, make_raw_step
+from repro.core.su3.plan import (
+    EngineConfig, build_plan, make_raw_step, verify_tolerance)
 
 _TILE = 32
 _SITES = 64
@@ -75,28 +76,29 @@ def test_compressed_multiply_stored_rows_track_full_kernel_any_input():
     assert err <= 4e-6 * max(scale, 1.0), (err, scale)
 
 
-def test_compressed_megakernel_chain_matches_dispatched_full_steps():
-    """The slot-batched megakernel with ``compressed=True`` chains K
-    compressed multiplies per slot in one dispatch; each slot must agree
-    with K separately dispatched FULL-width steps on SU(3) data."""
-    slot_k = (1, 3)
-    a = jnp.asarray(_su3(_SITES, 3), jnp.complex64)
-    b = jnp.asarray(_su3(1, 4)[0], jnp.complex64)
-    codec_f, step_f = _steps("none")
-    codec_c, _ = _steps("two_row")
-    mk = registry.get_kernel("pallas_megakernel")
-    a_c = jnp.stack([codec_c.pack(a)] * len(slot_k))
-    b_p = jnp.stack([codec_c.pack_b(b)] * len(slot_k))
-    out = mk.fn(a_c, b_p, jnp.asarray(slot_k, jnp.int32), tile=_TILE,
-                compressed=True)
-    for slot, k in enumerate(slot_k):
-        ref_phys = codec_f.pack(a)
-        for _ in range(k):
-            ref_phys = step_f(ref_phys, codec_f.pack_b(b))
-        ref = codec_f.unpack(ref_phys, _SITES)
-        got = codec_c.unpack(out[slot], _SITES)
-        err = float(jnp.max(jnp.abs(got - ref)))
-        assert err < k * 1e-5, (slot, k, err)
+@pytest.fixture(scope="module")
+def two_row_service():
+    """One two-row service, so its compiled codec serves every k."""
+    from repro.serve.su3 import ServiceConfig, SU3Service
+
+    return SU3Service(ServiceConfig(autotune=False, tile=16,
+                                    compression="two_row"))
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_compressed_service_multiply_matches_full_plan(two_row_service, k):
+    """A two-row served multiply of SU(3) data, its codec on the device,
+    agrees with K chained multiplies of the FULL-width plan within the
+    plan's verification tolerance."""
+    a = jnp.asarray(_su3(16, 3 + k), jnp.complex64)
+    b = jnp.asarray(_su3(1, 4 + k)[0], jnp.complex64)
+    rid = two_row_service.submit(a, b, k=k)
+    two_row_service.run_until_drained()
+    full = build_plan(EngineConfig(L=2, tile=16))
+    c_phys = full.fused_step(k)(full.codec.pack(a), full.codec.pack_b(b))
+    err = float(jnp.max(jnp.abs(two_row_service.pop_result(rid)
+                                - full.unpack(c_phys, 16))))
+    assert err < verify_tolerance("float32", reconstruct=True), (k, err)
 
 
 @pytest.mark.parametrize("dtype,accum", [("float32", ""),

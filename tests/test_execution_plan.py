@@ -83,9 +83,8 @@ def test_registry_unifies_variants_and_pallas():
     entry = registry.get_kernel("pallas")
     assert entry.form == registry.PLANAR and entry.supports_fused
     assert registry.kernel_names(backend="pallas") == [
-        "pallas", "pallas_cg", "pallas_megakernel", "pallas_stencil"]
+        "pallas", "pallas_cg", "pallas_stencil"]
     assert "pallas" not in registry.kernel_names(form=registry.CANONICAL)
-    assert registry.kernel_names(form=registry.BATCHED) == ["pallas_megakernel"]
     assert registry.kernel_names(form=registry.STENCIL) == ["pallas_stencil"]
     assert registry.kernel_names(form=registry.STENCIL_AXPY) == ["pallas_cg"]
 

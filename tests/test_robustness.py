@@ -11,7 +11,6 @@ import asyncio
 import random
 import time
 
-import numpy as np
 import pytest
 
 import jax
@@ -150,7 +149,6 @@ def test_metrics_robustness_counters_and_per_kind_splits():
     m.record_retry(2)
     m.record_retries_exhausted()
     m.record_fault()
-    m.record_degraded()
     m.record_quarantine(reseated=3)
     snap = m.snapshot()
     assert snap["rejected"] == 3  # the pre-existing total key is unchanged
@@ -160,7 +158,6 @@ def test_metrics_robustness_counters_and_per_kind_splits():
     assert snap["retries"] == 3
     assert snap["retries_exhausted"] == 1
     assert snap["faults_injected"] == 1
-    assert snap["degraded_dispatches"] == 1
     assert snap["quarantines"] == 1 and snap["reseated"] == 3
     # the legacy surface bench rows key on is still there
     for key in ("completed", "dispatches", "latency_p50_ms",
@@ -232,97 +229,32 @@ def test_deadline_evicts_active_solve_and_carries_partial():
 
 
 @pytest.mark.chaos
-def test_deadline_evicts_only_live_slot_in_megakernel_table():
-    # satellite edge case: the sweep empties a slot table down to zero live
-    # slots mid-chain; the table must idle cleanly and the next admit reuses
-    # the freed seat
-    svc = _svc(continuous=True, megakernel=True, chain_slots=2,
-               chain_horizon=1,
-               batcher=BatcherConfig(max_batch=2, warm_batch_sizes=(2,),
-                                     max_queue_depth=8))
-    a, b = _rand_ab(1)
-    rid = svc.submit(a, b, k=6, deadline_s=60.0)
-    for _ in range(2):
-        svc.step()
-    (table, _arrays), = svc._tables.values()
-    occupants = table.occupants()
-    assert len(occupants) == 1  # the only live slot
-    occupants[0][1].deadline_s = time.perf_counter() - 1.0
-    svc.step()  # sweep evicts; the empty table must not dispatch or crash
-    out = svc.pop_result(rid)
-    assert isinstance(out, DeadlineExceededError)
-    assert table.live == 0
-    # the freed seat serves the next request end-to-end
-    a2, b2 = _rand_ab(2)
-    rid2 = svc.submit(a2, b2, k=2)
-    svc.run_until_drained()
-    # the megakernel's reduction order differs from the plain runner's, so
-    # the cross-path check is allclose, not bitwise
-    ref = _svc().runner_for(2).multiply(a2[None], b2[None], k=2)[0]
-    np.testing.assert_allclose(
-        np.abs(np.asarray(svc.pop_result(rid2) - ref)), 0.0, atol=1e-4)
-
-
-@pytest.mark.chaos
-def test_midchain_eviction_frees_seat_for_pending_same_L_admit():
-    # satellite edge case: a same-L request waits in the queue while the
-    # chain is full; the deadline eviction must free the seat through the
-    # same re-seating machinery mid-chain admission uses
-    svc = _svc(continuous=True, chain_slots=1, chain_horizon=1,
-               batcher=BatcherConfig(max_batch=1, warm_batch_sizes=(1,),
-                                     max_queue_depth=8))
-    a1, b1 = _rand_ab(3)
-    a2, b2 = _rand_ab(4)
-    rid1 = svc.submit(a1, b1, k=8, deadline_s=60.0)
-    svc.step()  # seat rid1 into the single chain slot
-    rid2 = svc.submit(a2, b2, k=1)  # same-L admit pending behind a full chain
-    svc.step()
-    (chain, _arrays), = svc._chains.values()
-    occ = chain.occupants()
-    assert [o[1].req_id for o in occ] == [rid1]
-    occ[0][1].deadline_s = time.perf_counter() - 1.0
-    svc.run_until_drained()
-    assert isinstance(svc.pop_result(rid1), DeadlineExceededError)
-    ref = _svc().runner_for(2).multiply(a2[None], b2[None], k=1)[0]
-    assert bool(jnp.array_equal(svc.pop_result(rid2), ref))
-
-
-@pytest.mark.chaos
 def test_deadline_eviction_on_quarantined_host_reseats_then_times_out():
-    # satellite edge case: work seated on a host that gets quarantined is
-    # re-seated onto a healthy pool; an expired deadline must still produce
-    # a structured timeout (never a silent drop) after the move
-    svc = _svc(hosts=2, continuous=True, chain_slots=1, chain_horizon=1,
-               quarantine_after=1,
+    # satellite edge case: work queued on a host that gets quarantined is
+    # re-seated onto a healthy host's queue; an expired deadline must still
+    # produce a structured timeout (never a silent drop) after the move
+    svc = _svc(hosts=2, quarantine_after=1,
                batcher=BatcherConfig(max_batch=1, warm_batch_sizes=(1,),
                                      max_queue_depth=8))
     a, b = _rand_ab(5)
     home = svc.router.host_for(2)
     rid = svc.submit(a, b, k=8, deadline_s=60.0)
-    svc.step()  # seat on the home host
-    assert any(k[0] == home for k in svc._chains)
+    assert len(svc._batchers[home]) == 1  # queued on the home host
     svc.health.record_failure(home, "test latch")
     svc._quarantine(home)
     assert svc.health.is_quarantined(home)
-    assert svc.metrics.snapshot()["quarantines"] == 1
-    # the re-seated request sits on the healthy host — queued or already
-    # chained; step until it holds a seat, then force expiry there
-    deadline_past = time.perf_counter() - 1.0
-    found = False
-    for _ in range(20):
-        for chain, _arr in svc._chains.values():
-            for _slot, r, _rem in chain.occupants():
-                if r.req_id == rid:
-                    r.deadline_s = deadline_past
-                    found = True
-        if found:
-            break
-        svc.step()
-    assert found, "request lost during quarantine re-seat"
+    snap = svc.metrics.snapshot()
+    assert snap["quarantines"] == 1 and snap["reseated"] == 1
+    (healthy,) = [h for h in range(2) if h != home]
+    assert len(svc._batchers[home]) == 0
+    moved = [r for q in svc._batchers[healthy]._buckets.values() for r in q]
+    assert [r.req_id for r in moved] == [rid], "request lost during re-seat"
+    moved[0].deadline_s = time.perf_counter() - 1.0
     svc.run_until_drained()
     out = svc.pop_result(rid)
     assert isinstance(out, DeadlineExceededError)
     assert not svc.pending()
+    assert svc.metrics.snapshot()["dispatches"] == 0
 
 
 def test_reseat_resolves_already_expired_deadline_exactly_once():
@@ -520,61 +452,46 @@ def test_quarantine_reseats_onto_the_healthy_host_bitwise_clean():
     assert svc.health.healthy_hosts() == [0, 1]
 
 
+_BATCH_FAULTS = {
+    "dispatch": {"dispatch": FaultSpec(probability=1.0, actions=("fail",),
+                                       max_fires=1)},
+    "nonfinite": {"kernel": FaultSpec(probability=1.0, actions=("nan",),
+                                      max_fires=1)},
+}
+
+
 @pytest.mark.chaos
-def test_megakernel_dispatch_failure_degrades_to_chained_path():
-    # a failed megakernel batch re-dispatches down the per-slot chained
-    # path: numerically equivalent (different reduction order), not lost
-    plan = FaultPlan(2, {"dispatch": FaultSpec(probability=1.0,
-                                               actions=("fail",),
-                                               max_fires=1)})
-    svc = _storm_svc(plan, continuous=True, megakernel=True, chain_slots=2,
-                     chain_horizon=1,
-                     batcher=BatcherConfig(max_batch=2, warm_batch_sizes=(2,),
-                                           max_queue_depth=8))
-    reqs = [_rand_ab(200 + i) for i in range(2)]
+@pytest.mark.parametrize("hosts", [1, 2])
+@pytest.mark.parametrize("fault", sorted(_BATCH_FAULTS))
+def test_batch_fault_retries_to_bitwise_clean(fault, hosts):
+    # a failed launch or a poisoned (NaN) output is never delivered: the
+    # coalesced batch re-runs through the retry path and finishes bitwise
+    # equal to a clean run; with quarantine_after=1 on two hosts the retry
+    # lands on the healthy host, and a lone host is never latched
+    plan = FaultPlan(7, _BATCH_FAULTS[fault])
+    svc = _storm_svc(plan, hosts=hosts, quarantine_after=1)
+    reqs = [_rand_ab(400 + i) for i in range(2)]
+    home = svc.router.host_for(2)
     ids = [svc.submit(a, b, k=2) for a, b in reqs]
     svc.run_until_drained()
-    snap = svc.metrics.snapshot()
-    assert snap["degraded_dispatches"] >= 1
-    ref = _svc()
-    for rid, (a, b) in zip(ids, reqs):
-        out = svc.pop_result(rid)
-        assert not isinstance(out, Exception)
-        expect = ref.runner_for(2).multiply(a[None], b[None], k=2)[0]
-        np.testing.assert_allclose(
-            np.abs(np.asarray(out - expect)), 0.0, atol=1e-4)
-
-
-@pytest.mark.chaos
-def test_megakernel_nonfinite_output_rolls_back_past_donation():
-    # the megakernel donates its slot table, so the pre-dispatch state it
-    # rolls back to on a poisoned output must be a copy, not the donated
-    # buffer; the retried advance then finishes bitwise equal to a clean run
-    mega = dict(continuous=True, megakernel=True, chain_slots=2,
-                chain_horizon=1,
-                batcher=BatcherConfig(max_batch=2, warm_batch_sizes=(2,),
-                                      max_queue_depth=8))
-    reqs = [_rand_ab(300 + i) for i in range(2)]
-    clean = _svc(**mega)
-    clean_ids = [clean.submit(a, b, k=3) for a, b in reqs]
-    clean.run_until_drained()
-
-    plan = FaultPlan(6, {"kernel": FaultSpec(probability=1.0,
-                                             actions=("nan",), max_fires=1)})
-    svc = _storm_svc(plan, **mega)
-    ids = [svc.submit(a, b, k=3) for a, b in reqs]
-    svc.step()  # admit + the poisoned dispatch, rolled back
     assert plan.fired == 1
-    (_table, arrays), = svc._tables.values()
-    assert not arrays.a_phys.is_deleted()
-    donated = arrays.a_phys
-    svc.run_until_drained()
-    assert donated.is_deleted()  # the retried advance donated the rollback
-    assert svc.metrics.snapshot()["retries"] >= 1
-    for rid, rid0 in zip(ids, clean_ids):
+    snap = svc.metrics.snapshot()
+    assert snap["retries"] == 2 and snap["retries_exhausted"] == 0
+    # the same batch dispatched clean on the runner that served the retry
+    clean = svc.runner_for(2).multiply(
+        jnp.stack([a for a, _ in reqs]), jnp.stack([b for _, b in reqs]), k=2)
+    for i, rid in enumerate(ids):
         out = svc.pop_result(rid)
         assert not isinstance(out, Exception)
-        assert bool(jnp.array_equal(out, clean.pop_result(rid0)))
+        assert bool(jnp.array_equal(out, clean[i]))
+    if hosts == 2:
+        (healthy,) = [h for h in range(2) if h != home]
+        assert svc.health.quarantined() == {home}
+        assert snap["quarantines"] == 1
+        # the one delivered dispatch ran on the healthy host
+        assert snap["host_dispatches"] == {str(healthy): 1}
+    else:
+        assert svc.health.quarantined() == set()
 
 
 @pytest.mark.chaos

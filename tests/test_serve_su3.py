@@ -1,5 +1,8 @@
-"""Serving subsystem: dynamic batcher, SU3Service, metrics, bf16 plans."""
+"""Serving subsystem: dynamic batcher, SU3Service, metrics, bf16 plans,
+the host-sharded pool, and the multiply path's padding, mixed-L and
+deadline contracts."""
 import asyncio
+import time
 
 import numpy as np
 import pytest
@@ -11,9 +14,11 @@ import jax.numpy as jnp
 
 from repro.core import autotune
 from repro.core.su3.layouts import Layout
+from repro.core.su3.plan import verify_tolerance
 from repro.kernels import ref
 from repro.serve.su3 import (
     BatcherConfig,
+    DeadlineExceededError,
     DynamicBatcher,
     ServeRequest,
     ServiceConfig,
@@ -385,5 +390,182 @@ def test_solve_per_kind_iteration_metrics():
     snap = svc.metrics.snapshot()
     ki = snap["kind_iterations"]
     assert set(ki) == {"solve"} and ki["solve"] > 0
-    assert ki["solve"] % 4 in (0, 1, 2, 3)  # dispatched in <=4-iteration turns
-    assert snap["iterations"] >= ki["solve"]
+    # dispatched in full 4-iteration turns: the solve converges before
+    # max_iters, so no turn is cut short
+    assert ki["solve"] == 4 * snap["dispatches"]
+
+
+# -- host-sharded pool over the simulated host topology -----------------------
+
+
+def test_multihost_service_routes_by_locality():
+    svc = SU3Service(ServiceConfig(autotune=False, tile=16, hosts=2))
+    ids = [svc.submit(_rand_a(i), _rand_b(i), k=1) for i in range(2)]  # L=2
+    ids.append(svc.submit(_rand_a(9, n_sites=256), _rand_b(9), k=1))  # L=4
+    svc.run_until_drained()
+    # the two Ls landed on different hosts; pool keys carry the host
+    assert {key[0] for key in svc.pool_keys()} == {0, 1}
+    assert set(svc.router.assignments()) == {2, 4}
+    snap = svc.metrics.snapshot()
+    assert set(snap["host_dispatches"]) == {"0", "1"}
+    for rid in ids:
+        assert svc.pop_result(rid) is not None
+
+
+def test_multihost_warm_spreads_pool_across_hosts():
+    """warm() is a burst of first-sight Ls with no traffic in between; the
+    router's nominal placement charge must still spread them (a zero-load
+    tie would pin every warmed L — and so all future traffic — to host 0)."""
+    svc = SU3Service(ServiceConfig(autotune=False, tile=16, hosts=2))
+    svc.warm((2, 4))
+    assert {key[0] for key in svc.pool_keys()} == {0, 1}
+    homes = svc.router.assignments()
+    assert homes[2] != homes[4]
+
+
+def test_multihost_rejects_explicit_mesh():
+    with pytest.raises(ValueError, match="EITHER"):
+        SU3Service(ServiceConfig(autotune=False, tile=16, hosts=2), mesh=object())
+
+
+def test_service_config_validation():
+    with pytest.raises(ValueError, match="hosts"):
+        ServiceConfig(autotune=False, tile=16, hosts=0)
+
+
+# -- the multiply path's contracts ---------------------------------------------
+
+
+def _same_bits(x, y) -> bool:
+    x, y = np.ascontiguousarray(x), np.ascontiguousarray(y)
+    return (x.shape == y.shape and x.dtype == y.dtype
+            and np.array_equal(x.view(np.uint8), y.view(np.uint8)))
+
+
+def _su3(n, seed):
+    """``n`` random SU(3) matrices (QR of a complex Gaussian, unit det)."""
+    rng = np.random.default_rng(seed)
+    q, r = np.linalg.qr(rng.standard_normal((n, 3, 3))
+                        + 1j * rng.standard_normal((n, 3, 3)))
+    d = np.diagonal(r, axis1=-2, axis2=-1)
+    q = q * (d / np.abs(d))[..., None, :]
+    return (q / np.linalg.det(q)[..., None, None] ** (1 / 3)).astype(np.complex64)
+
+
+def _chain_ref(a, b, k):
+    for _ in range(k):
+        a = ref.su3_mult_ref(a, b)
+    return a
+
+
+@pytest.fixture(scope="module")
+def warm4_service():
+    """A service whose every batch pads to 4, shared so that its compiled
+    programs serve each case."""
+    return _svc(batcher=BatcherConfig(max_batch=4, warm_batch_sizes=(4,)))
+
+
+@pytest.mark.parametrize("live", [1, 2, 3])
+def test_padded_batch_rows_never_leak(warm4_service, live):
+    """A batch padded to warm size 4 with zero rows: the live results equal
+    each request dispatched alone, bit for bit, only the live requests
+    complete, and the metrics charge the padded rows."""
+    svc = warm4_service
+    svc.metrics.reset()
+    reqs = [(_rand_a(40 + i), _rand_b(50 + i)) for i in range(live)]
+    ids = [svc.submit(a, b, k=2) for a, b in reqs]
+    assert svc.run_until_drained() == live
+    runner = svc.runner_for(2)
+    for rid, (a, b) in zip(ids, reqs):
+        alone = runner.multiply(np.asarray(a)[None], np.asarray(b)[None], k=2)[0]
+        assert _same_bits(svc.pop_result(rid), alone)
+    assert svc.pop_ready() == {}
+    snap = svc.metrics.snapshot()
+    assert snap["dispatches"] == 1 and snap["completed"] == live
+    assert svc.metrics.padded_slots == 4 - live
+    assert snap["mean_batch_occupancy"] == pytest.approx(live / 4)
+
+
+@pytest.mark.parametrize("hosts", [1, 2])
+def test_mixed_L_stream_one_dispatch_per_bucket(hosts):
+    """A mixed-L, mixed-k stream of SU(3) fields: every (L, k) bucket is one
+    dispatch, each result is within the plan's tolerance of the chained
+    reference, and on two hosts each L runs on its router home."""
+    svc = _svc(hosts=hosts)
+    stream = [(2, 2), (4, 1), (2, 3), (4, 2)]
+    reqs = []
+    for i, (L, k) in enumerate(stream):
+        a = _su3(L**4 * 4, 60 + i).reshape(L**4, 4, 3, 3)
+        b = _su3(4, 70 + i)
+        reqs.append((svc.submit(a, b, k=k), a, b, k))
+    assert svc.run_until_drained() == len(stream)
+    tol = verify_tolerance("float32")
+    for rid, a, b, k in reqs:
+        err = float(jnp.max(jnp.abs(svc.pop_result(rid) - _chain_ref(a, b, k))))
+        assert err < tol, (k, err)
+    snap = svc.metrics.snapshot()
+    assert snap["dispatches"] == len(set(stream))
+    homes = svc.router.assignments()
+    assert {key[0] for key in svc.pool_keys()} == set(homes.values())
+    for key in svc.pool_keys():
+        assert key[0] == homes[key[1]]
+    if hosts == 2:
+        assert homes[2] != homes[4]
+        assert snap["host_dispatches"] == {"0": 2, "1": 2}
+
+
+def test_solve_completes_alongside_batched_multiply_stream():
+    """A CG solve (data-dependent turn count) shares the host with a
+    multiply stream that keeps arriving: multiplies complete while the
+    solve is in flight, the solve retires on its residual test, and every
+    request of both kinds has the right answer."""
+    from repro.core.su3.plan import CG_SHIFT, cg_reference_solve
+
+    svc = _svc(solve_iters_per_step=2)
+    u, b = _solve_problem()
+    # the solve shares the multiplies' (tenant, class) group, so their turns
+    # rotate multiply -> solve
+    sid = svc.submit_solve(u, b, tol=1e-6, max_iters=64, slo="bulk")
+    mult = [(svc.submit(_rand_a(i), _rand_b(i), k=k), i, k)
+            for i, k in enumerate([1, 2, 1])]
+    results = {}
+    while svc.pending():
+        svc.step()
+        results.update(svc.pop_ready())
+        if sid not in results and len(results) == len(mult):
+            # every multiply so far retired while the solve is in flight:
+            # one more arrives behind them
+            mult.append((svc.submit(_rand_a(7), _rand_b(7), k=1), 7, 1))
+    assert sid in results and len(results) == len(mult) + 1
+    assert len(mult) > 3  # a multiply arrived, and finished, mid-solve
+    for rid, seed, k in mult:
+        np.testing.assert_allclose(
+            np.asarray(results[rid]),
+            np.asarray(_chain_ref(_rand_a(seed), _rand_b(seed), k)),
+            rtol=1e-4, atol=1e-4)
+    x_ref, _, ok = cg_reference_solve(u, b, 2, sigma=CG_SHIFT, tol=1e-6,
+                                      max_iters=64)
+    assert ok
+    np.testing.assert_allclose(np.asarray(results[sid]), np.asarray(x_ref),
+                               rtol=1e-5, atol=1e-5)
+    assert 0 < svc.metrics.snapshot()["kind_iterations"]["solve"] < 64
+
+
+def test_deadline_expired_in_queue_does_not_block_its_bucket():
+    """An expired request is swept out of its (L, k) bucket with a
+    structured timeout; the request queued behind it in the same bucket is
+    dispatched alone and equals a clean dispatch of it bit for bit."""
+    svc = _svc()
+    a0, b0 = _rand_a(80), _rand_b(80)
+    a1, b1 = _rand_a(81), _rand_b(81)
+    dead = svc.submit(a0, b0, k=2, deadline_s=1e-4)
+    time.sleep(0.01)
+    live = svc.submit(a1, b1, k=2)
+    assert svc.run_until_drained() == 1
+    assert isinstance(svc.pop_result(dead), DeadlineExceededError)
+    clean = svc.runner_for(2).multiply(np.asarray(a1)[None],
+                                       np.asarray(b1)[None], k=2)[0]
+    assert _same_bits(svc.pop_result(live), clean)
+    snap = svc.metrics.snapshot()
+    assert snap["timeouts"] == 1 and snap["dispatches"] == 1
+    assert snap["mean_live_batch"] == 1.0

@@ -474,23 +474,27 @@ def test_service_stencil_validates_vector_shape():
 
 def test_service_stencil_stream_does_not_starve_chains():
     """Kind fairness: with BOTH kinds pending, turns alternate — a sustained
-    stencil stream must not starve a multiply chain already in flight."""
+    stencil stream must not starve a queued k=3 multiply."""
     from repro.serve.su3 import BatcherConfig, ServiceConfig, SU3Service
 
     rng = np.random.default_rng(13)
     svc = SU3Service(ServiceConfig(
-        autotune=False, tile=16, continuous=True,
+        autotune=False, tile=16,
         batcher=BatcherConfig(max_batch=2, warm_batch_sizes=(1, 2),
                               max_queue_depth=16),
     ))
     S = 16
     am, bm = _rand_complex(rng, (S, 4, 3, 3)), _rand_complex(rng, (4, 3, 3))
-    mid = svc.submit(am, bm, k=3)  # needs 3 chain iterations
+    mid = svc.submit(am, bm, k=3)
     u, v = _rand_complex(rng, (S, 4, 3, 3)), _rand_complex(rng, (S, 3))
     for step_n in range(12):
         if svc.has_result(mid):
             break
         svc.submit_stencil(u, v)  # keep the stencil queue non-empty
         svc.step()
-    assert svc.has_result(mid), "multiply chain starved by stencil stream"
+    assert svc.has_result(mid), "multiply starved by stencil stream"
+    from repro.kernels import ref as kref
+    want = kref.su3_mult_ref(kref.su3_mult_ref(kref.su3_mult_ref(am, bm), bm), bm)
+    np.testing.assert_allclose(np.asarray(svc.pop_result(mid)),
+                               np.asarray(want), atol=1e-4)
     svc.run_until_drained()

@@ -1,5 +1,5 @@
 """Multi-tenant SLO control plane (ISSUE 10): quotas, deficit-fair
-scheduling, brownout ladder, warm-pool autoscaling, seat preemption.
+scheduling, brownout ladder, warm-pool autoscaling, the latency lane.
 
 The pure-policy tests (TokenBucket, DeficitFairScheduler, BrownoutLadder,
 WarmPoolAutoscaler) run in microseconds with no device.  The service-level
@@ -233,7 +233,7 @@ def test_autoscale_config_validates_hysteresis():
         AutoscaleConfig(grow_occupancy=0.2, shrink_occupancy=0.3)
 
 
-# -- service-level: quotas, classes, brownout, preemption ----------------------
+# -- service-level: quotas, classes, brownout, the latency lane ----------------
 
 
 @pytest.mark.tenancy
@@ -364,18 +364,29 @@ def test_brownout_rung2_degrades_bulk_solves():
 
 
 @pytest.mark.tenancy
-def test_latency_preempts_youngest_bulk_seat_continuous():
-    svc = _svc(continuous=True, chain_slots=2,
-               batcher=BatcherConfig(max_batch=2))
+def test_latency_lane_under_bulk_backlog():
+    # a latency multiply arriving behind a bulk backlog is served within the
+    # deficit scheduler's stated bound — ceil(1/(q*w)) * sum over the other
+    # groups of ceil(1 + q*w_h) turns — and no bulk request is lost
+    svc = _svc(batcher=BatcherConfig(max_batch=1, warm_batch_sizes=(1,)))
     a, b = _rand_ab(9)
-    bulk_ids = [svc.submit(a, b, k=6) for _ in range(2)]
-    svc.step()  # seat both bulk requests (k=6: they stay in flight)
+    bulk_ids = [svc.submit(a, b, k=1) for _ in range(6)]
+    svc.step()  # the backlog owns the first turn
     lat_id = svc.submit(a, b, k=1, slo="latency")
-    done = svc.run_until_drained()
-    assert done == 3
-    assert svc.metrics.snapshot()["preemptions"] >= 1
-    for rid in bulk_ids + [lat_id]:  # zero lost: preempted bulk re-ran
+    q, weight = svc._sched.quantum, svc.cfg.slo.weight_for
+    lat, bulk = ("default", SLO_LATENCY), ("default", SLO_BULK)
+    bound = math.ceil(1.0 / (q * weight(lat))) * math.ceil(1.0 + q * weight(bulk))
+    turns = 0
+    while not svc.has_result(lat_id):
+        svc.step()
+        turns += 1
+        assert turns <= bound, f"latency lane waited {turns} > {bound} turns"
+    assert not isinstance(svc.pop_result(lat_id), Exception)
+    svc.run_until_drained()
+    for rid in bulk_ids:  # zero lost
         assert not isinstance(svc.pop_result(rid), Exception)
+    snap = svc.metrics.snapshot()
+    assert snap["completed"] == 7 and snap["shed"] == 0
 
 
 @pytest.mark.tenancy

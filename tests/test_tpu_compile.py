@@ -4,7 +4,7 @@ Nothing here runs: each test lowers a plan's jitted step at the paper's
 L=32 (1,048,576 sites, tile 512) with shapes only, and the installed TPU
 compiler either accepts the program or raises what the chip would raise.
 That witnesses what interpret mode cannot: Mosaic lowering (lane alignment,
-VMEM limits, the dynamic ``fori_loop`` chain, scalar prefetch) and, on the
+VMEM limits, the dynamic ``fori_loop`` chain) and, on the
 described 2x2 mesh, that every sharded kernel sits inside a shard_map.
 
 The topology is described inside a fixture, never at import: only one
@@ -110,24 +110,25 @@ def test_fused_chain_compiles_aliased(one_chip, k):
     assert mem.alias_size_in_bytes == mem.output_size_in_bytes > 0
 
 
-def test_megakernel_compiles(one_chip):
-    plan = build_plan(EngineConfig(L=L, tile=TILE), one_chip)
-    slots = 2
-    rep = NamedSharding(one_chip, P())
-    a = _shape((slots, 2, 36, plan.padded_sites), jnp.float32,
-               plan.lattice_batch_sharding())
-    compiled = plan.fused_batched_step(slots, max_k=4).lower(
-        a, _shape((slots, 2, 36), jnp.float32, rep),
-        _shape((slots,), jnp.int32, rep)).compile()
-    assert _kernels(compiled) == 1
+def _served_batch(runner, mesh):
+    """Shapes of a two-request physical batch and its links."""
+    a = _shape((2, 2, 36, runner.plan.padded_sites), jnp.float32,
+               runner.plan.lattice_batch_sharding())
+    return a, _shape((2, 2, 36), jnp.float32, NamedSharding(mesh, P()))
 
 
 def test_served_batch_step_compiles(one_chip):
     runner = BatchedLatticeRunner(EngineConfig(L=L, tile=TILE), one_chip)
-    a = _shape((2, 2, 36, runner.plan.padded_sites), jnp.float32,
-               runner.plan.lattice_batch_sharding())
-    b = _shape((2, 2, 36), jnp.float32, NamedSharding(one_chip, P()))
+    a, b = _served_batch(runner, one_chip)
     assert _kernels(runner._batched_step(1).lower(a, b).compile()) == 1
+
+
+def test_served_batch_chain_compiles(one_chip):
+    """The served cell's deepest chain, k=8, is one kernel: the whole
+    unrolled chain runs in a single dispatch."""
+    runner = BatchedLatticeRunner(EngineConfig(L=L, tile=TILE), one_chip)
+    a, b = _served_batch(runner, one_chip)
+    assert _kernels(runner._batched_step(8).lower(a, b).compile()) == 1
 
 
 def test_stencil_and_fused_cg_compile(one_chip):
